@@ -1,18 +1,6 @@
 """repro — FlashMLA-ETAP reproduction package.
 
-Importing the package installs the JAX version-compat shims (see
-``repro.compat``): tests and launch scripts written against the newer mesh
-APIs (``jax.set_mesh``, ``jax.sharding.AxisType``, ...) then run unmodified
-on older installed JAX.
-
-When JAX is not installed the shims are skipped instead of failing the
-import: the pure-stdlib subpackages (``repro.analysis`` — the CI lint job
-runs it in a ruff-only environment with no JAX wheel) stay importable.
+Importing the package imports nothing else: the pure-stdlib subpackages
+(``repro.analysis`` — the CI lint job runs it in a ruff-only environment
+with no JAX wheel) stay importable without JAX.
 """
-try:
-    from repro import compat as _compat
-except ModuleNotFoundError as _e:
-    if _e.name not in ("jax", "jaxlib"):
-        raise
-else:
-    _compat.install()

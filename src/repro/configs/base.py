@@ -78,7 +78,9 @@ class ModelConfig:
     norm_eps: float = 1e-6
     dtype: str = "bfloat16"
     # runtime switches
-    use_kernels: bool = False     # Pallas path (tests/bench); XLA path for dry-run
+    # attention dispatch: None = Pallas kernels on a TPU backend and the
+    # XLA twins elsewhere (core/attn_spec.resolve_backend); True/False force
+    use_kernels: bool | None = None
     remat: bool = True
     # RG-LRU width (recurrentgemma); 0 -> d_model
     lru_width: int = 0

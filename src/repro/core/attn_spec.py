@@ -13,6 +13,10 @@ Three invariants make the spec safe as a static jit key:
      function is looked up (:func:`canonicalize`), so flipping
      ``softmax_state.set_default_mode`` can never serve a stale trace.
      This preserves the contract ``jit_with_rescale`` established.
+     ``use_kernels=None`` / ``interpret=None`` resolve from the backend
+     the same way (:func:`resolve_backend`): compiled Pallas kernels on
+     a TPU, XLA twins (and interpret mode, where a kernel is asked for)
+     elsewhere.
   2. **Projection onto the entry's used fields** — every entry declares
      which spec fields its trace depends on (``uses``); all other fields
      are canonicalized to their defaults before keying the cache, so
@@ -45,17 +49,19 @@ class AttnSpec:
     before the per-layer scale is known).  ``rescale=None`` means "the
     process default" and is resolved before any jit lookup.
     ``kv_splits=None`` means auto-scheduled; the legacy ``n_splits``
-    keyword aliases onto it.  ``spec_tokens``/``spec_draft`` configure
-    speculative decoding (0 = off) and are consumed by the serve loop and
-    ``model.verify_step`` — no kernel trace depends on them."""
+    keyword aliases onto it.  ``use_kernels=None`` / ``interpret=None``
+    mean "decided by the backend" (:func:`resolve_backend`).
+    ``spec_tokens``/``spec_draft`` configure speculative decoding (0 =
+    off) and are consumed by the serve loop and ``model.verify_step`` — no
+    kernel trace depends on them."""
     scale: float = 0.0
     mode: str = "etap"             # attention pipeline: etap | standard
     rescale: str | None = None     # online-softmax mode (None = default)
     kv_splits: int | None = None   # split-KV count (None = auto)
     kv_dtype: str = "fp"           # paged pool storage layout
     block: int = 512               # dense KV block size
-    use_kernels: bool = False      # dispatch to the Pallas kernels
-    interpret: bool = True         # Pallas interpret mode (CPU)
+    use_kernels: bool | None = None  # Pallas kernels (None: on a TPU)
+    interpret: bool | None = None    # Pallas interpret mode (None: off a TPU)
     spec_tokens: int = 0           # speculative draft length k (0 = off)
     spec_draft: str = "ngram"      # draft proposer: ngram | head
 
@@ -89,8 +95,21 @@ def coerce(spec: AttnSpec | None, legacy: dict, *,
             f"deprecated; pass spec=AttnSpec(...) instead",
             DeprecationWarning, stacklevel=3)
         kw = {LEGACY_ALIASES.get(k, k): v for k, v in legacy.items()}
-        return AttnSpec(**kw)
-    return spec if spec is not None else AttnSpec()
+        return resolve_backend(AttnSpec(**kw))
+    return resolve_backend(spec if spec is not None else AttnSpec())
+
+
+def resolve_backend(spec: AttnSpec) -> AttnSpec:
+    """Fill the backend-dependent fields left at None.  On a TPU the
+    Pallas kernels are the attention path and run compiled; on any other
+    backend attention takes the XLA twins, and a kernel asked for
+    explicitly runs in interpret mode.  Explicit values are kept."""
+    if spec.use_kernels is not None and spec.interpret is not None:
+        return spec
+    tpu = jax.default_backend() == "tpu"
+    return spec.replace(
+        use_kernels=tpu if spec.use_kernels is None else spec.use_kernels,
+        interpret=(not tpu) if spec.interpret is None else spec.interpret)
 
 
 def split_legacy(kw: dict) -> dict:
@@ -112,10 +131,12 @@ def project(spec: AttnSpec, uses) -> AttnSpec:
 
 
 def canonicalize(spec: AttnSpec, uses) -> AttnSpec:
-    """Project onto ``uses`` and resolve ``rescale`` to a concrete mode —
-    the full pre-jit-cache normalization of an entry wrapper."""
+    """Project onto ``uses`` and resolve ``rescale`` and the backend
+    fields to concrete values — the full pre-jit-cache normalization of an
+    entry wrapper."""
     spec = project(spec, uses)
-    return spec.replace(rescale=softmax_state.resolve(spec.rescale))
+    return resolve_backend(
+        spec.replace(rescale=softmax_state.resolve(spec.rescale)))
 
 
 def _spec_tag(spec: AttnSpec) -> str:

@@ -25,9 +25,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.core import attn_spec
 from repro.kernels import softmax_state
+from repro.sharding.rules import current_mesh
 
 NEG_INF = softmax_state.NEG_INF
 
@@ -144,7 +144,8 @@ def etap_partial_xla(q, k, v, length, *, scale: float, block: int = 512,
 
     init = softmax_state.init((BG, H), (BG, Dv, H))
     if vary_axis is not None:
-        init = jax.tree.map(lambda a: compat.pvary(a, vary_axis), init)
+        init = jax.tree.map(
+            lambda a: jax.lax.pcast(a, vary_axis, to="varying"), init)
     return jax.lax.fori_loop(0, nb, step, init)
 
 
@@ -218,7 +219,7 @@ def seq_sharded_decode(q, cache, new_row, pos, *, dv: int, scale: float,
     Returns (O [B,H,dv], updated cache)."""
     from jax.sharding import PartitionSpec as P
 
-    mesh = compat.get_mesh()
+    mesh = current_mesh()
     mode = softmax_state.resolve(rescale)
 
     # shard ids ride in as an axis-sharded operand instead of
@@ -256,11 +257,11 @@ def seq_sharded_decode(q, cache, new_row, pos, *, dv: int, scale: float,
 
     # manual ONLY over the model axis: batch (pod/data) sharding of q/cache
     # keeps propagating automatically outside the manual region.
-    return compat.shard_map(
+    return jax.shard_map(
         local, mesh=mesh, axis_names={axis},
         in_specs=(P(), P(None, axis, None), P(), P(), P(axis)),
         out_specs=(P(), P(None, axis, None)),
-        check=False,
+        check_vma=False,
     )(q, cache, new_row, pos, shard_ids)
 
 
@@ -269,9 +270,9 @@ def decode_attention(q, k, v, length=None, *, spec=None, **legacy):
     :class:`repro.core.attn_spec.AttnSpec`.
 
     spec.mode: "etap" (the paper) or "standard" (FlashMLA-like baseline).
-    spec.use_kernels: dispatch to the Pallas implementations (tests and
-    benchmarks run them with spec.interpret=True on CPU; on a real TPU
-    interpret=False).
+    spec.use_kernels: dispatch to the Pallas implementations (None: on a
+    TPU backend only, compiled; off a TPU they run in interpret mode —
+    see attn_spec.resolve_backend).
     spec.kv_splits: KV-split count for the two-phase split-KV pipeline.
     None → auto via kernels.etap.schedule (resolves to 1 at short contexts /
     large batches, i.e. exactly the old single-pass behaviour) on both the
@@ -556,7 +557,8 @@ def gqa_partial_xla(q, k, v, length, *, scale: float, block: int = 512,
 
     init = softmax_state.init((B, K, G), (B, K, Dv, G))
     if vary_axis is not None:
-        init = jax.tree.map(lambda a: compat.pvary(a, vary_axis), init)
+        init = jax.tree.map(
+            lambda a: jax.lax.pcast(a, vary_axis, to="varying"), init)
     return jax.lax.fori_loop(0, nb, step, init)
 
 
@@ -570,7 +572,7 @@ def seq_sharded_gqa_decode(q, k_cache, v_cache, new_k, new_v, pos, *,
     only the (m, l, accT) stats. q: [B,K,G,hd]; new_k/new_v: [B,K,hd].
     Returns (O [B,K*G,Dv], new k_cache, new v_cache)."""
     from jax.sharding import PartitionSpec as P
-    mesh = compat.get_mesh()
+    mesh = current_mesh()
     mode = softmax_state.resolve(rescale)
     B, K, G, Dk = q.shape
     Dv = v_cache.shape[3]
@@ -604,11 +606,11 @@ def seq_sharded_gqa_decode(q, k_cache, v_cache, new_k, new_v, pos, *,
         return o.reshape(B, K * G, Dv).astype(v_cache.dtype), kc, vc
 
     cspec = P(None, axis, None, None)
-    return compat.shard_map(
+    return jax.shard_map(
         local, mesh=mesh, axis_names={axis},
         in_specs=(P(), cspec, cspec, P(), P(), P(), P(axis)),
         out_specs=(P(), cspec, cspec),
-        check=False,
+        check_vma=False,
     )(q, k_cache, v_cache, new_k, new_v, pos, shard_ids)
 
 

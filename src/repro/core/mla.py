@@ -12,7 +12,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.core import attn_spec
 from repro.core.etap import (decode_attention, decode_attention_paged,
                              prefill_attention_paged, seq_sharded_decode,
@@ -117,8 +116,8 @@ def mla_decode(params, cfg, x, cache, pos, *, spec=None, **legacy):
     q = _absorbed_query(params, cfg, x, positions)
     c_t = _latent(params, cfg, x[:, None, :], positions)[:, 0]  # [B,latent]
     scale = m.qk_head_dim ** -0.5
-    from repro.sharding.rules import seq_shardable
-    mesh = compat.get_mesh()
+    from repro.sharding.rules import current_mesh, seq_shardable
+    mesh = current_mesh()
     seq_shard = seq_shardable(cache["c"].shape[1], mesh)
     if seq_shard:
         # latent cache is S-sharded over the model axis (no head dim to

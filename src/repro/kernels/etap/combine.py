@@ -30,8 +30,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 from repro.kernels import softmax_state
 
 
@@ -68,7 +68,7 @@ def combine_splits_pallas(m, l, acc, *, transposed: bool, out_dtype,
         ],
         out_specs=pl.BlockSpec((1, H, Dv), lambda b: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((BG, H, Dv), out_dtype),
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(m, l, acc)

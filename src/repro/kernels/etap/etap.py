@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 from repro.kernels import softmax_state
 
 NEG_INF = softmax_state.NEG_INF
@@ -155,7 +154,7 @@ def _call(q, k, v, length, *, scale, block, interpret, fused_dv, rescale):
         body,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((BG, H, Dv), (v if v is not None else k).dtype),
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(length.astype(jnp.int32), *operands)
@@ -238,7 +237,7 @@ def _paged_call(q, pool, v_pool, table, lengths, *, scale, interpret,
         body,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, Dv), out_dtype),
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(lengths.astype(jnp.int32), table.astype(jnp.int32), *operands)
@@ -270,8 +269,9 @@ def etap_decode_mla_paged_pallas(q, kv_pool, dv: int, table, lengths, *,
 # ---------------------------------------------------------- chunked prefill
 def _etap_prefill_body(start_ref, table_ref, q_ref, k_ref, v_ref, o_ref,
                        acc_ref, m_ref, l_ref, *, scale: float, page: int,
-                       nb: int, heads: int, fused_dv: int, rescale: str,
-                       k_sz_ref=None, v_sz_ref=None, qpos_ref=None):
+                       nb: int, heads: int, cols: int, fused_dv: int,
+                       rescale: str, k_sz_ref=None, v_sz_ref=None,
+                       qpos_ref=None):
     """Chunked paged ETAP prefill (DESIGN.md §9): the decode body with the
     single query row widened to a [Cq, H] tile, flattened to CH = Cq*H
     online-softmax columns.  The KV walk streams the sequence's pool blocks
@@ -282,33 +282,39 @@ def _etap_prefill_body(start_ref, table_ref, q_ref, k_ref, v_ref, o_ref,
     are fully masked and drop out with weight exp(-inf - m) = 0; block 0 of
     the walk always holds position 0, so no column is ever all-masked.
 
+    The grid is (b, column tile t, KV block j): each step holds ``cols`` of
+    the CH columns (see :func:`_col_tiling`).  Columns never interact —
+    every one carries its own (m, ℓ, Accᵀ) column — so tiling them is the
+    same math, whatever the tile.
+
     ``qpos_ref`` is the VERIFY generalization (DESIGN.md §14): an explicit
-    per-column absolute query position [1, CH] replaces the derived
+    per-column absolute query position [1, cols] replaces the derived
     ``start + c // H`` — the draft-verification mask where each scored
     chunk row attends to exactly the pool rows at or before its own
     position, independent of how the chunk maps onto the pool tail."""
-    j = pl.program_id(1)
+    j = pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
         softmax_state.init_refs(m_ref, l_ref, acc_ref)
 
     k_blk = _dequant(k_ref[0], k_sz_ref)               # [page, Dk]
-    q = q_ref[0]                                       # [CH, Dk]
+    q = q_ref[0]                                       # [cols, Dk]
     if k_sz_ref is not None:
         q = q.astype(jnp.float32)                      # match dequanted K
     # Sᵀ = K·Qᵀ — pool block rows on M, the Cq*H query tile on N.
     sT = jax.lax.dot_general(
         k_blk, q, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale    # [page, CH]
+        preferred_element_type=jnp.float32) * scale    # [page, cols]
 
     kpos = j * page + jax.lax.broadcasted_iota(jnp.int32, sT.shape, 0)
     if qpos_ref is None:
         start = start_ref[pl.program_id(0)]
-        qpos = start + jax.lax.broadcasted_iota(
-            jnp.int32, sT.shape, 1) // heads
+        col = pl.program_id(1) * cols + jax.lax.broadcasted_iota(
+            jnp.int32, sT.shape, 1)
+        qpos = start + col // heads
     else:
-        qpos = qpos_ref[0][None, :]                    # [1, CH] per-column
+        qpos = qpos_ref[0]                             # [1, cols] per-column
     sT = jnp.where(kpos <= qpos, sT, NEG_INF)          # causal chunk-vs-pool
 
     v_blk = k_blk[:, :fused_dv] if fused_dv else _dequant(v_ref[0], v_sz_ref)
@@ -316,7 +322,7 @@ def _etap_prefill_body(start_ref, table_ref, q_ref, k_ref, v_ref, o_ref,
         (m_ref[...], l_ref[...], acc_ref[...]), sT,
         lambda p: jax.lax.dot_general(
             v_blk, p, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32),       # [Dv, CH]
+            preferred_element_type=jnp.float32),       # [Dv, cols]
         axis=0, mode=rescale)
 
     @pl.when(j == nb - 1)
@@ -370,6 +376,21 @@ def _verify_body_quant_fused(start_ref, table_ref, q_ref, qpos_ref, k_ref,
                        acc, m, l, qpos_ref=qpos_ref, k_sz_ref=k_sz_ref, **kw)
 
 
+# Widest slice of the Cq*H query columns one prefill grid step holds.  The
+# Accᵀ scratch and the q/o blocks all grow with it: at latent 576 / Dv 512
+# in bf16, 4096 columns overflow v5e's scoped VMEM and 2048 fit.  A
+# multiple of 128: Mosaic needs that of the qpos row's block when it is
+# narrower than the array.
+PREFILL_COL_TILE = 2048
+
+
+def _col_tiling(CH: int):
+    """(cols per grid step, CH padded to a whole number of tiles)."""
+    if CH <= PREFILL_COL_TILE:
+        return CH, CH
+    return PREFILL_COL_TILE, -(-CH // PREFILL_COL_TILE) * PREFILL_COL_TILE
+
+
 def _prefill_call(q, pool, v_pool, table, start, *, heads, scale, interpret,
                   fused_dv, rescale, k_sz=None, v_sz=None, qpos=None):
     B, CH, Dk = q.shape
@@ -377,30 +398,44 @@ def _prefill_call(q, pool, v_pool, table, start, *, heads, scale, interpret,
     nb = table.shape[1]
     Dv = fused_dv or v_pool.shape[2]
     quant = k_sz is not None
+    cols, CHp = _col_tiling(CH)
+    if CHp != CH:
+        # pad columns are zero queries (horizon 0 when explicit — key 0 is
+        # always live, so none is all-masked) and are sliced off below
+        q = jnp.pad(q, ((0, 0), (0, CHp - CH), (0, 0)))
+        if qpos is not None:
+            qpos = jnp.pad(qpos, ((0, 0), (0, CHp - CH)))
+
+    def pool_spec(D):
+        return pl.BlockSpec((1, page, D),
+                            lambda b, t, j, lens, tab: (tab[b, j], 0, 0))
 
     in_specs = [
-        pl.BlockSpec((1, CH, Dk), lambda b, j, *_: (b, 0, 0)),           # q
+        pl.BlockSpec((1, cols, Dk), lambda b, t, j, *_: (b, t, 0)),      # q
     ]
     operands = [q]
     if qpos is not None:
-        # per-column absolute query positions: a whole [1, CH] int32 row per
-        # batch step (VMEM vector compare — no SMEM vector indexing)
-        in_specs.append(pl.BlockSpec((1, CH), lambda b, j, *_: (b, 0)))
-        operands.append(qpos.astype(jnp.int32))
-    in_specs.append(_pool_spec(page, Dk))                                # pool
+        # per-column absolute query positions as a [B, 1, CHp] int32 plane:
+        # a (1, cols) row per step is a VMEM vector compare (no SMEM vector
+        # indexing), and the unit axis keeps the block's last two dims
+        # legal for Mosaic at any batch
+        in_specs.append(pl.BlockSpec((1, 1, cols),
+                                     lambda b, t, j, *_: (b, 0, t)))
+        operands.append(qpos.astype(jnp.int32)[:, None, :])
+    in_specs.append(pool_spec(Dk))                                       # pool
     operands.append(pool)
     if quant:
-        in_specs.append(_pool_spec(page, 2))
+        in_specs.append(pool_spec(2))
         operands.append(k_sz)
     if not fused_dv:
-        in_specs.append(_pool_spec(page, Dv))
+        in_specs.append(pool_spec(Dv))
         operands.append(v_pool)
         if quant:
-            in_specs.append(_pool_spec(page, 2))
+            in_specs.append(pool_spec(2))
             operands.append(v_sz)
 
-    kw = dict(scale=scale, page=page, nb=nb, heads=heads, fused_dv=fused_dv,
-              rescale=softmax_state.resolve(rescale))
+    kw = dict(scale=scale, page=page, nb=nb, heads=heads, cols=cols,
+              fused_dv=fused_dv, rescale=softmax_state.resolve(rescale))
     if qpos is not None:
         body = functools.partial(
             (_verify_body_quant_fused if fused_dv else _verify_body_quant)
@@ -418,23 +453,24 @@ def _prefill_call(q, pool, v_pool, table, start, *, heads, scale, interpret,
                  else (v_pool if v_pool is not None else pool).dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, nb),
+        grid=(B, CHp // cols, nb),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, CH, Dv), lambda b, j, *_: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, cols, Dv), lambda b, t, j, *_: (b, t, 0)),
         scratch_shapes=[
-            pltpu.VMEM((Dv, CH), jnp.float32),                 # Accᵀ
-            pltpu.VMEM((1, CH), jnp.float32),                  # m
-            pltpu.VMEM((1, CH), jnp.float32),                  # ℓ
+            pltpu.VMEM((Dv, cols), jnp.float32),               # Accᵀ
+            pltpu.VMEM((1, cols), jnp.float32),                # m
+            pltpu.VMEM((1, cols), jnp.float32),                # ℓ
         ],
     )
-    return pl.pallas_call(
+    o = pl.pallas_call(
         body,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, CH, Dv), out_dtype),
-        compiler_params=compat.tpu_compiler_params(
-            dimension_semantics=("arbitrary", "arbitrary")),
+        out_shape=jax.ShapeDtypeStruct((B, CHp, Dv), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(start.astype(jnp.int32), table.astype(jnp.int32), *operands)
+    return o[:, :CH]
 
 
 def etap_prefill_paged_pallas(q, k_pool, v_pool, table, start, *,
@@ -503,6 +539,19 @@ def etap_verify_mla_paged_pallas(q, kv_pool, dv: int, table, start, qpos, *,
 
 
 # ------------------------------------------------------- split-KV (phase 1)
+def _partial_out_specs(H, Dv):
+    """Phase-1 output blocks, one (b, split) pair per grid step.  m and ℓ
+    carry a unit axis, [B, n_splits, 1, H] blocked (1, 1, 1, H): Mosaic
+    needs a block's last two dims to be (8, 128)-divisible or to equal the
+    array's, and a (1, H) row of an [n_splits, H] plane is neither.  The
+    wrappers drop the unit axis, so callers still see [B, n_splits, H]."""
+    return [
+        pl.BlockSpec((1, 1, 1, H), lambda b, s, j, *_: (b, s, 0, 0)),    # m
+        pl.BlockSpec((1, 1, 1, H), lambda b, s, j, *_: (b, s, 0, 0)),    # ℓ
+        pl.BlockSpec((1, 1, Dv, H), lambda b, s, j, *_: (b, s, 0, 0)),   # Accᵀ
+    ]
+
+
 def _etap_partial_body(length_ref, q_ref, k_ref, v_ref,
                        m_out_ref, l_out_ref, acc_out_ref,
                        acc_ref, m_ref, l_ref, *, scale: float, block: int,
@@ -543,8 +592,8 @@ def _etap_partial_body(length_ref, q_ref, k_ref, v_ref,
 
     @pl.when(j == npb - 1)
     def _emit():
-        m_out_ref[0] = m_ref[...]
-        l_out_ref[0] = l_ref[...]
+        m_out_ref[0, 0] = m_ref[...]
+        l_out_ref[0, 0] = l_ref[...]
         acc_out_ref[0, 0] = acc_ref[...]
 
 
@@ -589,31 +638,28 @@ def etap_partial_pallas(q, k, v, length, *, scale: float, block: int,
         num_scalar_prefetch=1,
         grid=(BG, n_splits, npb),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, H), lambda b, s, j, *_: (b, s, 0)),      # m
-            pl.BlockSpec((1, 1, H), lambda b, s, j, *_: (b, s, 0)),      # ℓ
-            pl.BlockSpec((1, 1, Dv, H), lambda b, s, j, *_: (b, s, 0, 0)),
-        ],
+        out_specs=_partial_out_specs(H, Dv),
         scratch_shapes=[
             pltpu.VMEM((Dv, H), jnp.float32),                  # Accᵀ
             pltpu.VMEM((1, H), jnp.float32),                   # m
             pltpu.VMEM((1, H), jnp.float32),                   # ℓ
         ],
     )
-    return pl.pallas_call(
+    m, l, accT = pl.pallas_call(
         body,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((BG, n_splits, H), jnp.float32),
-            jax.ShapeDtypeStruct((BG, n_splits, H), jnp.float32),
+            jax.ShapeDtypeStruct((BG, n_splits, 1, H), jnp.float32),
+            jax.ShapeDtypeStruct((BG, n_splits, 1, H), jnp.float32),
             jax.ShapeDtypeStruct((BG, n_splits, Dv, H), jnp.float32),
         ],
         # splits are independent work items — only the within-split KV walk
         # is a sequential accumulation.
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(length.astype(jnp.int32), *operands)
+    return m[:, :, 0], l[:, :, 0], accT
 
 
 def _paged_partial_body(length_ref, table_ref, q_ref, k_ref, v_ref,
@@ -699,26 +745,23 @@ def etap_paged_partial_pallas(q, k_pool, v_pool, table, lengths, *,
         num_scalar_prefetch=2,
         grid=(B, n_splits, npb),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, H), lambda b, s, j, *_: (b, s, 0)),      # m
-            pl.BlockSpec((1, 1, H), lambda b, s, j, *_: (b, s, 0)),      # ℓ
-            pl.BlockSpec((1, 1, Dv, H), lambda b, s, j, *_: (b, s, 0, 0)),
-        ],
+        out_specs=_partial_out_specs(H, Dv),
         scratch_shapes=[
             pltpu.VMEM((Dv, H), jnp.float32),                  # Accᵀ
             pltpu.VMEM((1, H), jnp.float32),                   # m
             pltpu.VMEM((1, H), jnp.float32),                   # ℓ
         ],
     )
-    return pl.pallas_call(
+    m, l, accT = pl.pallas_call(
         body,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, n_splits, H), jnp.float32),
-            jax.ShapeDtypeStruct((B, n_splits, H), jnp.float32),
+            jax.ShapeDtypeStruct((B, n_splits, 1, H), jnp.float32),
+            jax.ShapeDtypeStruct((B, n_splits, 1, H), jnp.float32),
             jax.ShapeDtypeStruct((B, n_splits, Dv, H), jnp.float32),
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(lengths.astype(jnp.int32), table.astype(jnp.int32), *operands)
+    return m[:, :, 0], l[:, :, 0], accT

@@ -17,7 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 from repro.kernels import softmax_state
 
 NEG_INF = softmax_state.NEG_INF
@@ -85,7 +84,7 @@ def flash_decode_pallas(q, k, v, length, *, scale: float, block: int = 512,
                           rescale=softmax_state.resolve(rescale)),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((BG, H, Dv), v.dtype),
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(length.astype(jnp.int32), q, k, v)
@@ -128,8 +127,8 @@ def _partial_body(length_ref, q_ref, k_ref, v_ref,
 
     @pl.when(j == npb - 1)
     def _emit():
-        m_out_ref[0] = m_ref[...].T                     # [1, H]
-        l_out_ref[0] = l_ref[...].T
+        m_out_ref[0, 0] = m_ref[...].T                  # [1, H]
+        l_out_ref[0, 0] = l_ref[...].T
         acc_out_ref[0, 0] = acc_ref[...]
 
 
@@ -154,9 +153,11 @@ def flash_decode_partial_pallas(q, k, v, length, *, scale: float, block: int,
             pl.BlockSpec((1, block, Dv),
                          lambda b, s, j, *_, npb=npb: (b, s * npb + j, 0)),
         ],
+        # m/ℓ carry a unit axis so their (1, H) block rows equal the
+        # array's last two dims (Mosaic's tiling rule); dropped below
         out_specs=[
-            pl.BlockSpec((1, 1, H), lambda b, s, j, *_: (b, s, 0)),
-            pl.BlockSpec((1, 1, H), lambda b, s, j, *_: (b, s, 0)),
+            pl.BlockSpec((1, 1, 1, H), lambda b, s, j, *_: (b, s, 0, 0)),
+            pl.BlockSpec((1, 1, 1, H), lambda b, s, j, *_: (b, s, 0, 0)),
             pl.BlockSpec((1, 1, H, Dv), lambda b, s, j, *_: (b, s, 0, 0)),
         ],
         scratch_shapes=[
@@ -165,16 +166,17 @@ def flash_decode_partial_pallas(q, k, v, length, *, scale: float, block: int,
             pltpu.VMEM((H, 1), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    m, l, acc = pl.pallas_call(
         functools.partial(_partial_body, scale=scale, block=block, npb=npb,
                           rescale=softmax_state.resolve(rescale)),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((BG, n_splits, H), jnp.float32),
-            jax.ShapeDtypeStruct((BG, n_splits, H), jnp.float32),
+            jax.ShapeDtypeStruct((BG, n_splits, 1, H), jnp.float32),
+            jax.ShapeDtypeStruct((BG, n_splits, 1, H), jnp.float32),
             jax.ShapeDtypeStruct((BG, n_splits, H, Dv), jnp.float32),
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(length.astype(jnp.int32), q, k, v)
+    return m[:, :, 0], l[:, :, 0], acc

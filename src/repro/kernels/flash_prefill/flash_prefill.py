@@ -16,7 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 from repro.kernels import softmax_state
 
 NEG_INF = softmax_state.NEG_INF
@@ -99,7 +98,7 @@ def flash_prefill_pallas(q, k, v, *, scale: float, bq: int = 256,
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
         out_shape=jax.ShapeDtypeStruct((B * H, S, Dv), v.dtype),
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(qh, kh, vh)

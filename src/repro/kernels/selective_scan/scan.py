@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
 
 def _body(dA_ref, dBx_ref, c_ref, y_ref, hout_ref, h_ref, *, chunk: int,
           nchunks: int):
@@ -78,7 +76,7 @@ def selective_scan_pallas(dA, dBx, c, *, chunk: int = 256,
         out_shape=[jax.ShapeDtypeStruct((B, L, D), jnp.float32),
                    jax.ShapeDtypeStruct((B, D, N), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((d_block, N), jnp.float32)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(dA, dBx, c)

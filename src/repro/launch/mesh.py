@@ -4,8 +4,6 @@ from __future__ import annotations
 
 import jax
 
-from repro import compat
-
 
 def make_production_mesh(*, multi_pod: bool = False):
     """Single pod: 256 chips as (data=16, model=16). Multi-pod: 2 pods = 512
@@ -13,12 +11,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     batch/FSDP sharding and carries the cross-pod (DCN-class) collectives."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    types = (jax.sharding.AxisType.Auto,) * len(axes)  # compat backfills
-    return compat.make_mesh(shape, axes, axis_types=types)
+    types = (jax.sharding.AxisType.Auto,) * len(axes)
+    return jax.make_mesh(shape, axes, axis_types=types)
 
 
 def make_host_mesh():
     """Whatever this host actually has (tests / examples)."""
     n = len(jax.devices())
-    return compat.make_mesh((n, 1), ("data", "model"),
-                            axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    return jax.make_mesh((n, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)

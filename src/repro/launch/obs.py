@@ -27,9 +27,10 @@ profiler); this module owns everything user-facing:
                    request id's lifecycle instants.
 
   kernel_report()  joins ``--profile-kernels`` launch timings against the
-                   ``launch/roofline.py`` memory-bandwidth model:
+                   device's HBM peak from ``launch/roofline.py``:
                    per-entry achieved GB/s and achieved-vs-roofline
-                   fraction (memory-floor time / measured wall time).
+                   fraction (memory-floor time / measured wall time);
+                   "not measured" off a TPU.
 """
 from __future__ import annotations
 
@@ -97,34 +98,44 @@ def _geometry_bytes(geometry: tuple) -> int:
     return total
 
 
-def kernel_report(prof) -> list:
+def kernel_report(prof, device=None) -> list:
     """Per-(entry, spec, geometry) profile rows joined against the
-    roofline memory floor, slowest first."""
+    roofline memory floor of ``device`` (default: the first JAX device),
+    slowest first.  Off a TPU there is no device to hold to a roofline:
+    ``achieved_gbps`` and ``roofline_fraction`` are None ("not measured")."""
+    device = device if device is not None else jax.devices()[0]
+    bw = (roofline.peaks(device.device_kind).hbm_bw
+          if device.platform == "tpu" else None)
     rows = []
     for (name, tag, geometry), (count, total_s) in prof.records.items():
         mean_s = total_s / max(count, 1)
         byts = _geometry_bytes(geometry)
-        floor_s = byts / roofline.HBM_BW
         rows.append({
             "entry": name, "spec": tag, "launches": count,
             "mean_us": mean_s * 1e6, "total_ms": total_s * 1e3,
-            "arg_bytes": byts,
-            "achieved_gbps": byts / max(mean_s, 1e-12) / 1e9,
-            "roofline_fraction": floor_s / max(mean_s, 1e-12),
+            "arg_bytes": byts, "hbm_bw": bw,
+            "achieved_gbps": (byts / max(mean_s, 1e-12) / 1e9
+                              if bw else None),
+            "roofline_fraction": (byts / bw / max(mean_s, 1e-12)
+                                  if bw else None),
         })
     rows.sort(key=lambda r: -r["total_ms"])
     return rows
 
 
 def kernel_report_lines(rows, sampled: int, sample_every: int) -> list:
+    bw = rows[0]["hbm_bw"] if rows else None
+    floor = (f"HBM-roofline floor ({bw / 1e9:.0f} GB/s)" if bw
+             else "HBM-roofline floor: not measured (no TPU)")
     lines = [f"[serve] kernel profile: {sampled} sampled launches "
-             f"(every {sample_every}); achieved vs HBM-roofline floor "
-             f"({roofline.HBM_BW / 1e9:.0f} GB/s):"]
+             f"(every {sample_every}); achieved vs {floor}:"]
     for r in rows:
+        share = (f"{r['achieved_gbps']:.3g} GB/s, "
+                 f"{r['roofline_fraction']:.3g} of roofline"
+                 if r["roofline_fraction"] is not None else "not measured")
         lines.append(
             f"[serve]   {r['entry']}: {r['launches']}x "
-            f"{r['mean_us']:.0f}us/launch, {r['achieved_gbps']:.3g} GB/s, "
-            f"{r['roofline_fraction']:.3g} of roofline ({r['spec']})")
+            f"{r['mean_us']:.0f}us/launch, {share} ({r['spec']})")
     return lines
 
 

@@ -1,9 +1,9 @@
 """Roofline-term extraction from compiled dry-run artifacts (DESIGN.md §7).
 
-TPU v5e constants (per chip):
-    peak bf16 compute : 197 TFLOP/s
-    HBM bandwidth     : 819 GB/s
-    ICI per link      : ~50 GB/s
+Peaks are per chip and keyed by ``jax.Device.device_kind`` (:data:`PEAKS`);
+a device kind missing from the table is an error, never a default.  The
+dry-run and the split-KV model project onto a described v5e (:data:`V5E`),
+since that is the chip the production mesh is built from.
 
 The SPMD-partitioned HLO module is the *per-device* program, so
 cost_analysis() FLOPs/bytes are per-chip already:
@@ -18,9 +18,34 @@ from __future__ import annotations
 import re
 from dataclasses import dataclass, field
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
+
+@dataclass(frozen=True)
+class Peaks:
+    """Published per-chip peaks."""
+    flops: float      # dense bf16 FLOP/s
+    hbm_bw: float     # HBM bytes/s
+    ici_bw: float     # bytes/s per ICI link
+    source: str
+
+
+V5E = "TPU v5 lite"   # what jax reports as a v5e chip's device_kind
+
+PEAKS = {
+    V5E: Peaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9,
+               source="Google Cloud documentation, 'TPU v5e': 197 TFLOP/s "
+                      "bf16, 819 GB/s HBM, 1,600 Gbit/s ICI over 4 links"),
+}
+
+
+def peaks(device_kind: str) -> Peaks:
+    """The published peaks of ``device_kind``; unknown kinds raise."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; add them "
+            f"to roofline.PEAKS with their source") from None
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -81,6 +106,7 @@ def roofline_terms_from_analysis(analysis: dict) -> dict:
     """Terms from hlo_analysis.analyze() (trip-count-aware — the primary
     source; cost_analysis() counts while bodies once and is kept only as a
     cross-check column)."""
+    pk = PEAKS[V5E]
     flops = float(analysis["flops"])
     byts = float(analysis["bytes"])
     coll = float(analysis["collective_bytes"])
@@ -88,9 +114,9 @@ def roofline_terms_from_analysis(analysis: dict) -> dict:
         "hlo_flops": flops,
         "hlo_bytes": byts,
         "collective_bytes": coll,
-        "t_compute": flops / PEAK_FLOPS,
-        "t_memory": byts / HBM_BW,
-        "t_collective": coll / ICI_BW,
+        "t_compute": flops / pk.flops,
+        "t_memory": byts / pk.hbm_bw,
+        "t_collective": coll / pk.ici_bw,
     }
     dom = max(("t_compute", "t_memory", "t_collective"), key=lambda k: out[k])
     out["bottleneck"] = dom[2:]
@@ -100,15 +126,16 @@ def roofline_terms_from_analysis(analysis: dict) -> dict:
 
 
 def roofline_terms(cost: dict, coll: CollectiveStats) -> dict:
+    pk = PEAKS[V5E]
     flops = float(cost.get("flops", 0.0))
     byts = float(cost.get("bytes accessed", 0.0))
     out = {
         "hlo_flops": flops,
         "hlo_bytes": byts,
         "collective_bytes": float(coll.total_bytes),
-        "t_compute": flops / PEAK_FLOPS,
-        "t_memory": byts / HBM_BW,
-        "t_collective": coll.total_bytes / ICI_BW,
+        "t_compute": flops / pk.flops,
+        "t_memory": byts / pk.hbm_bw,
+        "t_collective": coll.total_bytes / pk.ici_bw,
     }
     dom = max(("t_compute", "t_memory", "t_collective"), key=lambda k: out[k])
     out["bottleneck"] = dom[2:]
@@ -129,6 +156,7 @@ def splitkv_roofline(BG: int, S: int, H: int, Dk: int, Dv: int,
     cap is exactly the requirement that `overhead` stays ≪ 1 here."""
     from repro.kernels.etap.schedule import DEFAULT_CORES
 
+    pk = PEAKS[V5E]
     q_bytes = BG * H * Dk * kv_itemsize
     kv_bytes = BG * S * (Dk if mla_fused else Dk + Dv) * kv_itemsize
     stat_bytes = BG * n_splits * (2 * H + Dv * H) * 4
@@ -136,9 +164,9 @@ def splitkv_roofline(BG: int, S: int, H: int, Dk: int, Dv: int,
     flops = 2.0 * BG * S * H * (Dk + Dv)
 
     occupancy = min(1.0, BG * n_splits / DEFAULT_CORES)
-    t_partial_mem = (q_bytes + kv_bytes + stat_bytes) / HBM_BW
-    t_partial_compute = flops / (PEAK_FLOPS * occupancy)
-    t_combine = (stat_bytes + o_bytes) / HBM_BW
+    t_partial_mem = (q_bytes + kv_bytes + stat_bytes) / pk.hbm_bw
+    t_partial_compute = flops / (pk.flops * occupancy)
+    t_combine = (stat_bytes + o_bytes) / pk.hbm_bw
     t_total = max(t_partial_mem, t_partial_compute) + t_combine
     return {
         "kv_bytes": kv_bytes,

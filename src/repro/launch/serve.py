@@ -1,6 +1,8 @@
 """Serving driver: batched prefill + autoregressive decode with the ETAP
-pipeline (the paper's workload). Real execution on host devices with
-reduced configs; production-mesh serving is proven by dryrun.py.
+pipeline (the paper's workload). Runs on CPU with reduced configs and on
+one TPU at DeepSeek-R1 chip-share widths (chip_smoke.py), where attention
+takes the compiled ETAP kernels; production-mesh serving is proven by
+dryrun.py.
 
 Two cache layouts:
 
@@ -180,7 +182,7 @@ def _make_requests(args, vocab: int):
     return reqs
 
 
-def run_paged(args, cfg) -> dict:
+def run_paged(args, cfg, *, record_logits: bool = False) -> dict:
     """Continuous-batching serve loop: CHUNKED paged prefill interleaved
     with decode under a per-step token budget, driven by the SLO-aware
     scheduler (runtime/scheduler.py, DESIGN.md §9/§12).
@@ -226,7 +228,11 @@ def run_paged(args, cfg) -> dict:
 
     Re-tracing is bounded: prefill_chunk compiles once per distinct chunk
     size, and chunk sizes are min(--prefill-chunk, remaining prompt) over
-    the quantized prompt buckets of :func:`_make_requests`."""
+    the quantized prompt buckets of :func:`_make_requests`.
+
+    ``record_logits=True`` fills the returned ``seed_logits`` and
+    ``first_decode_logits`` ({request id: fp32 logits row}); otherwise
+    both stay empty and no logits leave the device."""
     # one fresh registry per run — back-to-back runs in one process
     # (tests, benchmarks) must never mix counters.  Every subsystem below
     # (pool, scheduler, heartbeats, injector, drafter) writes into it.
@@ -384,6 +390,10 @@ def run_paged(args, cfg) -> dict:
     g_queued = reg.gauge("sched/queued")
     g_running = reg.gauge("sched/running")
     t_prefill = 0.0
+    # record_logits: each request's last prefill logits and first decode
+    # step's logits, both computed from inputs two attention paths share —
+    # what a comparison of the paths holds to its bound (chip_smoke.py)
+    seed_logits, first_logits = {}, {}
 
     # profiler installed AFTER warmup: compile-time launches never land in
     # the records; cleared in the finally so one run can't leak its
@@ -467,6 +477,9 @@ def run_paged(args, cfg) -> dict:
                 c_chunks.inc()
                 if r.pf_pos == plen:          # prompt done -> start decoding
                     seed = int(jnp.argmax(logits[0, -1]))
+                    if record_logits:
+                        seed_logits.setdefault(
+                            r.id, np.asarray(logits[0, -1], np.float32))
                     if r.replay:
                         # restored victim: the re-prefill must re-derive the
                         # first delivered token bit-for-bit (grid invariant)
@@ -518,6 +531,12 @@ def run_paged(args, cfg) -> dict:
                         params, holder["cache"], jnp.array(cur_arr, jnp.int32),
                         jnp.array(table_m), jnp.array(lens_m))
                     nxt = np.asarray(jnp.argmax(logits, axis=-1))
+                    new = ([r for r in plain if r.id not in first_logits]
+                           if record_logits else [])
+                    if new:
+                        rows = np.asarray(logits, np.float32)
+                        first_logits.update({r.id: rows[r.slot]
+                                             for r in new})
                     if tracer is not None:
                         tracer.complete("decode_step", ts,
                                         args={"slots": len(plain)})
@@ -686,6 +705,8 @@ def run_paged(args, cfg) -> dict:
                           c_spec_acc.value / max(c_spec_prop.value, 1)}
                      if k_max > 0 else None),
             "metrics": snap, "kernel_report": krep,
+            "seed_logits": seed_logits,
+            "first_decode_logits": first_logits,
             "t_prefill": t_prefill, "t_decode": t_decode}
 
 
@@ -866,4 +887,6 @@ def parse_args(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+    compile_cache.enable()
     run(parse_args())

@@ -13,13 +13,13 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core import attn_spec
 from repro.core.etap import (decode_attention, gqa_decode_xla, gqa_to_grouped,
                              seq_sharded_gqa_decode)
 from repro.models import layers
 from repro.runtime import paged_cache
-from repro.sharding.rules import BATCH, constrain, seq_shardable
+from repro.sharding.rules import (BATCH, constrain, current_mesh,
+                                  seq_shardable)
 
 NEG_INF = -1e30
 
@@ -27,7 +27,7 @@ NEG_INF = -1e30
 def _score_constraint(s):
     """Scores [B,H,q,S]: shard heads over `model` when divisible, else fall
     back to sharding the q-position dim (e.g. llava's 56 heads on TP16)."""
-    mesh = compat.get_mesh()
+    mesh = current_mesh()
     if mesh is None or "model" not in mesh.axis_names:
         return s
     if s.shape[1] % mesh.shape["model"] == 0:
@@ -209,8 +209,10 @@ def attention_decode(params, cfg, x, cache, pos, *, spec=None, **legacy):
     Smax = cache["k"].shape[1]
     K = k.shape[1]
     scale = cfg.resolved_head_dim ** -0.5
-    mesh = compat.get_mesh()
-    seq_shard = (cfg.attention_kind == "full" and not cfg.use_kernels
+    spec = attn_spec.resolve_backend(
+        spec.replace(scale=scale, use_kernels=cfg.use_kernels))
+    mesh = current_mesh()
+    seq_shard = (cfg.attention_kind == "full" and not spec.use_kernels
                  and seq_shardable(Smax, mesh))
     if seq_shard:
         # big full-attention cache: S-sharded over `model` (shard_map partial
@@ -224,8 +226,7 @@ def attention_decode(params, cfg, x, cache, pos, *, spec=None, **legacy):
         vc = jax.lax.dynamic_update_index_in_dim(cache["v"], v, slot, 1)
         length = jnp.minimum(pos + 1, Smax)
         o = gqa_decode(q, kc, vc, jnp.full((B,), length, jnp.int32),
-                       spec=spec.replace(scale=scale,
-                                         use_kernels=cfg.use_kernels))
+                       spec=spec)
     out = layers.dense(o.reshape(B, -1), params["w_o"])
     return out, {"k": kc, "v": vc}
 

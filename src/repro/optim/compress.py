@@ -69,9 +69,9 @@ def pod_reduce_with_feedback(grads, residual, axis: str = "pod"):
         q, s = quantize(g32)
         deq = dequantize(q, s, g32.shape)
         new_r = g32 - deq
-        from repro import compat
-        mesh = compat.get_mesh()
-        if mesh is not None and axis in getattr(mesh, "axis_names", ()):
+        from repro.sharding.rules import current_mesh
+        mesh = current_mesh()
+        if mesh is not None and axis in mesh.axis_names:
             try:
                 deq = jax.lax.psum(deq, axis) / mesh.shape[axis]
             except NameError:

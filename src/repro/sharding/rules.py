@@ -16,19 +16,23 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
-
 
 def batch_axes(mesh: Mesh):
     return ("pod", "data") if "pod" in mesh.axis_names else ("data",)
+
+
+def current_mesh():
+    """The mesh set by ``jax.set_mesh``, or None outside one."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def constrain(x, spec: P):
     """Best-effort with_sharding_constraint: no-op outside a mesh context,
     and silently drops mesh axes that are absent or don't divide the dim
     (e.g. a 15-head tensor on a 16-way model axis stays unsharded)."""
-    mesh = compat.get_mesh()
-    if mesh is None or not mesh.axis_names:
+    mesh = current_mesh()
+    if mesh is None:
         return x
     entries = [_fit(e, x.shape[i], mesh) for i, e in enumerate(spec)]
     entries += [None] * (x.ndim - len(entries))

@@ -54,3 +54,16 @@ def fp64_oracle():
         yield Oracle
     finally:
         jax.config.update("jax_enable_x64", prev)
+
+
+@pytest.fixture(scope="session")
+def chip_smoke():
+    """The repo-root ``chip_smoke.py`` as a module (its config builder and
+    its kernel-vs-XLA comparison), loaded without running it."""
+    import importlib.util
+    from pathlib import Path
+    path = Path(__file__).resolve().parents[1] / "chip_smoke.py"
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod

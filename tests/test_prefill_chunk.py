@@ -94,6 +94,36 @@ def test_prefill_kernel_mla_fused_vs_ref():
     assert _rmse(out, ref) <= 1e-4
 
 
+@pytest.mark.parametrize("verify", [False, True], ids=["prefill", "verify"])
+def test_prefill_kernel_column_tiles_exact(monkeypatch, verify):
+    """Tiling the Cq*H column axis over the grid — with a padded last tile
+    — is the same math: every column carries its own softmax state."""
+    from repro.kernels.etap import etap as kern
+    B, H, D, dv, page = 2, 4, 48, 32, 16
+    q = jnp.asarray(RNG.normal(size=(B, CQ, H, D)), jnp.float32)
+    kv = jnp.asarray(RNG.normal(size=(B, S, D)), jnp.float32)
+    start = jnp.asarray(STARTS[:B], jnp.int32)
+    pool, bp = pc.dense_to_paged(kv, [s + CQ for s in STARTS[:B]],
+                                 pc.layout_for(B, S, page))
+    table, _ = bp.device_views()
+    qpos = start[:, None] + jnp.arange(CQ, dtype=jnp.int32)[None, :]
+
+    def run():
+        if verify:
+            return kern.etap_verify_mla_paged_pallas(
+                q, pool, dv, table, start, qpos, scale=D ** -0.5)
+        return kern.etap_prefill_mla_paged_pallas(
+            q, pool, dv, table, start, scale=D ** -0.5)
+
+    whole = run()
+    monkeypatch.setattr(kern, "PREFILL_COL_TILE", 16)
+    assert kern._col_tiling(CQ * H) == (16, 48)     # 44 columns: 3 tiles
+    tiled = run()
+    assert np.array_equal(np.asarray(tiled), np.asarray(whole))
+    ref = _ref_prefill(q, kv, np.asarray(kv)[..., :dv], STARTS[:B])
+    assert _rmse(tiled, ref) <= 1e-4
+
+
 def test_prefill_kernel_shuffled_table():
     """The prefill kernel must follow the TABLE, not physical pool order."""
     page, n, H, Dk = 16, 6, 4, 32

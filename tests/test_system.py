@@ -133,3 +133,29 @@ def test_etap_used_in_model_decode_matches_kernel():
     d_xla, _ = model.decode_step(params, cfg, cache, tokens[:, 8], pos)
     d_krn, _ = model.decode_step(params, cfg_k, cache, tokens[:, 8], pos)
     np.testing.assert_allclose(np.asarray(d_xla), np.asarray(d_krn), atol=2e-4)
+
+
+def test_serve_kernel_and_xla_paths_agree(chip_smoke):
+    """run_paged on the Pallas kernels and on the XLA twins: the same
+    tokens, and the per-request logits chip_smoke.py compares (each
+    request's last prefill logits and first decode step) agree — fp32
+    paths match to rounding, so the chip run's bound is all bf16."""
+    import dataclasses
+
+    from repro.launch import serve
+
+    cfg = reduced(get_config("deepseek_r1_671b"))
+    args = serve.parse_args(["--reduced", "--batch", "2", "--requests", "3",
+                             "--prompt", "24", "--gen", "4",
+                             "--prefill-chunk", "8", "--shared-prefix", "8"])
+    res = {uk: serve.run_paged(args, dataclasses.replace(cfg, use_kernels=uk),
+                               record_logits=True)
+           for uk in (True, False)}
+    assert res[True]["outputs"] == res[False]["outputs"]
+    for key in ("seed_logits", "first_decode_logits"):
+        assert sorted(res[True][key]) == [0, 1, 2]
+        for rid, row in res[False][key].items():
+            np.testing.assert_allclose(res[True][key][rid], row, atol=1e-5)
+    cmp = chip_smoke.compare(res[False], res[True])
+    assert cmp["ok"] and cmp["token_agreement"] == 1.0
+    assert cmp["decode_rows"] == 3
