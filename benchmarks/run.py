@@ -746,7 +746,7 @@ def bench_obs():
         assert evs and all(k in e for e in evs
                            for k in ("ph", "ts", "pid", "tid", "name"))
         names = {e["name"] for e in evs}
-        assert {"prefill_chunk", "decode_step"} <= names, names
+        assert {"serve.prefill.wait", "serve.decode.wait"} <= names, names
         with open(mpath) as f:
             met = json.load(f)
         assert met["meta"]["schema_version"] == telemetry.OBS_SCHEMA_VERSION

@@ -31,13 +31,11 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import time
 import warnings
 
 import jax
 
 from repro.kernels import softmax_state
-from repro.runtime import telemetry
 
 
 @dataclasses.dataclass(frozen=True)
@@ -139,28 +137,6 @@ def canonicalize(spec: AttnSpec, uses) -> AttnSpec:
         spec.replace(rescale=softmax_state.resolve(spec.rescale)))
 
 
-def _spec_tag(spec: AttnSpec) -> str:
-    """Compact spec label for profiler records — the fields that select a
-    kernel family, not the full repr."""
-    return (f"mode={spec.mode} rescale={spec.rescale} "
-            f"kv={spec.kv_dtype} splits={spec.kv_splits}")
-
-
-def _geometry(args, kw) -> tuple:
-    """Hashable (shape, dtype) summary of the array arguments — what the
-    profiler aggregates launches by."""
-    geo = []
-    for a in args:
-        shape = getattr(a, "shape", None)
-        if shape is not None:
-            geo.append((tuple(shape), str(getattr(a, "dtype", "?"))))
-    for k in sorted(kw):
-        shape = getattr(kw[k], "shape", None)
-        if shape is not None:
-            geo.append((k, tuple(shape), str(getattr(kw[k], "dtype", "?"))))
-    return tuple(geo)
-
-
 def attn_entry(*, uses=(), static_argnames=()):
     """Decorator for public attention entry points.
 
@@ -170,17 +146,7 @@ def attn_entry(*, uses=(), static_argnames=()):
     ``uses`` + rescale resolution) BEFORE the jit-cache lookup, and calls
     the jitted body with ``spec`` as a static argument.  Non-spec
     keywords (``k_sz``, ``combine``, ...) pass through untouched;
-    ``static_argnames`` lists the non-spec statics among them.
-
-    This wrapper is also the kernel-profiling choke point: when a
-    :class:`repro.runtime.telemetry.KernelProfiler` is installed
-    (``--profile-kernels``), sampled launches run under
-    ``block_until_ready`` and are recorded with the spec tag + argument
-    geometry.  Profiling only engages OUTSIDE other traces — if any
-    argument is a tracer the entry is being inlined into an enclosing
-    jit, where wall-timing is meaningless and ``block_until_ready``
-    invalid — and never changes the computation (same jitted call either
-    way; forcing completion is a scheduling effect only)."""
+    ``static_argnames`` lists the non-spec statics among them."""
     def deco(fn):
         jfn = jax.jit(fn, static_argnames=("spec",) + tuple(static_argnames))
 
@@ -189,16 +155,6 @@ def attn_entry(*, uses=(), static_argnames=()):
             legacy = split_legacy(kw)
             s = coerce(spec, legacy, where=fn.__name__)
             s = canonicalize(s, uses)
-            prof = telemetry.profiler()
-            if (prof is not None
-                    and not any(isinstance(a, jax.core.Tracer) for a in args)
-                    and prof.want()):
-                t0 = time.perf_counter()
-                out = jfn(*args, spec=s, **kw)
-                jax.block_until_ready(out)
-                prof.record(fn.__name__, _spec_tag(s), _geometry(args, kw),
-                            time.perf_counter() - t0)
-                return out
             return jfn(*args, spec=s, **kw)
 
         wrapper.__wrapped_jit__ = jfn

@@ -1,7 +1,7 @@
 """Serve-loop observability front end (DESIGN.md §15).
 
-``runtime/telemetry.py`` owns the primitives (registry / tracer /
-profiler); this module owns everything user-facing:
+``runtime/telemetry.py`` owns the primitives (registry / tracer); this
+module owns everything user-facing:
 
   emit()           the ONE sanctioned stdout chokepoint for serve-loop
                    reporting.  ``benchmarks/lint_prints.py`` fails CI on
@@ -23,14 +23,9 @@ profiler); this module owns everything user-facing:
   write_trace()    ``--trace-out``: Chrome trace-event JSON.  Open in
                    https://ui.perfetto.dev (drag the file in) or
                    chrome://tracing.  Thread 0 is the engine timeline
-                   (prefill/decode/verify spans); thread 1000+id is
-                   request id's lifecycle instants.
-
-  kernel_report()  joins ``--profile-kernels`` launch timings against the
-                   device's HBM peak from ``launch/roofline.py``:
-                   per-entry achieved GB/s and achieved-vs-roofline
-                   fraction (memory-floor time / measured wall time);
-                   "not measured" off a TPU.
+                   (the serve loop's spans, each request's carrying
+                   ``req``); thread 1000+id is request id's lifecycle
+                   instants.
 """
 from __future__ import annotations
 
@@ -39,16 +34,7 @@ import subprocess
 
 import jax
 
-from repro.launch import roofline
 from repro.runtime.telemetry import OBS_SCHEMA_VERSION
-
-_DTYPE_BYTES = {
-    "float64": 8, "int64": 8, "uint64": 8,
-    "float32": 4, "int32": 4, "uint32": 4,
-    "bfloat16": 2, "float16": 2, "int16": 2, "uint16": 2,
-    "int8": 1, "uint8": 1, "bool": 1,
-    "float8_e4m3fn": 1, "float8_e5m2": 1,
-}
 
 
 def emit(line: str) -> None:
@@ -84,61 +70,6 @@ def write_trace(tracer, path: str) -> dict:
     return tracer.export(path)
 
 
-def _geometry_bytes(geometry: tuple) -> int:
-    """HBM traffic floor for one launch: every argument array read once.
-    (Outputs and intermediate traffic are not modeled — this is a FLOOR,
-    so the reported roofline fraction is an upper bound on achievement.)"""
-    total = 0
-    for entry in geometry:
-        shape, dtype = entry[-2], entry[-1]
-        n = 1
-        for d in shape:
-            n *= int(d)
-        total += n * _DTYPE_BYTES.get(dtype, 2)
-    return total
-
-
-def kernel_report(prof, device=None) -> list:
-    """Per-(entry, spec, geometry) profile rows joined against the
-    roofline memory floor of ``device`` (default: the first JAX device),
-    slowest first.  Off a TPU there is no device to hold to a roofline:
-    ``achieved_gbps`` and ``roofline_fraction`` are None ("not measured")."""
-    device = device if device is not None else jax.devices()[0]
-    bw = (roofline.peaks(device.device_kind).hbm_bw
-          if device.platform == "tpu" else None)
-    rows = []
-    for (name, tag, geometry), (count, total_s) in prof.records.items():
-        mean_s = total_s / max(count, 1)
-        byts = _geometry_bytes(geometry)
-        rows.append({
-            "entry": name, "spec": tag, "launches": count,
-            "mean_us": mean_s * 1e6, "total_ms": total_s * 1e3,
-            "arg_bytes": byts, "hbm_bw": bw,
-            "achieved_gbps": (byts / max(mean_s, 1e-12) / 1e9
-                              if bw else None),
-            "roofline_fraction": (byts / bw / max(mean_s, 1e-12)
-                                  if bw else None),
-        })
-    rows.sort(key=lambda r: -r["total_ms"])
-    return rows
-
-
-def kernel_report_lines(rows, sampled: int, sample_every: int) -> list:
-    bw = rows[0]["hbm_bw"] if rows else None
-    floor = (f"HBM-roofline floor ({bw / 1e9:.0f} GB/s)" if bw
-             else "HBM-roofline floor: not measured (no TPU)")
-    lines = [f"[serve] kernel profile: {sampled} sampled launches "
-             f"(every {sample_every}); achieved vs {floor}:"]
-    for r in rows:
-        share = (f"{r['achieved_gbps']:.3g} GB/s, "
-                 f"{r['roofline_fraction']:.3g} of roofline"
-                 if r["roofline_fraction"] is not None else "not measured")
-        lines.append(
-            f"[serve]   {r['entry']}: {r['launches']}x "
-            f"{r['mean_us']:.0f}us/launch, {share} ({r['spec']})")
-    return lines
-
-
 def _c(snap: dict, name: str) -> int:
     return int(snap["counters"].get(name, 0))
 
@@ -146,8 +77,8 @@ def _c(snap: dict, name: str) -> int:
 def summarize_paged(snap: dict, ctx: dict) -> list:
     """The ``[serve]`` summary for the paged loop, rendered from the
     registry snapshot.  ``ctx`` carries run facts that are configuration
-    or wall-clock, not metrics: flags, timings, sample tokens, the
-    per-class stats dict, and the optional kernel report."""
+    or wall-clock, not metrics: flags, timings, sample tokens and the
+    per-class stats dict."""
     tokens_served = _c(snap, "serve/decode_tokens")
     steps = _c(snap, "serve/decode_steps")
     prefill_tokens = _c(snap, "serve/prefill_tokens")
@@ -205,10 +136,6 @@ def summarize_paged(snap: dict, ctx: dict) -> list:
             f"{_c(snap, 'serve/spec_verify_steps')} verify launches, "
             f"{accepted}/{proposed} drafts accepted "
             f"({accepted / max(proposed, 1):.0%})")
-    if ctx.get("kernel_report"):
-        lines.extend(kernel_report_lines(ctx["kernel_report"],
-                                         ctx["profile_sampled"],
-                                         ctx["profile_every"]))
     for stats, flag in ((ctx.get("trace_stats"), "--trace-out"),
                         (ctx.get("metrics_path"), "--metrics-out")):
         if stats and flag == "--trace-out":

@@ -24,12 +24,14 @@ Two cache layouts:
 Observability (DESIGN.md §15): every stat a run reports is recorded into
 one per-run MetricsRegistry (runtime/telemetry.py) and the ``[serve]``
 summary renders from its snapshot (launch/obs.py) — ``--metrics-out``
-archives the same snapshot as JSON.  ``--trace-out`` records the request
-lifecycle + engine spans into a bounded ring buffer and exports Chrome
-trace-event JSON; ``--profile-kernels N`` times every N-th attention
-launch at the ``attn_entry`` choke point.  Telemetry never touches token
-streams: telemetry-on output is bitwise identical to telemetry-off at
-default sampling (tests/test_telemetry.py + BENCH_obs.json gate it).
+archives the same snapshot as JSON.  The paged loop's spans (one
+``serve.tick`` per iteration, a launch / wait split of every device
+call, admission, prefix-cache work, GC) go to ``jax.profiler``'s trace on
+the device trace's clock whenever a profiler session is open;
+``--trace-out`` also keeps them, with the request-lifecycle instants, in
+a bounded ring buffer exported as Chrome trace-event JSON.  Telemetry
+never touches token streams: telemetry-on output is bitwise identical to
+telemetry-off (tests/test_telemetry.py + BENCH_obs.json gate it).
 
     PYTHONPATH=src python -m repro.launch.serve --arch deepseek_r1_671b \
         --reduced --batch 4 --prompt 64 --gen 32 --mode etap \
@@ -38,7 +40,6 @@ default sampling (tests/test_telemetry.py + BENCH_obs.json gate it).
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import os
 import time
 from collections import deque
@@ -237,8 +238,10 @@ def run_paged(args, cfg, *, record_logits: bool = False) -> dict:
     # (tests, benchmarks) must never mix counters.  Every subsystem below
     # (pool, scheduler, heartbeats, injector, drafter) writes into it.
     reg = telemetry.MetricsRegistry()
-    tracer = (telemetry.Tracer(capacity=args.trace_buffer)
-              if args.trace_out else None)
+    # spans always go to the profiler (a no-op without a session); the
+    # Chrome-JSON ring only under --trace-out
+    tracer = telemetry.Tracer(
+        capacity=args.trace_buffer if args.trace_out else 0)
     params = model.init(jax.random.PRNGKey(args.seed), cfg)
     B = args.batch
     max_total = args.prompt + args.gen
@@ -259,7 +262,8 @@ def run_paged(args, cfg, *, record_logits: bool = False) -> dict:
     if args.preemption == "swap" and host_blocks == 0:
         host_blocks = layout.num_blocks - 1   # host tier mirrors the pool
     bp = BlockPool(layout, B, host_blocks=host_blocks, metrics=reg)
-    prefix = PrefixCache(layout.block_size) if args.prefix_cache else None
+    prefix = (PrefixCache(layout.block_size, tracer=tracer)
+              if args.prefix_cache else None)
     cache = model.init_paged_cache(cfg, layout, kv_dtype=args.kv_dtype)
     pending = deque(sorted(_make_requests(args, cfg.vocab_size),
                            key=lambda r: (r["arrival"], r["id"])))
@@ -303,41 +307,26 @@ def run_paged(args, cfg, *, record_logits: bool = False) -> dict:
     # the cache pytree is DONATED through both jitted entries (as the dense
     # path donates through its scan carry): the pool is updated in place
     # instead of copied per call, keeping admission's peak extra memory at
-    # one chunk, not a second pool.
+    # one chunk, not a second pool.  Named functions, so the compiled
+    # modules and the device trace say which program ran.
     spec = attn_spec.AttnSpec(mode=args.mode, kv_splits=args.kv_splits,
                               kv_dtype=args.kv_dtype,
                               spec_tokens=args.spec_tokens,
                               spec_draft=args.spec_draft)
-    profile_every = args.profile_kernels
-    if profile_every:
-        # --profile-kernels: (a) route attention through the Pallas kernel
-        # entries — the attn_entry choke point wraps THOSE; the XLA
-        # reference path is plain functions with nothing to hook — and
-        # (b) run the outer step/prefill/verify callables UNJITTED.  Under
-        # the default outer jit the attention entries are inlined at trace
-        # time (tracer args — the profiler hook must and does skip them);
-        # unjitted, every attn_entry still jits and runs its own compiled
-        # launch, which the choke-point hook can time with
-        # block_until_ready.  Both moves change compilation (not the
-        # math), so the bitwise-identity guarantee is stated for DEFAULT
-        # sampling (profiling off) only.
-        cfg = dataclasses.replace(cfg, use_kernels=True)
-        def step_fn(p, c, t, table, lengths):
-            return model.decode_step(p, cfg, c, t, None, spec=spec,
-                                     cache_layout="paged",
-                                     block_table=table, lengths=lengths)
 
-        def prefill_fn(p, cch, t, table, lens):
-            return model.prefill_chunk(p, cfg, cch, t, table, lens,
-                                       spec=spec)
-    else:
-        step_fn = jax.jit(lambda p, c, t, table, lengths: model.decode_step(
-            p, cfg, c, t, None, spec=spec, cache_layout="paged",
-            block_table=table, lengths=lengths),
-            donate_argnums=(1,))
-        prefill_fn = jax.jit(
-            lambda p, cch, t, table, lens: model.prefill_chunk(
-                p, cfg, cch, t, table, lens, spec=spec), donate_argnums=(1,))
+    def serve_decode_step(p, c, t, table, lengths):
+        return model.decode_step(p, cfg, c, t, None, spec=spec,
+                                 cache_layout="paged", block_table=table,
+                                 lengths=lengths)
+
+    def serve_prefill_chunk(p, cch, t, table, lens):
+        return model.prefill_chunk(p, cfg, cch, t, table, lens, spec=spec)
+
+    def serve_verify_step(p, c, t, table, lengths):
+        return model.verify_step(p, cfg, c, t, table, lengths, spec=spec)
+
+    step_fn = jax.jit(serve_decode_step, donate_argnums=(1,))
+    prefill_fn = jax.jit(serve_prefill_chunk, donate_argnums=(1,))
     # warm the decode step OUTSIDE the timed region (the dense path also
     # compiles before its timer): all slots inactive → the dummy rows land
     # in the reserved null block, so rebinding the returned cache (the
@@ -357,15 +346,7 @@ def run_paged(args, cfg, *, record_logits: bool = False) -> dict:
     if k_max > 0:
         drafter = spec_decode.make_drafter(args.spec_draft, params,
                                            metrics=reg)
-        if profile_every:
-            def verify_fn(p, c, t, table, lengths):
-                return model.verify_step(p, cfg, c, t, table, lengths,
-                                         spec=spec)
-        else:
-            verify_fn = jax.jit(
-                lambda p, c, t, table, lengths: model.verify_step(
-                    p, cfg, c, t, table, lengths, spec=spec),
-                donate_argnums=(1,))
+        verify_fn = jax.jit(serve_verify_step, donate_argnums=(1,))
         # warm the verify pass outside the timer too, with the same all-
         # null masked launch as step_fn: the k dummy rows land in the null
         # block and compile time never lands in t_decode
@@ -395,256 +376,270 @@ def run_paged(args, cfg, *, record_logits: bool = False) -> dict:
     # what a comparison of the paths holds to its bound (chip_smoke.py)
     seed_logits, first_logits = {}, {}
 
-    # profiler installed AFTER warmup: compile-time launches never land in
-    # the records; cleared in the finally so one run can't leak its
-    # profiler into the next
-    prof = None
-    if profile_every:
-        prof = telemetry.KernelProfiler(profile_every)
-        telemetry.set_profiler(prof)
+    # spans (DESIGN.md §15): one serve.tick per iteration; inside it each
+    # device launch is a *.launch span (host prep + dispatch) and a *.wait
+    # span (the host blocked on the device); the rest is host work
     t0 = time.perf_counter()
-    try:
+    with tracer.gc_spans():
         while pending or sched.queue or sched.by_slot:
             tick = tick_box[0]
-            now = time.perf_counter()
-            # ---- (0) arrivals + paranoia sweep + heartbeat bookkeeping
-            while pending and pending[0]["arrival"] <= tick:
-                req = pending.popleft()
-                sched.add(scheduler.Request(
-                    id=req["id"], prompt=req["prompt"], gen=req["gen"],
-                    priority=req["priority"], arrival=req["arrival"]), now)
-            if args.paranoia and tick % args.paranoia == 0:
-                bp.audit()
-            if hb.dead():                     # missed beat = failure tick
-                c_restarts.inc()              # ...worker comes back below
-                if tracer is not None:
+            with tracer.span("serve.tick", step_num=tick):
+                now = time.perf_counter()
+                # ---- (0) arrivals + paranoia sweep + heartbeat bookkeeping
+                while pending and pending[0]["arrival"] <= tick:
+                    req = pending.popleft()
+                    sched.add(scheduler.Request(
+                        id=req["id"], prompt=req["prompt"], gen=req["gen"],
+                        priority=req["priority"], arrival=req["arrival"]),
+                        now)
+                if args.paranoia and tick % args.paranoia == 0:
+                    bp.audit()
+                if hb.dead():                 # missed beat = failure tick
+                    c_restarts.inc()          # ...worker comes back below
                     tracer.instant("worker_restart", args={"tick": tick})
 
-            # ---- (1) admission / restore / preemption (scheduler policy)
-            sched.admit(tick, now)
+                # ---- (1) admission / restore / preemption (scheduler
+                # policy)
+                with tracer.span("sched.admit"):
+                    sched.admit(tick, now)
 
-            running = sched.running()
-            dec = [r for r in running if r.decoding]
-            # speculation is restricted to slots with at least k_max
-            # deliveries left (uniform-k launches: start + k_max never
-            # exceeds the slot's reserved budget exactly when remaining >=
-            # k_max) that are not teacher-forcing a restore replay;
-            # everything else takes the plain one-token step below
-            spec_dec = [r for r in dec
-                        if k_max > 0 and not r.replay and r.remaining >= k_max]
-            spec_slots = {r.slot for r in spec_dec}
-            # decode tokens this step (each spec slot runs k_max verify rows)
-            spent = len(dec) + max(0, k_max - 1) * len(spec_dec)
-            # ITL SLO: shrink the prefill share of the budget when delivered
-            # inter-token latency runs hot (no-op at the default budget split)
-            budget_eff = spent + sched.prefill_quota(max(0, budget - spent))
+                running = sched.running()
+                dec = [r for r in running if r.decoding]
+                # speculation is restricted to slots with at least k_max
+                # deliveries left (uniform-k launches: start + k_max never
+                # exceeds the slot's reserved budget exactly when
+                # remaining >= k_max) that are not teacher-forcing a
+                # restore replay; everything else takes the plain
+                # one-token step below
+                spec_dec = [r for r in dec if k_max > 0 and not r.replay
+                            and r.remaining >= k_max]
+                spec_slots = {r.slot for r in spec_dec}
+                # decode tokens this step (each spec slot runs k_max
+                # verify rows)
+                spent = len(dec) + max(0, k_max - 1) * len(spec_dec)
+                # ITL SLO: shrink the prefill share of the budget when
+                # delivered inter-token latency runs hot (no-op at the
+                # default budget split)
+                budget_eff = spent + sched.prefill_quota(
+                    max(0, budget - spent))
 
-            # ---- (2) prefill chunks from cold slots under the budget
-            pf_tokens = 0
-            cold = sorted((r for r in running if not r.decoding),
-                          key=lambda r: r.admit_seq)
-            for r in cold:
-                b = r.slot
-                plen = r.plen
-                # trim the first tail chunk onto the global chunk grid:
-                # after a prefix-cache hit (or a restore) at a non-chunk-
-                # multiple offset, the next chunk ends at the grid point,
-                # so every later chunk has the exact shape the uncached run
-                # would have used (bitwise-equal decode, DESIGN.md §10).
-                # Uncached (pf_pos % chunk == 0) this is the plain
-                # min(chunk, remaining).
-                c = min(chunk - r.pf_pos % chunk, plen - r.pf_pos)
-                if spent + c > budget_eff and spent > 0:
-                    break                     # budget spent — defer chunk
-                tp = time.perf_counter()
-                ts = tracer.now_us() if tracer is not None else 0.0
-                toks_c = r.prompt[None, r.pf_pos:r.pf_pos + c]
-                trow = jnp.array(bp.table[b:b + 1])
-                lrow = jnp.array(bp.lengths[b:b + 1])
-                logits, holder["cache"] = prefill_fn(params, holder["cache"],
-                                                     toks_c, trow, lrow)
-                jax.block_until_ready(logits)
-                t_prefill += time.perf_counter() - tp
-                if tracer is not None:
-                    tracer.complete("prefill_chunk", ts,
-                                    args={"req": r.id, "tokens": c,
-                                          "pf_pos": r.pf_pos})
-                bp.extend(b, c)
-                r.pf_pos += c
-                spent += c
-                pf_tokens += c
-                c_pf.inc(c)
-                c_chunks.inc()
-                if r.pf_pos == plen:          # prompt done -> start decoding
-                    seed = int(jnp.argmax(logits[0, -1]))
-                    if record_logits:
-                        seed_logits.setdefault(
-                            r.id, np.asarray(logits[0, -1], np.float32))
-                    if r.replay:
-                        # restored victim: the re-prefill must re-derive the
-                        # first delivered token bit-for-bit (grid invariant)
-                        assert seed == r.replay[0], \
-                            f"request {r.id}: restore diverged at prefill " \
-                            f"(got {seed}, delivered {r.replay[0]})"
-                    else:
-                        r.cur = seed
-                    r.decoding = True
-                    if prefix is not None:
-                        # cache the prompt's full blocks NOW (not at
-                        # release): queued requests share them while this
-                        # one decodes
-                        prefix.insert(np.asarray(r.prompt), bp.block_ids(b),
-                                      bp)
-
-            # ---- (3) one ragged decode step over the decoding slots
-            if dec:
-                if injector is not None:
-                    try:
-                        injector.check(tick)
-                    except WorkerFailure:
-                        # the decode worker died mid-step: its outputs
-                        # never land — requeue the victim through the
-                        # recompute path and skip the step (no beat →
-                        # dead() next tick)
-                        victim = max(dec, key=lambda r: r.slot)
-                        sched.fail_running(victim.slot, tick)
-                        tick_box[0] += 1
-                        continue
-                # mask cold slots (and, for each launch, the OTHER
-                # launch's slots) to the null block: the decode write for
-                # them must not land inside a half-prefilled prompt or a
-                # live sequence
-                plain = [r for r in dec if r.slot not in spec_slots]
-                if plain:
-                    plain_slots = {r.slot for r in plain}
-                    table_m = bp.table.copy()
-                    lens_m = bp.lengths.copy()
-                    cur_arr = np.zeros((B,), np.int64)
-                    for b in range(B):
-                        if b not in plain_slots:
-                            table_m[b] = 0
-                            lens_m[b] = 0
-                    for r in plain:
-                        cur_arr[r.slot] = r.replay[0] if r.replay else r.cur
-                    ts = tracer.now_us() if tracer is not None else 0.0
-                    logits, holder["cache"] = step_fn(
-                        params, holder["cache"], jnp.array(cur_arr, jnp.int32),
-                        jnp.array(table_m), jnp.array(lens_m))
-                    nxt = np.asarray(jnp.argmax(logits, axis=-1))
-                    new = ([r for r in plain if r.id not in first_logits]
-                           if record_logits else [])
-                    if new:
-                        rows = np.asarray(logits, np.float32)
-                        first_logits.update({r.id: rows[r.slot]
-                                             for r in new})
-                    if tracer is not None:
-                        tracer.complete("decode_step", ts,
-                                        args={"slots": len(plain)})
-                    c_steps.inc()
-                    if pf_tokens:
-                        c_inter.inc()
-
-                    # ---- retire / bookkeep (host side)
-                    now = time.perf_counter()
-                    for r in plain:
-                        b = r.slot
+                # ---- (2) prefill chunks from cold slots under the budget
+                pf_tokens = 0
+                cold = sorted((r for r in running if not r.decoding),
+                              key=lambda r: r.admit_seq)
+                for r in cold:
+                    b = r.slot
+                    plen = r.plen
+                    # trim the first tail chunk onto the global chunk
+                    # grid: after a prefix-cache hit (or a restore) at a
+                    # non-chunk-multiple offset, the next chunk ends at
+                    # the grid point, so every later chunk has the exact
+                    # shape the uncached run would have used (bitwise-
+                    # equal decode, DESIGN.md §10).  Uncached (pf_pos %
+                    # chunk == 0) this is the plain min(chunk, remaining).
+                    c = min(chunk - r.pf_pos % chunk, plen - r.pf_pos)
+                    if spent + c > budget_eff and spent > 0:
+                        break                 # budget spent — defer chunk
+                    done = r.pf_pos + c == plen
+                    tp = time.perf_counter()
+                    with tracer.span("serve.prefill.launch", req=r.id,
+                                     tokens=c):
+                        toks_c = r.prompt[None, r.pf_pos:r.pf_pos + c]
+                        trow = jnp.array(bp.table[b:b + 1])
+                        lrow = jnp.array(bp.lengths[b:b + 1])
+                        sched.start_prefill(r, time.perf_counter())
+                        logits, holder["cache"] = prefill_fn(
+                            params, holder["cache"], toks_c, trow, lrow)
+                    with tracer.span("serve.prefill.wait", req=r.id):
+                        jax.block_until_ready(logits)
+                        t_prefill += time.perf_counter() - tp
+                        if done:
+                            seed = int(jnp.argmax(logits[0, -1]))
+                            if record_logits:
+                                seed_logits.setdefault(
+                                    r.id,
+                                    np.asarray(logits[0, -1], np.float32))
+                    bp.extend(b, c)
+                    r.pf_pos += c
+                    spent += c
+                    pf_tokens += c
+                    c_pf.inc(c)
+                    c_chunks.inc()
+                    if done:                  # prompt done -> start decoding
                         if r.replay:
-                            # teacher-forced replay: the token was already
-                            # delivered before preemption — rebuild its KV
-                            # row and assert the decode path re-derives the
-                            # NEXT token bit-for-bit (the bitwise-restore
-                            # guarantee made falsifiable at every replayed
-                            # position)
-                            fed = r.replay.popleft()
-                            bp.append(b)
-                            expect = r.replay[0] if r.replay else r.cur
-                            assert int(nxt[b]) == int(expect), \
-                                f"request {r.id}: replay diverged after " \
-                                f"token {fed} (got {int(nxt[b])}, " \
-                                f"expected {int(expect)})"
-                            c_replay.inc()
+                            # restored victim: the re-prefill must
+                            # re-derive the first delivered token
+                            # bit-for-bit (grid invariant)
+                            assert seed == r.replay[0], \
+                                f"request {r.id}: restore diverged at " \
+                                f"prefill (got {seed}, delivered " \
+                                f"{r.replay[0]})"
                         else:
-                            sched.deliver(r, r.cur, now)
-                            c_tokens.inc()
-                            bp.append(b)
-                            r.cur = int(nxt[b])
-                            if r.remaining == 0:
-                                sched.finish(r)
+                            r.cur = seed
+                        r.decoding = True
+                        if prefix is not None:
+                            # cache the prompt's full blocks NOW (not at
+                            # release): queued requests share them while
+                            # this one decodes
+                            prefix.insert(np.asarray(r.prompt),
+                                          bp.block_ids(b), bp)
 
-                if spec_dec:
-                    # ---- speculative verify (DESIGN.md §14): draft k-1
-                    # tokens per slot from the committed stream, score
-                    # [cur, d_1, .., d_{k-1}] in ONE prefill-shaped launch
-                    # against the paged pool, accept the longest draft
-                    # prefix matching the model's own argmax chain.
-                    # Greedy acceptance makes the delivered stream bitwise
-                    # identical to one-at-a-time decode whatever the
-                    # drafter proposes.
-                    table_m = bp.table.copy()
-                    lens_m = bp.lengths.copy()
-                    tok_arr = np.zeros((B, k_max), np.int64)
-                    drafts_by_slot = {}
-                    for b in range(B):
-                        if b not in spec_slots:
-                            table_m[b] = 0
-                            lens_m[b] = 0
-                    for r in spec_dec:
-                        b = r.slot
-                        history = np.concatenate([np.asarray(r.prompt),
-                                                  np.asarray(r.out + [r.cur],
-                                                             np.int64)])
-                        ds = (list(drafter(history, k_max - 1))
-                              if k_max > 1 else [])
-                        drafts_by_slot[b] = ds
-                        tok_arr[b] = [r.cur] + ds
-                    ts = tracer.now_us() if tracer is not None else 0.0
-                    logits, holder["cache"] = verify_fn(
-                        params, holder["cache"], jnp.array(tok_arr, jnp.int32),
-                        jnp.array(table_m), jnp.array(lens_m))
-                    preds = np.asarray(jnp.argmax(logits, axis=-1))  # [B, k]
-                    if tracer is not None:
-                        tracer.complete("verify_step", ts,
-                                        args={"slots": len(spec_dec),
-                                              "k": k_max})
-                    c_steps.inc()
-                    c_spec_steps.inc()
-                    if pf_tokens:
-                        c_inter.inc()
+                # ---- (3) one ragged decode step over the decoding slots
+                if dec:
+                    if injector is not None:
+                        try:
+                            injector.check(tick)
+                        except WorkerFailure:
+                            # the decode worker died mid-step: its outputs
+                            # never land — requeue the victim through the
+                            # recompute path and skip the step (no beat →
+                            # dead() next tick)
+                            victim = max(dec, key=lambda r: r.slot)
+                            sched.fail_running(victim.slot, tick)
+                            tick_box[0] += 1
+                            continue
+                    # mask cold slots (and, for each launch, the OTHER
+                    # launch's slots) to the null block: the decode write
+                    # for them must not land inside a half-prefilled
+                    # prompt or a live sequence
+                    plain = [r for r in dec if r.slot not in spec_slots]
+                    if plain:
+                        with tracer.span("serve.decode.launch",
+                                         slots=len(plain)):
+                            plain_slots = {r.slot for r in plain}
+                            table_m = bp.table.copy()
+                            lens_m = bp.lengths.copy()
+                            cur_arr = np.zeros((B,), np.int64)
+                            for b in range(B):
+                                if b not in plain_slots:
+                                    table_m[b] = 0
+                                    lens_m[b] = 0
+                            for r in plain:
+                                cur_arr[r.slot] = (r.replay[0] if r.replay
+                                                   else r.cur)
+                            logits, holder["cache"] = step_fn(
+                                params, holder["cache"],
+                                jnp.array(cur_arr, jnp.int32),
+                                jnp.array(table_m), jnp.array(lens_m))
+                        with tracer.span("serve.decode.wait"):
+                            nxt = np.asarray(jnp.argmax(logits, axis=-1))
+                            new = ([r for r in plain
+                                    if r.id not in first_logits]
+                                   if record_logits else [])
+                            if new:
+                                rows = np.asarray(logits, np.float32)
+                                first_logits.update({r.id: rows[r.slot]
+                                                     for r in new})
+                        c_steps.inc()
+                        if pf_tokens:
+                            c_inter.inc()
 
-                    # ---- commit / rewind / deliver (host side)
-                    now = time.perf_counter()
-                    for r in spec_dec:
-                        b = r.slot
-                        start = int(bp.lengths[b])
-                        # the verify pass appended k_max KV rows on device;
-                        # commit them on the host, then rewind the rejected
-                        # tail IN PLACE (free_blocks=False — the slot keeps
-                        # its full reservation, and the garbage rows sit
-                        # past the committed length where no mask ever
-                        # reads them until the next launch overwrites them)
-                        bp.extend(b, k_max)
-                        accepted, nxt_tok = spec_decode.accept_greedy(
-                            drafts_by_slot[b], preds[b])
-                        bp.truncate(b, start + 1 + accepted,
-                                    free_blocks=False)
-                        for t in [r.cur] + drafts_by_slot[b][:accepted]:
-                            sched.deliver(r, int(t), now)
-                            c_tokens.inc()
-                        c_spec_prop.inc(len(drafts_by_slot[b]))
-                        c_spec_acc.inc(accepted)
-                        r.cur = int(nxt_tok)
-                        if r.remaining == 0:
-                            sched.finish(r)
-            # per-tick occupancy gauges: pure reads of pool/scheduler state
-            c_ticks.inc()
-            bp.observe(reg)
-            g_queued.set(len(sched.queue))
-            g_running.set(len(sched.by_slot))
-            hb.beat(WORKER)
-            tick_box[0] += 1
-    finally:
-        if prof is not None:
-            telemetry.set_profiler(None)
+                        # ---- retire / bookkeep (host side)
+                        with tracer.span("serve.decode.retire"):
+                            now = time.perf_counter()
+                            for r in plain:
+                                b = r.slot
+                                if r.replay:
+                                    # teacher-forced replay: the token was
+                                    # already delivered before preemption
+                                    # — rebuild its KV row and assert the
+                                    # decode path re-derives the NEXT
+                                    # token bit-for-bit (the bitwise-
+                                    # restore guarantee made falsifiable
+                                    # at every replayed position)
+                                    fed = r.replay.popleft()
+                                    bp.append(b)
+                                    expect = (r.replay[0] if r.replay
+                                              else r.cur)
+                                    assert int(nxt[b]) == int(expect), \
+                                        f"request {r.id}: replay " \
+                                        f"diverged after token {fed} " \
+                                        f"(got {int(nxt[b])}, expected " \
+                                        f"{int(expect)})"
+                                    c_replay.inc()
+                                else:
+                                    sched.deliver(r, r.cur, now)
+                                    c_tokens.inc()
+                                    bp.append(b)
+                                    r.cur = int(nxt[b])
+                                    if r.remaining == 0:
+                                        sched.finish(r)
+
+                    if spec_dec:
+                        # ---- speculative verify (DESIGN.md §14): draft
+                        # k-1 tokens per slot from the committed stream,
+                        # score [cur, d_1, .., d_{k-1}] in ONE prefill-
+                        # shaped launch against the paged pool, accept the
+                        # longest draft prefix matching the model's own
+                        # argmax chain.  Greedy acceptance makes the
+                        # delivered stream bitwise identical to one-at-a-
+                        # time decode whatever the drafter proposes.
+                        with tracer.span("serve.verify.launch",
+                                         slots=len(spec_dec), k=k_max):
+                            table_m = bp.table.copy()
+                            lens_m = bp.lengths.copy()
+                            tok_arr = np.zeros((B, k_max), np.int64)
+                            drafts_by_slot = {}
+                            for b in range(B):
+                                if b not in spec_slots:
+                                    table_m[b] = 0
+                                    lens_m[b] = 0
+                            for r in spec_dec:
+                                b = r.slot
+                                history = np.concatenate([
+                                    np.asarray(r.prompt),
+                                    np.asarray(r.out + [r.cur], np.int64)])
+                                ds = (list(drafter(history, k_max - 1))
+                                      if k_max > 1 else [])
+                                drafts_by_slot[b] = ds
+                                tok_arr[b] = [r.cur] + ds
+                            logits, holder["cache"] = verify_fn(
+                                params, holder["cache"],
+                                jnp.array(tok_arr, jnp.int32),
+                                jnp.array(table_m), jnp.array(lens_m))
+                        with tracer.span("serve.verify.wait"):
+                            preds = np.asarray(jnp.argmax(logits, axis=-1))
+                        c_steps.inc()
+                        c_spec_steps.inc()
+                        if pf_tokens:
+                            c_inter.inc()
+
+                        # ---- commit / rewind / deliver (host side)
+                        with tracer.span("serve.decode.retire"):
+                            now = time.perf_counter()
+                            for r in spec_dec:
+                                b = r.slot
+                                start = int(bp.lengths[b])
+                                # the verify pass appended k_max KV rows
+                                # on device; commit them on the host, then
+                                # rewind the rejected tail IN PLACE
+                                # (free_blocks=False — the slot keeps its
+                                # full reservation, and the garbage rows
+                                # sit past the committed length where no
+                                # mask ever reads them until the next
+                                # launch overwrites them)
+                                bp.extend(b, k_max)
+                                ds = drafts_by_slot[b]
+                                accepted, nxt_tok = \
+                                    spec_decode.accept_greedy(ds, preds[b])
+                                bp.truncate(b, start + 1 + accepted,
+                                            free_blocks=False)
+                                for t in [r.cur] + ds[:accepted]:
+                                    sched.deliver(r, int(t), now)
+                                    c_tokens.inc()
+                                c_spec_prop.inc(len(ds))
+                                c_spec_acc.inc(accepted)
+                                r.cur = int(nxt_tok)
+                                if r.remaining == 0:
+                                    sched.finish(r)
+                # per-tick occupancy gauges: pure reads of pool/scheduler
+                # state
+                c_ticks.inc()
+                bp.observe(reg)
+                g_queued.set(len(sched.queue))
+                g_running.set(len(sched.by_slot))
+                hb.beat(WORKER)
+                tick_box[0] += 1
     t_total = time.perf_counter() - t0
     t_decode = t_total - t_prefill
 
@@ -654,10 +649,8 @@ def run_paged(args, cfg, *, record_logits: bool = False) -> dict:
     sstats = sched.stats()
     pstats = prefix.stats() if prefix is not None else None
     snap = reg.snapshot()
-    krep = (obs.kernel_report(prof)
-            if prof is not None and prof.records else None)
     trace_stats = (obs.write_trace(tracer, args.trace_out)
-                   if tracer is not None else None)
+                   if args.trace_out else None)
     if args.metrics_out:
         obs.write_metrics(
             args.metrics_out, snap,
@@ -677,9 +670,6 @@ def run_paged(args, cfg, *, record_logits: bool = False) -> dict:
             "refusals": len(refused_ids),
             "prefill_tokens_saved": prefill_tokens_saved,
             "sched": sstats, "classes": sched.class_stats(),
-            "kernel_report": krep,
-            "profile_sampled": prof.sampled if prof is not None else 0,
-            "profile_every": profile_every,
             "trace_stats": trace_stats, "metrics_path": args.metrics_out,
             "sample": first}):
         obs.emit(line)
@@ -704,7 +694,7 @@ def run_paged(args, cfg, *, record_logits: bool = False) -> dict:
                       "acceptance_rate":
                           c_spec_acc.value / max(c_spec_prop.value, 1)}
                      if k_max > 0 else None),
-            "metrics": snap, "kernel_report": krep,
+            "metrics": snap,
             "seed_logits": seed_logits,
             "first_decode_logits": first_logits,
             "t_prefill": t_prefill, "t_decode": t_decode}
@@ -833,14 +823,6 @@ def parse_args(argv=None):
                     help="write the full metrics-registry snapshot as "
                          "schema-versioned JSON (counters/gauges/histogram "
                          "tails + git sha and jax version provenance)")
-    ap.add_argument("--profile-kernels", type=int, default=0, metavar="N",
-                    help="time every N-th attention-kernel launch at the "
-                         "attn_entry choke point (block_until_ready; "
-                         "tagged with AttnSpec + geometry, joined against "
-                         "the HBM roofline in the summary).  0 = off, the "
-                         "default — profiling runs the outer step "
-                         "UNJITTED, so use it for kernel attribution, not "
-                         "end-to-end throughput (paged layout only)")
     ap.add_argument("--kv-splits", type=int, default=None,
                     help="split-KV count for decode attention "
                          "(default: auto-scheduled)")
@@ -876,13 +858,10 @@ def parse_args(argv=None):
                  "use --spec-draft ngram")
     if args.trace_buffer < 1:
         ap.error("--trace-buffer must be >= 1")
-    if args.profile_kernels < 0:
-        ap.error("--profile-kernels must be >= 0")
-    if args.cache_layout == "dense" and (args.trace_out
-                                         or args.profile_kernels):
-        ap.error("--trace-out/--profile-kernels require --cache-layout "
-                 "paged: the dense scan is one opaque jitted launch with "
-                 "no per-request lifecycle or per-launch entries to record")
+    if args.cache_layout == "dense" and args.trace_out:
+        ap.error("--trace-out requires --cache-layout paged: the dense "
+                 "scan is one opaque jitted launch with no per-request "
+                 "lifecycle or per-launch spans to record")
     return args
 
 

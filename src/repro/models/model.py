@@ -19,30 +19,9 @@ from jax.sharding import PartitionSpec as P
 from repro.core import attn_spec
 from repro.core import mla as mla_mod
 from repro.models import attention, frontend, layers, mamba, moe, rglru
-from repro.runtime import telemetry
 from repro.sharding.rules import BATCH, constrain
 
 AUX_KEYS = ("load_balance", "router_z")
-
-
-def _scan_layers(body, x, xs):
-    """lax.scan over a stacked layer group — unless a kernel profiler is
-    installed (runtime/telemetry.py) and the carry is concrete.  scan traces
-    its body, so every attention launch inside sees tracer operands and the
-    per-launch timing hook in core/attn_spec.attn_entry must skip it
-    (tracers can't be block_until_ready'd).  A Python loop keeps each layer's
-    launch concrete and timeable; profiling mode has already given up the
-    fused outer jit, so the extra per-layer dispatch only moves time between
-    buckets, never changes results."""
-    if (telemetry.profiler() is not None
-            and not isinstance(x, jax.core.Tracer)):
-        n = jax.tree.leaves(xs)[0].shape[0]
-        ys = []
-        for i in range(n):
-            x, y = body(x, jax.tree.map(lambda a, i=i: a[i], xs))
-            ys.append(y)
-        return x, jax.tree.map(lambda *rows: jnp.stack(rows), *ys)
-    return jax.lax.scan(body, x, xs)
 
 
 # ------------------------------------------------------------- layer groups
@@ -457,7 +436,7 @@ def _chunk_forward(params, cfg, cache, tokens, block_table, lengths, spec,
                                              lengths, spec, qpos)
                 ncs[f"b{j}"] = nc
             return x, ncs
-        x, gc_new = _scan_layers(body, x, (gparams, gcache))
+        x, gc_new = jax.lax.scan(body, x, (gparams, gcache))
         new_caches.append(gc_new)
     x = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = layers.unembed(params["embed"], x)
@@ -557,7 +536,7 @@ def decode_step(params, cfg, cache, tokens, pos, *, spec=None,
                                       lengths=lengths)
                 ncs[f"b{j}"] = nc
             return x, ncs
-        x, gc_new = _scan_layers(body, x, (gparams, gcache))
+        x, gc_new = jax.lax.scan(body, x, (gparams, gcache))
         new_caches.append(gc_new)
     x = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = layers.unembed(params["embed"], x)

@@ -39,6 +39,8 @@ from collections import OrderedDict
 
 import numpy as np
 
+from repro.runtime import telemetry
+
 
 class _Node:
     """One cached KV block: edge label `key` (the block's token tuple) from
@@ -59,9 +61,13 @@ class PrefixCache:
     chains, with LRU leaf eviction.  One instance per BlockPool; the pool
     owns the refcounts, the trie owns the recency order."""
 
-    def __init__(self, block_size: int):
+    def __init__(self, block_size: int, tracer: telemetry.Tracer = None):
         assert block_size >= 1
         self.block_size = block_size
+        # match/insert are host work on the serve loop's critical path:
+        # each runs in a span of the run's tracer (profiler-only if none)
+        self.tracer = tracer if tracer is not None \
+            else telemetry.Tracer(capacity=0)
         self._root = _Node(None, None, None, -1)
         self._lru: OrderedDict[int, _Node] = OrderedDict()  # LRU -> MRU
         self._uid = itertools.count()
@@ -104,24 +110,26 @@ class PrefixCache:
         GROW while it waits — donors finish prefill, tries fill) would
         otherwise count one request N times and inflate the hit rate; it
         calls :meth:`record` once, on successful admission."""
-        if record:
-            self.lookups += 1
-        n_tok = int(np.asarray(tokens).size)
-        node, chain = self._root, []
-        for key in self._keys(tokens):
-            child = node.children.get(key)
-            if child is None:
-                break
-            self._lru.move_to_end(child.uid)
-            chain.append(child.block_id)
-            node = child
-        while chain and len(chain) * self.block_size >= n_tok:
-            chain.pop()
-        matched = len(chain) * self.block_size
-        if record and chain:
-            self.hits += 1
-            self.matched_tokens += matched
-        return chain, matched
+        with self.tracer.span("prefix.match") as sp:
+            if record:
+                self.lookups += 1
+            n_tok = int(np.asarray(tokens).size)
+            node, chain = self._root, []
+            for key in self._keys(tokens):
+                child = node.children.get(key)
+                if child is None:
+                    break
+                self._lru.move_to_end(child.uid)
+                chain.append(child.block_id)
+                node = child
+            while chain and len(chain) * self.block_size >= n_tok:
+                chain.pop()
+            matched = len(chain) * self.block_size
+            if record and chain:
+                self.hits += 1
+                self.matched_tokens += matched
+            sp.set_metadata(blocks=len(chain))
+            return chain, matched
 
     def record(self, matched: int) -> None:
         """Count one lookup (and its hit, if any) — the deferred-stats
@@ -141,19 +149,21 @@ class PrefixCache:
         cached under the same token path is deduped — the existing physical
         block is kept and the caller's duplicate stays owned by its slot
         alone.  Returns the number of blocks newly inserted."""
-        node, new = self._root, 0
-        for key, bid in zip(self._keys(tokens), chain, strict=False):
-            child = node.children.get(key)
-            if child is None:
-                child = _Node(key, int(bid), node, next(self._uid))
-                node.children[key] = child
-                pool.ref_block(int(bid))
-                new += 1
-            self._lru[child.uid] = child
-            self._lru.move_to_end(child.uid)
-            node = child
-        self.inserted_blocks += new
-        return new
+        with self.tracer.span("prefix.insert") as sp:
+            node, new = self._root, 0
+            for key, bid in zip(self._keys(tokens), chain, strict=False):
+                child = node.children.get(key)
+                if child is None:
+                    child = _Node(key, int(bid), node, next(self._uid))
+                    node.children[key] = child
+                    pool.ref_block(int(bid))
+                    new += 1
+                self._lru[child.uid] = child
+                self._lru.move_to_end(child.uid)
+                node = child
+            self.inserted_blocks += new
+            sp.set_metadata(blocks=new)
+            return new
 
     def pin_chain(self, chain) -> None:
         """Take a best-effort pin on every block of `chain`: pinned blocks

@@ -134,6 +134,7 @@ class Request:
     pinned: list | None = None        # trie chain pinned while out
     admit_seq: int = -1                  # FCFS order among cold slots
     t_arrival: float = 0.0               # wall clock, for SLO accounting
+    t_prefill: float | None = None       # first prefill chunk's dispatch
     t_first: float | None = None
     t_last: float | None = None
     ttft_ms: float | None = None
@@ -219,6 +220,16 @@ class Scheduler:
         self._instant("enqueued", req,
                       {"priority": req.priority, "plen": req.plen,
                        "gen": int(req.gen)})
+
+    def start_prefill(self, r: Request, now: float) -> None:
+        """A prefill chunk of ``r`` is being dispatched.  The first one
+        records ``sched/prefill_wait_ms``: arrival to the first chunk,
+        the time the request's work waited behind admission and the
+        prefill budget.  Restores and replays do not record again."""
+        if r.t_prefill is None:
+            r.t_prefill = now
+            self.metrics.histogram("sched/prefill_wait_ms").record(
+                (now - r.t_arrival) * 1e3)
 
     def running(self) -> list:
         """RUNNING requests in slot order (the per-step iteration order)."""

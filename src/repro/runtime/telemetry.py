@@ -1,7 +1,8 @@
-"""Zero-dependency serving telemetry core (DESIGN.md §15).
+"""Serving telemetry core (DESIGN.md §15).
 
-Three primitives, stdlib-only so they import (and stay cheap) everywhere
-in the runtime — kernels, scheduler, pool, serve loop:
+Two primitives, cheap enough for every layer of the runtime — scheduler,
+pool, prefix cache, serve loop (the registry is stdlib-only; the tracer
+imports ``jax.profiler`` when it is built):
 
   MetricsRegistry   process-wide counters / gauges / log-bucketed
                     histograms.  Replaces the ad-hoc stat dicts and the
@@ -11,19 +12,15 @@ in the runtime — kernels, scheduler, pool, serve loop:
                     and the serve summary / ``--metrics-out`` render from
                     ONE snapshot.
 
-  Tracer            per-request lifecycle + per-launch span events in a
-                    BOUNDED ring buffer (overflow drops the oldest event,
-                    never grows), exported as Chrome trace-event JSON
-                    (``--trace-out``, loadable in Perfetto / chrome
-                    about:tracing).
-
-  KernelProfiler    opt-in per-launch attention-kernel timing hook.
-                    ``core.attn_spec.attn_entry`` — the single choke
-                    point every jitted attention entry goes through —
-                    consults :func:`profiler` and, when one is installed,
-                    times the launch with ``block_until_ready`` and tags
-                    it with the AttnSpec + argument geometry.  The
-                    roofline join lives in ``launch/obs.py``.
+  Tracer            ONE span API with two sinks.  Every ``span`` opens a
+                    ``jax.profiler.TraceAnnotation`` of the same name, so
+                    under a profiler session it lands on the xplane host
+                    plane on the device trace's clock; with a ring
+                    (``--trace-out``) it is also recorded, with the
+                    request-lifecycle instants, into a BOUNDED ring
+                    buffer (overflow drops the oldest event, never grows)
+                    exported as Chrome trace-event JSON (loadable in
+                    Perfetto / chrome about:tracing).
 
 Histogram contract (the part tests pin): values are QUANTIZED at record
 time onto log-spaced buckets (geometric midpoint representative, relative
@@ -35,11 +32,11 @@ latency tails and CI-archived snapshots need.
 
 The telemetry invariant (enforced by tests + BENCH_obs.json): recording
 never influences served tokens — telemetry-on output is bitwise identical
-to telemetry-off — and the default-sampling overhead stays <= 2% of
-decode throughput.
+to telemetry-off — and the overhead stays <= 2% of decode throughput.
 """
 from __future__ import annotations
 
+import gc
 import json
 import math
 import os
@@ -278,27 +275,65 @@ def set_registry(reg: MetricsRegistry) -> MetricsRegistry:
 
 
 # ---------------------------------------------------------------- tracing
+class Span:
+    """A span of a tracer that keeps a ring: its
+    ``jax.profiler.TraceAnnotation`` (``StepTraceAnnotation`` for a step)
+    and its ring record.  Used as a context manager; ``set_metadata``
+    adds metadata known only once the work is done (``blocks``
+    matched), as on the annotation itself."""
+    __slots__ = ("_tr", "_name", "_args", "_ann", "_t0")
+
+    def __init__(self, tr, name, args, ann):
+        self._tr, self._name, self._args, self._ann = tr, name, args, ann
+
+    def __enter__(self) -> "Span":
+        self._ann.__enter__()
+        self._t0 = self._tr.now_us()
+        return self
+
+    def set_metadata(self, **kw) -> None:
+        self._ann.set_metadata(**kw)
+        self._args.update(kw)
+
+    def __exit__(self, *exc) -> bool:
+        self._ann.__exit__(*exc)
+        self._tr.complete(self._name, self._t0, args=self._args or None)
+        return False
+
+
 class Tracer:
-    """Bounded-memory span/instant recorder exporting Chrome trace-event
-    JSON.  Events live in a ring buffer (``capacity`` newest events;
-    overflow increments ``dropped`` and evicts the oldest — recording
-    never allocates past the ring).  Timestamps are microseconds on one
-    monotonic clock (``time.perf_counter`` by default) relative to tracer
-    construction; ``to_events`` sorts by ``ts``, so exported timestamps
-    are non-decreasing even though spans are recorded at END time.
+    """Spans on the profiler's clock, and a bounded-memory span/instant
+    ring exporting Chrome trace-event JSON.
+
+    :meth:`span` is the one call site.  It always opens a
+    ``jax.profiler.TraceAnnotation`` (``StepTraceAnnotation`` when given
+    ``step_num``): with no profiler session that costs an enter/exit of
+    about a microsecond, and its keyword args are only encoded while a
+    session records.  ``capacity`` > 0 also keeps a ring (``--trace-out``): the
+    ``capacity`` newest events, overflow incrementing ``dropped`` and
+    evicting the oldest.  Ring timestamps are microseconds on one
+    monotonic clock (``time.perf_counter`` by default) relative to
+    tracer construction; ``to_events`` sorts by ``ts``, so exported
+    timestamps are non-decreasing even though spans are recorded at END
+    time.  With ``capacity=0`` :meth:`instant` and :meth:`complete` are
+    no-ops.
 
     Event kinds (Chrome trace-event ``ph``):
-      "X"  complete span  (ts = start, dur = duration) — chunks, steps
+      "X"  complete span  (ts = start, dur = duration) — the serve spans
       "i"  instant        — request lifecycle edges
       "M"  metadata       — process name, emitted once at export
     """
 
     def __init__(self, capacity: int = 65536, clock=time.perf_counter):
-        assert capacity >= 1
+        assert capacity >= 0
+        from jax import profiler
+        self._annotation = profiler.TraceAnnotation
+        self._step = profiler.StepTraceAnnotation
         self.capacity = capacity
+        self.ring = capacity > 0
         self._clock = clock
         self._t0 = clock()
-        self._buf: deque = deque(maxlen=capacity)
+        self._buf: deque = deque(maxlen=max(capacity, 1))
         self.recorded = 0
         self.pid = os.getpid()
 
@@ -314,22 +349,49 @@ class Tracer:
         self.recorded += 1
 
     def instant(self, name: str, tid: int = 0, args: dict = None) -> None:
-        self._push(("i", name, self.now_us(), int(tid), 0.0, args))
+        if self.ring:
+            self._push(("i", name, self.now_us(), int(tid), 0.0, args))
 
     def complete(self, name: str, t0_us: float, tid: int = 0,
                  args: dict = None) -> None:
-        """Record a span that STARTED at ``t0_us`` (from :meth:`now_us`)
-        and ends now."""
-        self._push(("X", name, t0_us, int(tid),
-                    max(0.0, self.now_us() - t0_us), args))
+        """Record into the ring a span that STARTED at ``t0_us`` (from
+        :meth:`now_us`) and ends now."""
+        if self.ring:
+            self._push(("X", name, t0_us, int(tid),
+                        max(0.0, self.now_us() - t0_us), args))
+
+    def span(self, name: str, step_num: int = None, **args):
+        """A span named ``name`` (ring thread 0), ``args`` as its
+        metadata in both sinks; ``step_num`` makes it a profiler step
+        (the serve tick).  Without a ring the annotation itself is the
+        span (same ``set_metadata``, no wrapper cost)."""
+        if step_num is None:
+            ann = self._annotation(name, **args)
+        else:
+            ann = self._step(name, step_num=step_num, **args)
+            args["step_num"] = step_num
+        return Span(self, name, args, ann) if self.ring else ann
 
     @contextmanager
-    def span(self, name: str, tid: int = 0, args: dict = None):
-        t0 = self.now_us()
+    def gc_spans(self):
+        """A ``python.gc`` span around every collection while the block
+        runs: a ``gc.callbacks`` start/stop pair, removed on exit."""
+        box = [None]
+
+        def hook(phase, info):
+            if phase == "start":
+                box[0] = self.span("python.gc",
+                                   generation=info["generation"])
+                box[0].__enter__()
+            elif box[0] is not None:
+                box[0].__exit__(None, None, None)
+                box[0] = None
+
+        gc.callbacks.append(hook)
         try:
             yield
         finally:
-            self.complete(name, t0, tid=tid, args=args)
+            gc.callbacks.remove(hook)
 
     def to_events(self) -> list:
         """Chrome trace-event dicts, sorted by timestamp.  Every event
@@ -361,53 +423,3 @@ class Tracer:
             json.dump(doc, f)
         return {"events": len(events), "recorded": self.recorded,
                 "dropped": self.dropped, "path": path}
-
-
-# ------------------------------------------------------- kernel profiling
-class KernelProfiler:
-    """Opt-in per-launch kernel timing (``--profile-kernels N``).
-
-    ``attn_entry`` calls :meth:`want` once per entry invocation; every
-    ``sample_every``-th launch is run to completion under
-    ``block_until_ready`` and recorded as (entry name, spec tag, argument
-    geometry) -> (launch count, total seconds).  Aggregation happens at
-    record time, so memory is bounded by the number of DISTINCT
-    geometries (a handful per serve run), not the launch count.
-
-    Forcing completion per sampled launch defeats async dispatch — that
-    is the point (true per-launch wall time) and why the profiler is
-    opt-in rather than part of default-sampling telemetry."""
-
-    def __init__(self, sample_every: int = 1):
-        assert sample_every >= 1
-        self.sample_every = sample_every
-        self._tick = 0
-        self.sampled = 0
-        # (name, tag, geometry) -> [count, total_seconds]
-        self.records: dict[tuple, list] = {}
-
-    def want(self) -> bool:
-        self._tick += 1
-        return (self._tick - 1) % self.sample_every == 0
-
-    def record(self, name: str, tag: str, geometry: tuple,
-               dt_s: float) -> None:
-        self.sampled += 1
-        rec = self.records.setdefault((name, tag, geometry), [0, 0.0])
-        rec[0] += 1
-        rec[1] += dt_s
-
-
-_PROFILER: KernelProfiler = None
-
-
-def profiler() -> KernelProfiler:
-    """The installed kernel profiler, or None (the default: attn_entry's
-    hook is a single ``is None`` check per call)."""
-    return _PROFILER
-
-
-def set_profiler(p: KernelProfiler) -> KernelProfiler:
-    global _PROFILER
-    prev, _PROFILER = _PROFILER, p
-    return prev

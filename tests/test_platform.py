@@ -2,16 +2,13 @@
 path (compiled Pallas kernels on a TPU, XLA twins elsewhere), where the
 persistent compilation cache lives, and which published peaks a roofline
 share is taken against."""
-from types import SimpleNamespace
-
 import jax
 import pytest
 from jax.experimental.compilation_cache import compilation_cache
 
 from repro.configs import get_config
 from repro.core import attn_spec
-from repro.launch import compile_cache, obs, roofline
-from repro.runtime import telemetry
+from repro.launch import compile_cache, roofline
 
 
 def test_attn_spec_off_tpu_takes_xla_and_interprets_kernels():
@@ -66,28 +63,3 @@ def test_peaks_keyed_by_device_kind():
     assert "TPU v5e" in pk.source
     with pytest.raises(ValueError, match="no published peaks"):
         roofline.peaks("cpu")
-
-
-def _profile():
-    prof = telemetry.KernelProfiler(1)
-    prof.record("etap_decode_mla_paged", "mode=etap", (((1024,), "int8"),),
-                1e-6)
-    return prof
-
-
-def test_kernel_report_off_tpu_is_not_measured():
-    rows = obs.kernel_report(_profile())
-    assert rows[0]["roofline_fraction"] is None
-    assert rows[0]["achieved_gbps"] is None
-    lines = obs.kernel_report_lines(rows, 1, 1)
-    assert "not measured" in lines[0] and "not measured" in lines[1]
-
-
-def test_kernel_report_on_v5e_uses_its_peak():
-    v5e = SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
-    row = obs.kernel_report(_profile(), device=v5e)[0]
-    assert row["achieved_gbps"] == pytest.approx(1024 / 1e-6 / 1e9)
-    assert row["roofline_fraction"] == pytest.approx(1024 / 819e9 / 1e-6)
-    with pytest.raises(ValueError):
-        obs.kernel_report(_profile(), device=SimpleNamespace(
-            platform="tpu", device_kind="TPU v99"))

@@ -1,11 +1,15 @@
 """Telemetry layer (DESIGN.md §15): histogram algebra, ring-buffer
-bounds, Chrome trace-event schema, the attn_entry profiling hook, and the
+bounds, Chrome trace-event schema, spans on the profiler's clock, and the
 serve-loop acceptance criteria — telemetry-on is BITWISE output-identical
-to telemetry-off (fp and int8+prefix-cache legs) and the exported trace
-covers the full request lifecycle under a contended burst."""
+to telemetry-off (fp and int8+prefix-cache legs, profiler session on and
+off), the exported trace covers the full request lifecycle under a
+contended burst, and the profiler's trace holds the serve loop's spans."""
 import dataclasses
+import gc
 import json
 import math
+import warnings
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -133,10 +137,10 @@ def test_tracer_ring_bounded():
 def test_trace_export_schema(tmp_path):
     tr = telemetry.Tracer(capacity=64)
     tr.instant("enqueued", tid=1001, args={"req": 1})
-    with tr.span("prefill_chunk", args={"tokens": 8}):
+    with tr.span("serve.prefill.launch", req=1, tokens=8):
         pass
     t0 = tr.now_us()
-    tr.complete("decode_step", t0)
+    tr.complete("serve.decode.wait", t0)
     path = str(tmp_path / "trace.json")
     stats = tr.export(path)
     assert stats["recorded"] == 3 and stats["dropped"] == 0
@@ -152,42 +156,6 @@ def test_trace_export_schema(tmp_path):
             assert e["s"] == "t"
     ts = [e["ts"] for e in evs]
     assert ts == sorted(ts)                      # monotonic after sort
-
-
-# ------------------------------------------------------ kernel profiling
-def test_profiler_sampling_pattern():
-    p = telemetry.KernelProfiler(sample_every=3)
-    assert [p.want() for _ in range(7)] \
-        == [True, False, False, True, False, False, True]
-
-
-def test_profiler_hooks_attn_entry():
-    """attn_entry times concrete launches under an installed profiler and
-    tags them with entry name + spec; under an outer trace (args are
-    tracers, block_until_ready would be invalid) the hook must skip,
-    not crash."""
-    from repro.kernels.etap import ops as etap_ops
-    rng = np.random.default_rng(0)
-    q = jnp.asarray(rng.normal(size=(1, 2, 64)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(1, 128, 64)), jnp.float32)
-    v = k[..., :32]
-    prev = telemetry.set_profiler(telemetry.KernelProfiler(1))
-    try:
-        prof = telemetry.profiler()
-        ref = etap_ops.etap_decode(q, k, v, None, scale=64 ** -0.5, block=64)
-        assert prof.sampled == 1
-        ((name, tag, geom),) = prof.records
-        cnt, tot = prof.records[(name, tag, geom)]
-        assert name == "etap_decode" and "mode=" in tag
-        assert cnt == 1 and tot >= 0.0 and geom     # geometry captured
-        jitted = jax.jit(lambda q: etap_ops.etap_decode(
-            q, k, v, None, scale=64 ** -0.5, block=64))
-        out = jitted(q)
-        assert prof.sampled == 1                    # guard skipped the hook
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=1e-6, atol=1e-6)
-    finally:
-        telemetry.set_profiler(prev)
 
 
 # ------------------------------------------------------------ end to end
@@ -232,8 +200,8 @@ def test_serve_trace_bitwise_and_lifecycle(tmp_path):
     ts = [e["ts"] for e in evs]
     assert ts == sorted(ts)
     names = {e["name"] for e in evs}
-    need = {"enqueued", "admitted", "finished",
-            "prefill_chunk", "decode_step", "verify_step"}
+    need = {"enqueued", "admitted", "finished", "serve.tick",
+            "serve.prefill.wait", "serve.decode.wait", "serve.verify.wait"}
     if inst["kv_dtype"] == "fp":       # quantized legs widen slots and may
         assert inst["sched"]["preempts_recompute"] > 0   # never contend
         need |= {"preempted", "restored"}
@@ -244,6 +212,8 @@ def test_serve_trace_bitwise_and_lifecycle(tmp_path):
     snap = inst["metrics"]
     assert met["metrics"] == snap
     assert snap["counters"]["serve/decode_tokens"] == inst["decode_tokens"]
+    # one arrival -> first-chunk wait per request, restores not again
+    assert snap["histograms"]["sched/prefill_wait_ms"]["count"] == 6
     # class_stats() and the snapshot render from the same histograms
     for cls, cstats in inst["classes"].items():
         hd = snap["histograms"][f"sched/class{cls}/ttft_ms"]
@@ -265,7 +235,7 @@ def test_serve_bitwise_int8_prefix(tmp_path):
     inst = _serve(base + ["--trace-out", tpath, "--metrics-out", mpath],
                   cfg)
     assert inst["outputs"] == plain["outputs"]
-    assert {"prefill_chunk", "decode_step"} \
+    assert {"serve.prefill.wait", "serve.decode.wait", "prefix.match"} \
         <= {e["name"] for e in json.load(open(tpath))["traceEvents"]}
     assert json.load(open(mpath))["metrics"]["counters"][
         "serve/decode_tokens"] == inst["decode_tokens"]
@@ -287,3 +257,136 @@ def test_fault_injection_counters_pinned():
     assert c["serve/worker_restarts"] == res["worker_restarts"]
     assert c["ft/heartbeats"] == c["serve/ticks"]
     assert c["serve/replayed_tokens"] == res["replayed_tokens"]
+
+
+# ------------------------------------------------- spans on the profiler
+def test_tracer_without_ring_records_nothing():
+    """capacity=0 (no --trace-out): spans reach the profiler only."""
+    tr = telemetry.Tracer(capacity=0)
+    with tr.span("serve.tick", step_num=3):
+        with tr.span("prefix.match") as sp:
+            sp.set_metadata(blocks=2)
+    tr.instant("enqueued", tid=1001)
+    tr.complete("serve.decode.wait", tr.now_us())
+    assert tr.recorded == 0 and len(tr.to_events()) == 1   # meta only
+
+
+def test_gc_spans_cover_collections_and_uninstall():
+    tr = telemetry.Tracer(capacity=64)
+    n_callbacks = len(gc.callbacks)
+    enabled = gc.isenabled()
+    gc.disable()                                  # only the collections below
+    try:
+        with tr.gc_spans():
+            assert len(gc.callbacks) == n_callbacks + 1
+            gc.collect()
+        assert len(gc.callbacks) == n_callbacks
+        gc.collect()                              # no longer recorded
+    finally:
+        if enabled:
+            gc.enable()
+    spans = [e for e in tr.to_events() if e["name"] == "python.gc"]
+    assert len(spans) == 1 and spans[0]["args"] == {"generation": 2}
+
+
+def _host_events(log_dir) -> dict:
+    """{line name: [(name, start_ns, dur_ns, stats)]} of the host planes
+    of the newest xplane under ``log_dir``."""
+    from jax.profiler import ProfileData
+    path = sorted(Path(log_dir).glob("plugins/profile/*/*.xplane.pb"))[-1]
+    out = {}
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", DeprecationWarning)
+        for plane in ProfileData.from_file(str(path)).planes:
+            if not plane.name.startswith("/host:"):
+                continue
+            for line in plane.lines:
+                out.setdefault(line.name, []).extend(
+                    (ev.name, ev.start_ns, ev.duration_ns, dict(ev.stats))
+                    for ev in line.events)
+    return out
+
+
+def _profiled(log_dir):
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    return jax.profiler.trace(str(log_dir), profiler_options=opts)
+
+
+SPAN_RUN = ["--reduced", "--batch", "2", "--prompt", "16", "--gen", "6",
+            "--requests", "4", "--page-size", "8", "--prefill-chunk", "8",
+            "--cache-layout", "paged", "--shared-prefix", "8",
+            "--arrival-rate", "0.5", "--seed", "0"]
+
+
+@pytest.fixture(scope="module")
+def profiled_serve(tmp_path_factory):
+    """One tiny run_paged with no profiler session and one inside
+    ``jax.profiler.trace``: (plain result, traced result, spans)."""
+    cfg = _no_moe_cfg()
+    plain = _serve(SPAN_RUN, cfg)
+    log_dir = tmp_path_factory.mktemp("xplane")
+    with _profiled(log_dir):
+        traced = _serve(SPAN_RUN, cfg)
+    return plain, traced, _host_events(log_dir)
+
+
+def _inside(inner, outer) -> bool:
+    return outer[1] <= inner[1] and inner[1] + inner[2] <= outer[1] + outer[2]
+
+
+def test_profiled_serve_spans_nest_in_ticks(profiled_serve):
+    """The xplane holds one serve.tick per tick run; every prefill and
+    decode span nests inside a tick; one wait span per device call the
+    run's counters report."""
+    _, res, host = profiled_serve
+    spans = [ev for ev in host["python"]
+             if ev[0].startswith(("serve.", "sched.", "prefix."))]
+    ticks = [ev for ev in spans if ev[0] == "serve.tick"]
+    c = res["metrics"]["counters"]
+    assert len(ticks) == c["serve/ticks"]
+    assert sorted(ev[3]["step_num"] for ev in ticks) \
+        == list(range(c["serve/ticks"]))
+    work = [ev for ev in spans
+            if ev[0].startswith(("serve.prefill.", "serve.decode."))]
+    assert work and all(any(_inside(ev, t) for t in ticks) for ev in work)
+    waits = [ev for ev in spans if ev[0].endswith(".wait")]
+    assert len(waits) == c["serve/prefill_chunks"] + c["serve/decode_steps"]
+    names = {ev[0] for ev in spans}
+    assert {"sched.admit", "prefix.match", "prefix.insert",
+            "serve.prefill.launch", "serve.decode.launch",
+            "serve.decode.retire"} <= names
+    # the jitted serve programs carry their names into the trace
+    modules = {ev[3].get("hlo_module") for evs in host.values()
+               for ev in evs}
+    assert {"jit_serve_decode_step", "jit_serve_prefill_chunk"} <= modules
+
+
+def test_profiled_serve_is_bitwise_and_records_prefill_wait(profiled_serve):
+    plain, res, _ = profiled_serve
+    assert res["outputs"] == plain["outputs"]
+    h = res["metrics"]["histograms"]["sched/prefill_wait_ms"]
+    assert h["count"] == 4 and h["max"] > 0.0
+
+
+def test_span_and_device_op_share_one_clock(tmp_path):
+    """A jitted matmul run inside serve.tick > serve.decode.wait: its
+    operation event on the CPU client's thread lies inside the wait span,
+    so spans and operations are on one clock."""
+    tr = telemetry.Tracer(capacity=0)
+    f = jax.jit(lambda a: a @ a)
+    x = jnp.ones((512, 512), jnp.float32)
+    f(x).block_until_ready()
+    with _profiled(tmp_path):
+        with tr.span("serve.tick", step_num=0):
+            with tr.span("serve.decode.wait"):
+                f(x).block_until_ready()
+    host = _host_events(tmp_path)
+    (wait,) = [ev for ev in host["python"] if ev[0] == "serve.decode.wait"]
+    (tick,) = [ev for ev in host["python"] if ev[0] == "serve.tick"]
+    ops = [ev for name, evs in host.items()
+           if name.startswith("tf_XLAPjRtCpuClient") for ev in evs
+           if ev[0].startswith("dot_general")]
+    assert ops and _inside(wait, tick)
+    assert all(_inside(op, wait) for op in ops)
