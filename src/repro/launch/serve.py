@@ -43,6 +43,7 @@ import argparse
 import os
 import time
 from collections import deque
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
@@ -183,6 +184,39 @@ def _make_requests(args, vocab: int):
     return reqs
 
 
+def paged_programs(cfg, layout, spec):
+    """Every jitted program that takes or returns the paged pool.
+
+    ``init`` creates the pool; ``step`` / ``prefill`` / ``verify`` (the
+    serve entries), ``copy_block`` (COW) and ``write_blocks`` (swap
+    restore) each take the pool donated and hand it back in the layout it
+    came in, so it is updated in place from one program to the next.  A
+    new program that rebinds the pool must do the same (DESIGN.md §8).
+    Named functions, so the compiled modules and the device trace say
+    which program ran."""
+    def serve_init_pool():
+        return model.init_paged_cache(cfg, layout, kv_dtype=spec.kv_dtype)
+
+    def serve_decode_step(p, c, t, table, lengths):
+        return model.decode_step(p, cfg, c, t, None, spec=spec,
+                                 cache_layout="paged", block_table=table,
+                                 lengths=lengths)
+
+    def serve_prefill_chunk(p, c, t, table, lengths):
+        return model.prefill_chunk(p, cfg, c, t, table, lengths, spec=spec)
+
+    def serve_verify_step(p, c, t, table, lengths):
+        return model.verify_step(p, cfg, c, t, table, lengths, spec=spec)
+
+    return SimpleNamespace(
+        init=jax.jit(serve_init_pool),
+        step=jax.jit(serve_decode_step, donate_argnums=(1,)),
+        prefill=jax.jit(serve_prefill_chunk, donate_argnums=(1,)),
+        verify=jax.jit(serve_verify_step, donate_argnums=(1,)),
+        copy_block=jax.jit(model.copy_paged_block, donate_argnums=(0,)),
+        write_blocks=jax.jit(model.write_paged_blocks, donate_argnums=(0,)))
+
+
 def run_paged(args, cfg, *, record_logits: bool = False) -> dict:
     """Continuous-batching serve loop: CHUNKED paged prefill interleaved
     with decode under a per-step token budget, driven by the SLO-aware
@@ -264,7 +298,14 @@ def run_paged(args, cfg, *, record_logits: bool = False) -> dict:
     bp = BlockPool(layout, B, host_blocks=host_blocks, metrics=reg)
     prefix = (PrefixCache(layout.block_size, tracer=tracer)
               if args.prefix_cache else None)
-    cache = model.init_paged_cache(cfg, layout, kv_dtype=args.kv_dtype)
+    # serve spec: mode / kv_splits / kv layout / speculation for every
+    # attention layer of every program
+    spec = attn_spec.AttnSpec(mode=args.mode, kv_splits=args.kv_splits,
+                              kv_dtype=args.kv_dtype,
+                              spec_tokens=args.spec_tokens,
+                              spec_draft=args.spec_draft)
+    progs = paged_programs(cfg, layout, spec)
+    cache = progs.init()
     pending = deque(sorted(_make_requests(args, cfg.vocab_size),
                            key=lambda r: (r["arrival"], r["id"])))
     n_requests = len(pending)
@@ -282,10 +323,11 @@ def run_paged(args, cfg, *, record_logits: bool = False) -> dict:
 
     def _kv_write(ids, rows, start):
         sel = jax.tree.map(lambda r: r[:, start:start + len(ids)], rows)
-        holder["cache"] = model.write_paged_blocks(holder["cache"], ids, sel)
+        holder["cache"] = progs.write_blocks(holder["cache"],
+                                             np.asarray(ids, np.int32), sel)
 
     def _kv_copy(src, dst):
-        holder["cache"] = model.copy_paged_block(holder["cache"], src, dst)
+        holder["cache"] = progs.copy_block(holder["cache"], src, dst)
 
     sched = scheduler.Scheduler(
         bp, prefix,
@@ -304,29 +346,11 @@ def run_paged(args, cfg, *, record_logits: bool = False) -> dict:
                            metrics=reg)
     WORKER = "decode-worker-0"
 
-    # the cache pytree is DONATED through both jitted entries (as the dense
+    # the cache pytree is DONATED through every jitted entry (as the dense
     # path donates through its scan carry): the pool is updated in place
     # instead of copied per call, keeping admission's peak extra memory at
-    # one chunk, not a second pool.  Named functions, so the compiled
-    # modules and the device trace say which program ran.
-    spec = attn_spec.AttnSpec(mode=args.mode, kv_splits=args.kv_splits,
-                              kv_dtype=args.kv_dtype,
-                              spec_tokens=args.spec_tokens,
-                              spec_draft=args.spec_draft)
-
-    def serve_decode_step(p, c, t, table, lengths):
-        return model.decode_step(p, cfg, c, t, None, spec=spec,
-                                 cache_layout="paged", block_table=table,
-                                 lengths=lengths)
-
-    def serve_prefill_chunk(p, cch, t, table, lens):
-        return model.prefill_chunk(p, cfg, cch, t, table, lens, spec=spec)
-
-    def serve_verify_step(p, c, t, table, lengths):
-        return model.verify_step(p, cfg, c, t, table, lengths, spec=spec)
-
-    step_fn = jax.jit(serve_decode_step, donate_argnums=(1,))
-    prefill_fn = jax.jit(serve_prefill_chunk, donate_argnums=(1,))
+    # one chunk, not a second pool (paged_programs).
+    step_fn, prefill_fn = progs.step, progs.prefill
     # warm the decode step OUTSIDE the timed region (the dense path also
     # compiles before its timer): all slots inactive → the dummy rows land
     # in the reserved null block, so rebinding the returned cache (the
@@ -346,7 +370,7 @@ def run_paged(args, cfg, *, record_logits: bool = False) -> dict:
     if k_max > 0:
         drafter = spec_decode.make_drafter(args.spec_draft, params,
                                            metrics=reg)
-        verify_fn = jax.jit(serve_verify_step, donate_argnums=(1,))
+        verify_fn = progs.verify
         # warm the verify pass outside the timer too, with the same all-
         # null masked launch as step_fn: the k dummy rows land in the null
         # block and compile time never lands in t_decode

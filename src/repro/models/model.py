@@ -6,8 +6,10 @@ Layers are *grouped* so jax.lax.scan compiles each distinct block body once:
    "superblock" group + unrolled remainder;
  - irregular prefixes (deepseek's 3 dense + 58 MoE layers) become run-length
    segments.
-The KV/state cache pytree mirrors the grouping, so decode scans layers with
-(params, cache) as scan xs and the updated cache as scan ys.
+The KV/state cache pytree mirrors the grouping.  A dense cache rides the
+layer scan as xs/ys next to the params; a paged pool is carried whole
+instead (:func:`_paged_layers`), so no layer's pool is sliced out, relaid
+out or restacked inside the scan.
 """
 from __future__ import annotations
 
@@ -328,7 +330,9 @@ def copy_paged_block(cache, src: int, dst: int):
     The serve scheduler calls this on the COW pair returned by
     BlockPool.admit_shared when a cached prefix ends mid-block, BEFORE any
     chunk is appended to the new slot (DESIGN.md §10) — all layers share
-    one block table, so one (src, dst) pair covers the whole stack."""
+    one block table, so one (src, dst) pair covers the whole stack.  The
+    serve loop runs it jitted with the pool donated, so the copy is made
+    in place (launch/serve.paged_programs)."""
     return jax.tree.map(lambda p: p.at[:, dst].set(p[:, src]), cache)
 
 
@@ -350,8 +354,9 @@ def write_paged_blocks(cache, ids, rows):
     into physical blocks `ids` of every pool leaf — the host→device leg of
     swap restoration.  Dtypes already match (the host copy kept the pool's
     storage dtype), so the round-trip is bitwise and a restored sequence
-    decodes exactly as if it had never been preempted."""
-    idx = jnp.asarray(np.asarray(ids, np.int32))
+    decodes exactly as if it had never been preempted.  Jitted like
+    :func:`copy_paged_block` in the serve loop."""
+    idx = jnp.asarray(ids, jnp.int32)
     return jax.tree.map(
         lambda p, r: p.at[:, idx].set(jnp.asarray(r).astype(p.dtype)),
         cache, rows)
@@ -419,25 +424,54 @@ def prefill_chunk(params, cfg, cache, tokens, block_table, lengths, *,
                           spec)
 
 
+def _paged_layers(params, cfg, cache, x, block_table, block):
+    """Run every layer group over a paged cache that is carried whole.
+
+    Each group's pool leaves ``[n, N, page, *F]`` enter the scan carry as
+    one flat ``[n*N, page, *F]`` view; only the params and the layer index
+    ride as ``xs``.  Layer ``i`` addresses its own N blocks through
+    ``block_table + i*N``, so its null block is ``i*N`` and inactive slots
+    stay masked as before.  The scatter writes the carry in place and the
+    kernels read it through the table: no layer's pool is sliced out,
+    relaid out or stacked back inside the scan.  Where the device's default
+    layout of the pool is not row-major, XLA relays each group's pool out
+    once on the way in and once on the way out (DESIGN.md §8).
+    ``block(lp, sig, x, pool, table)`` runs one block and returns
+    ``(x, pool)``.  Returns (x, new cache)."""
+    new_caches = []
+    for g, gparams, gcache in zip(layer_groups(cfg), params["groups"], cache,
+                                  strict=True):
+        n = g["n"]
+        N = jax.tree.leaves(gcache)[0].shape[1]
+        flat = jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), gcache)
+
+        def body(carry, xs, g=g, N=N):
+            x, pools = carry
+            lp, i = xs
+            table = block_table + i * N
+            new = {}
+            for j, sig in enumerate(g["sigs"]):
+                x, new[f"b{j}"] = block(lp[f"b{j}"], sig, x, pools[f"b{j}"],
+                                        table)
+            return (x, new), None
+
+        (x, flat), _ = jax.lax.scan(body, (x, flat),
+                                    (gparams, jnp.arange(n, dtype=jnp.int32)))
+        new_caches.append(jax.tree.map(lambda a, ref: a.reshape(ref.shape),
+                                       flat, gcache))
+    return x, new_caches
+
+
 def _chunk_forward(params, cfg, cache, tokens, block_table, lengths, spec,
                    qpos=None):
     """Shared chunk-shaped forward of prefill_chunk and verify_step."""
     x = constrain(layers.embed(params["embed"], tokens), P(BATCH, None, None))
-    groups = layer_groups(cfg)
-    new_caches = []
-    for g, gparams, gcache in zip(groups, params["groups"], cache,
-                                  strict=True):
-        def body(x, xs, g=g):
-            lp, lc = xs
-            ncs = {}
-            for j, sig in enumerate(g["sigs"]):
-                x, nc = _block_prefill_chunk(lp[f"b{j}"], cfg, sig, x,
-                                             lc[f"b{j}"], block_table,
-                                             lengths, spec, qpos)
-                ncs[f"b{j}"] = nc
-            return x, ncs
-        x, gc_new = jax.lax.scan(body, x, (gparams, gcache))
-        new_caches.append(gc_new)
+
+    def block(lp, sig, x, pool, table):
+        return _block_prefill_chunk(lp, cfg, sig, x, pool, table, lengths,
+                                    spec, qpos)
+
+    x, new_caches = _paged_layers(params, cfg, cache, x, block_table, block)
     x = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = layers.unembed(params["embed"], x)
     return logits, new_caches
@@ -521,23 +555,28 @@ def decode_step(params, cfg, cache, tokens, pos, *, spec=None,
     if cache_layout == "paged":
         assert block_table is not None and lengths is not None
     x = constrain(layers.embed(params["embed"], tokens), P(BATCH, None))
-    groups = layer_groups(cfg)
-    new_caches = []
-    for g, gparams, gcache in zip(groups, params["groups"], cache,
-                                  strict=True):
-        def body(x, xs, g=g):
-            lp, lc = xs
-            ncs = {}
-            for j, sig in enumerate(g["sigs"]):
-                x, nc = _block_decode(lp[f"b{j}"], cfg, sig, x, lc[f"b{j}"],
-                                      pos, spec,
-                                      cache_layout=cache_layout,
-                                      block_table=block_table,
-                                      lengths=lengths)
-                ncs[f"b{j}"] = nc
-            return x, ncs
-        x, gc_new = jax.lax.scan(body, x, (gparams, gcache))
-        new_caches.append(gc_new)
+    if cache_layout == "paged":
+        def block(lp, sig, x, pool, table):
+            return _block_decode(lp, cfg, sig, x, pool, None, spec,
+                                 cache_layout="paged", block_table=table,
+                                 lengths=lengths)
+
+        x, new_caches = _paged_layers(params, cfg, cache, x, block_table,
+                                      block)
+    else:
+        # a dense cache has no table to offset: it rides the scan as xs/ys
+        new_caches = []
+        for g, gparams, gcache in zip(layer_groups(cfg), params["groups"],
+                                      cache, strict=True):
+            def body(x, xs, g=g):
+                lp, lc = xs
+                ncs = {}
+                for j, sig in enumerate(g["sigs"]):
+                    x, ncs[f"b{j}"] = _block_decode(lp[f"b{j}"], cfg, sig, x,
+                                                    lc[f"b{j}"], pos, spec)
+                return x, ncs
+            x, gc_new = jax.lax.scan(body, x, (gparams, gcache))
+            new_caches.append(gc_new)
     x = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = layers.unembed(params["embed"], x)
     return logits, new_caches

@@ -370,3 +370,145 @@ def test_continuous_batching_serve_loop():
     # the same byte budget, so the reported count is the bound)
     assert res["steps"] >= max(gens.values())
     assert res["tokens_served"] <= res["batch_slots"] * res["steps"]
+
+
+# ---------------------------------------------- the pool carried whole
+def _isolation_cfg(case):
+    """(config, kv layout) of a layer-isolation case: MLA in bf16 with a
+    lone dense layer and a scanned group of 3 MoE layers, the same stack
+    with int8 codes + "c_sz" scale pools, and a 3-layer GQA k/v stack."""
+    import dataclasses
+
+    from repro.configs import get_config, reduced
+    if case == "gqa":
+        return dataclasses.replace(reduced(get_config("qwen3_8b"),
+                                           kv_heads=2), num_layers=3), "fp"
+    cfg = dataclasses.replace(reduced(get_config("deepseek_r1_671b")),
+                              num_layers=4)
+    if case == "mla-bf16":
+        return dataclasses.replace(cfg, dtype="bfloat16"), "fp"
+    return cfg, "int8"
+
+
+def _distinct_pools(cache, seed):
+    """Random values in every leaf, so each layer's pool differs from every
+    other layer's everywhere (scales and zero-points stay positive)."""
+    rng = np.random.default_rng(seed)
+
+    def fill(a):
+        if jnp.issubdtype(a.dtype, jnp.integer):
+            return jnp.asarray(rng.integers(-100, 100, a.shape), a.dtype)
+        return jnp.asarray(rng.uniform(0.5, 1.5, a.shape), a.dtype)
+    return jax.tree.map(fill, cache)
+
+
+def _assert_rows_written(old, new, table, starts, n_rows):
+    """Every layer's pool changed exactly at the rows each batch row wrote
+    through the shared table (n_rows tokens from starts[b]), in its own
+    pool: an all-null table row writes its own layer's null block."""
+    page = jax.tree.leaves(old)[0].shape[2]
+    n_blocks = jax.tree.leaves(old)[0].shape[1]
+    want = np.zeros((n_blocks, page), bool)
+    for b, s in enumerate(starts):
+        for t in range(s, s + n_rows):
+            want[table[b, t // page], t % page] = True
+    for g, (g_old, g_new) in enumerate(zip(old, new, strict=True)):
+        for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(g_old),
+                                jax.tree.leaves(g_new), strict=True):
+            o = np.asarray(a).astype(np.float32)
+            n = np.asarray(b).astype(np.float32)
+            for i in range(o.shape[0]):
+                moved = (o[i] != n[i]).reshape(n_blocks, page, -1).any(-1)
+                where = f"group {g} layer {i} {jax.tree_util.keystr(path)}"
+                assert not (moved & ~want).any(), \
+                    f"{where} changed rows it never wrote: " \
+                    f"{np.argwhere(moved & ~want)[:4].tolist()}"
+                assert moved[want].all(), \
+                    f"{where} missed rows it wrote: " \
+                    f"{np.argwhere(want & ~moved)[:4].tolist()}"
+
+
+@pytest.mark.parametrize("case", ["mla-bf16", "mla-int8", "gqa"])
+def test_paged_programs_write_only_their_own_layer(case):
+    """The paged decode step, prefill chunk and verify step carry each layer
+    group's pool as one flat view and address layer i through
+    table + i*N: each layer's pool changes only at the rows that layer
+    wrote, and an inactive slot (all-null table row) writes only into its
+    own layer's null block.  Chunk rows straddle block boundaries."""
+    from repro.core import attn_spec
+    from repro.models import model
+
+    cfg, kv_dtype = _isolation_cfg(case)
+    params = model.init(jax.random.PRNGKey(0), cfg)
+    layout = pc.layout_for(3, 32, block_size=8, spare_blocks=2)
+    bp = pc.BlockPool(layout, 3)
+    for b, n in ((0, 13), (1, 5)):          # slot 2 stays inactive
+        assert bp.admit(0, 32) == b
+        bp.extend(b, n)
+    table, lengths = bp.device_views()
+    host_table, starts = np.asarray(table), np.asarray(lengths)
+    assert not host_table[2].any() and starts[2] == 0
+    cache = _distinct_pools(
+        model.init_paged_cache(cfg, layout, kv_dtype=kv_dtype), seed=1)
+    spec = attn_spec.AttnSpec(kv_dtype=kv_dtype)
+    toks = jax.random.randint(jax.random.PRNGKey(2), (3, 6), 0,
+                              cfg.vocab_size)
+
+    _, new = model.decode_step(params, cfg, cache, toks[:, 0], None,
+                               spec=spec, cache_layout="paged",
+                               block_table=table, lengths=lengths)
+    _assert_rows_written(cache, new, host_table, starts, 1)
+    _, new = model.prefill_chunk(params, cfg, cache, toks, table, lengths,
+                                 spec=spec)            # 13..18 crosses 16
+    _assert_rows_written(cache, new, host_table, starts, 6)
+    _, new = model.verify_step(params, cfg, cache, toks[:, :3], table,
+                               lengths, spec=spec)
+    _assert_rows_written(cache, new, host_table, starts, 3)
+
+
+def test_pool_format_survives_every_writer():
+    """init -> decode step -> COW copy -> swap write -> decode step through
+    launch/serve.paged_programs: every program takes the pool in the format
+    the serve entries were compiled for, hands it back in that format, and
+    consumes (donates) its input, so the pool is updated in place and no
+    program relays it out or compiles again for another layout."""
+    import dataclasses
+
+    from repro.configs import get_config, reduced
+    from repro.core import attn_spec
+    from repro.launch import serve
+    from repro.models import model
+
+    cfg = dataclasses.replace(reduced(get_config("deepseek_r1_671b")),
+                              num_layers=4)
+    params = model.init(jax.random.PRNGKey(0), cfg)
+    layout = pc.layout_for(2, 32, block_size=8)
+    progs = serve.paged_programs(cfg, layout, attn_spec.AttnSpec())
+    bp = pc.BlockPool(layout, 2)
+    assert bp.admit(0, 32) == 0
+    table, lengths = bp.device_views()
+    tok = jnp.zeros((2,), jnp.int32)
+    cache = progs.init()
+    step_in = progs.step.lower(params, cache, tok, table,
+                               lengths).compile().input_formats[0][1]
+
+    def formats(c):
+        return jax.tree.map(lambda a: a.format, c)
+
+    def check(before, after):
+        assert formats(after) == step_in
+        assert all(a.is_deleted() for a in jax.tree.leaves(before))
+
+    assert formats(cache) == step_in
+    _, c1 = progs.step(params, cache, tok, table, lengths)
+    check(cache, c1)
+    c2 = progs.copy_block(c1, 1, 3)
+    check(c1, c2)
+    rows = model.read_paged_blocks(c2, [1, 3])
+    c3 = progs.write_blocks(c2, np.asarray([4, 5], np.int32), rows)
+    check(c2, c3)
+    for leaf, row in zip(jax.tree.leaves(c3), jax.tree.leaves(rows),
+                         strict=True):
+        np.testing.assert_array_equal(np.asarray(leaf[:, 4:6]), row)
+    _, c4 = progs.step(params, c3, tok, table, lengths)
+    check(c3, c4)

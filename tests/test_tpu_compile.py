@@ -5,7 +5,10 @@ decode step) at DeepSeek-R1 chip-share widths — B 16, H 16, latent 576,
 Dv 512, page 64, bf16 — for one chip of a ``v5e:2x2`` topology, and
 asserts the compiled program holds a Mosaic kernel (``tpu_custom_call``).
 Mosaic's block-tiling rule and its VMEM budget are enforced here, where
-interpret mode enforces neither.
+interpret mode enforces neither.  The serve programs of
+``launch/serve.paged_programs`` are compiled as the serve loop jits them:
+their compiled text must hold no whole-pool copy inside the layer scan,
+and their pool formats must agree from one program to the next.
 
 The topology is described inside a module fixture (never at import):
 only one process may hold the TPU library, and xdist workers all import
@@ -15,7 +18,9 @@ compiles — an entry written for a described chip cannot be read back.
 from __future__ import annotations
 
 import dataclasses
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +29,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core import attn_spec
 from repro.kernels.etap import ops
+from repro.launch import serve
 from repro.models import model
 from repro.runtime.paged_cache import layout_for
 
@@ -137,3 +143,130 @@ def test_decode_step_full_width(one_chip, chip_smoke):
     text = _compile(step, one_chip, params, cache, ((B,), jnp.int32),
                     TABLE, LENS)
     assert "tpu_custom_call" in text
+
+
+# ------------------------------------------------ the pool kept in place
+_INSTR = re.compile(r"^\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\{[^}]*\} "
+                    r"([\w-]+)\(")
+_COMP = re.compile(r"^(?:ENTRY )?%(\S+) .*\{\s*$")
+_CALLS = re.compile(r"calls=%([\w.\-]+)")
+_BODY = re.compile(r"body=%([\w.\-]+)")
+_COPYING = ("copy", "dynamic-slice", "dynamic-update-slice")
+CELL_TOKENS = 16_384 + 128
+
+
+def whole_pool_copies(text, n_blocks, row):
+    """(name, opcode, shape, in a loop body) of every copy, dynamic-slice
+    or dynamic-update-slice in compiled HLO ``text``, or fusion wrapping
+    one, whose result is whole layer pools: a shape ending in ``row``
+    (page, latent) whose leading dims hold a multiple of ``n_blocks``
+    blocks — ``[n, N, page, F]``, ``[N, page, F]`` or ``[n*N, page, F]``.
+    Instructions inside fused computations are judged through their
+    fusion; the in-place row scatter is no copy and is not listed."""
+    comps, body, fused, loops = {}, None, set(), set()
+    for line in text.splitlines():
+        m = _COMP.match(line)
+        if m:
+            body = comps.setdefault(m.group(1), [])
+            continue
+        loop = _BODY.search(line) if " while(" in line else None
+        if loop:
+            loops.add(loop.group(1))
+        m = _INSTR.match(line)
+        if m and body is not None:
+            callee = _CALLS.search(line) if m.group(3) == "fusion" else None
+            if callee:
+                fused.add(callee.group(1))
+            dims = tuple(int(d) for d in m.group(2).split(",") if d)
+            body.append((m.group(1), m.group(3), dims,
+                         callee.group(1) if callee else None))
+    found = []
+    for name, instrs in comps.items():
+        if name in fused:
+            continue
+        for op_name, op, dims, callee in instrs:
+            whole = (len(dims) > len(row) and dims[-len(row):] == row
+                     and math.prod(dims[:-len(row)]) % n_blocks == 0)
+            wraps = op == "fusion" and any(
+                o in _COPYING for _, o, _, _ in comps.get(callee, ()))
+            if whole and (op in _COPYING or wraps):
+                found.append((op_name, op, dims, name in loops))
+    return found
+
+
+@pytest.fixture(scope="module")
+def r1_programs(one_chip, chip_smoke):
+    """The serve programs of a 4-layer R1 chip share (3 scanned dense
+    layers + 1 lone MoE layer) for the described chip, over the
+    r1-doc-decode cell's pool (16 slots of 16,512 tokens: 4,129 blocks,
+    where the chip's default pool layout puts the block axis minor),
+    with shape arguments placed on it."""
+    cfg = dataclasses.replace(chip_smoke.chip_share_config(),
+                              use_kernels=True)
+    layout = layout_for(B, CELL_TOKENS, block_size=PAGE)
+    progs = serve.paged_programs(
+        cfg, layout, attn_spec.AttnSpec(mode="etap", interpret=False))
+
+    def place(s):
+        return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+
+    def arr(shape):
+        return place(jax.ShapeDtypeStruct(shape, jnp.int32))
+
+    params = jax.tree.map(place, jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), cfg)))
+    cache = jax.tree.map(place, jax.eval_shape(progs.init))
+    nb = layout.max_blocks
+    args = {"decode": (progs.step, (B,), (B, nb)),
+            "prefill": (progs.prefill, (1, 512), (1, nb)),
+            "verify": (progs.verify, (B, 4), (B, nb))}
+    compiled = {}
+
+    def compile_(name):
+        if name not in compiled:
+            fn, toks, table = args[name]
+            compiled[name] = fn.lower(params, cache, arr(toks), arr(table),
+                                      arr(table[:1])).compile()
+        return compiled[name]
+    return cfg, progs, layout, cache, compile_
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill", "verify"])
+def test_serve_program_copies_the_pool_only_at_its_boundary(r1_programs,
+                                                          program):
+    """No serve program slices a layer's pool out of the stack, relays it
+    or writes it back inside the layer scan: the pool is carried whole.
+    What is left is one relayout of each group's pool on the way in and
+    one on the way out (the chip's default layout of a 4,129-block pool
+    is not the row-major one the kernels read): 2 x 4 layer pools."""
+    cfg, _, layout, _, compile_ = r1_programs
+    text = compile_(program).as_text()
+    assert "tpu_custom_call" in text
+    found = whole_pool_copies(text, layout.num_blocks, (PAGE, LAT))
+    inside = [f for f in found if f[3] or f[1] != "copy"]
+    assert not inside, f"whole-pool copies in the layer scan: {inside}"
+    pools = sum(math.prod(d[:-2]) // layout.num_blocks for _, _, d, _ in found)
+    assert pools == 2 * cfg.num_layers, found
+
+
+def test_pool_format_agrees_across_every_writer(r1_programs, one_chip):
+    """init, the three serve entries, the COW copy and the swap write all
+    compile to one pool format, in and out: the pool one program hands on
+    is the pool the next was compiled for, so none relays it between
+    programs or compiles again for another layout."""
+    _, progs, _, cache, compile_ = r1_programs
+    want = jax.jit(progs.init, out_shardings=one_chip).lower().compile() \
+        .output_formats
+    for name in ("decode", "prefill", "verify"):
+        c = compile_(name)
+        assert c.input_formats[0][1] == want, name
+        assert c.output_formats[1] == want, name
+    i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    ids = jax.ShapeDtypeStruct((2,), jnp.int32, sharding=one_chip)
+    rows = jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+        (s.shape[0], 2) + s.shape[2:], s.dtype, sharding=one_chip), cache)
+    for fn, args in ((progs.copy_block, (i32, i32)),
+                     (progs.write_blocks, (ids, rows))):
+        c = fn.lower(cache, *args).compile()
+        assert c.input_formats[0][0] == want
+        assert c.output_formats == want
