@@ -50,8 +50,8 @@ def _say(msg: str) -> None:
 # ------------------------------------------------------------ the files
 def load_cell(root: Path, name: str) -> SimpleNamespace:
     """The cell's entry in BENCHMARK.json and the files it names:
-    bench/workloads/<cell>.json, bench/traffic/<traffic>.json and the
-    configuration's file."""
+    bench/workloads/<cell>.json, bench/traffic/<traffic>.json, the
+    configuration's file and its architecture's bench/arch/<model_type>.py."""
     spec = json.loads((root / "BENCHMARK.json").read_text())
     wl = {w["name"]: w for w in spec["workloads"]}.get(name)
     if wl is None:
@@ -63,9 +63,11 @@ def load_cell(root: Path, name: str) -> SimpleNamespace:
                      .read_text())
     if cell["config"] != wl["config"] or cell["traffic"] != wl["traffic"]:
         raise SystemExit(f"{name}: workload file and BENCHMARK.json differ")
+    config = config_mod.load(root / cfg_entry["file"])
     return SimpleNamespace(name=name, spec=spec, entry=wl, cell=cell,
-                           mix=mix, config_name=wl["config"],
-                           config=config_mod.load(root / cfg_entry["file"]),
+                           mix=mix, config_name=wl["config"], config=config,
+                           architecture=config_mod.architecture(
+                               config, bench / "arch"),
                            chips=int(wl["chips"]), root=root)
 
 
@@ -255,8 +257,8 @@ def _numbers(ref, served, got) -> tuple:
     return float(np.max(best - picked)), err, scale
 
 
-def compare(arch, seed, sample, outputs, seed_logits, first_logits,
-            control: bool = False) -> dict:
+def compare(architecture, arch, seed, sample, outputs, seed_logits,
+            first_logits, control: bool = False) -> dict:
     """The numbers ``correct`` is decided by, for the sampled requests.
 
     served_gap: the widest gap by which a served (greedy) token's
@@ -266,8 +268,8 @@ def compare(arch, seed, sample, outputs, seed_logits, first_logits,
     row), over the largest |reference| logit of those rows.
     With ``control`` the reference in float8 is put in the program's place,
     on the same prompts and served tokens: its own argmax at each position
-    is the served token, its rows are the recorded ones."""
-    from reference import mla_moe
+    is the served token, its rows are the recorded ones.  The reference
+    is the configuration's architecture module's ``logits``."""
     seqs, rows = [], []
     for r in sample:
         served = list(outputs[r.id])
@@ -275,8 +277,8 @@ def compare(arch, seed, sample, outputs, seed_logits, first_logits,
                                                          np.int32)]))
         rows.append(np.arange(len(r.prompt) - 1,
                               len(r.prompt) - 1 + len(served)))
-    ref = mla_moe.logits(arch, seed, seqs, rows, mode="fp32")
-    alt = mla_moe.logits(arch, seed, seqs, rows, mode="fp8") \
+    ref = architecture.logits(arch, seed, seqs, rows, mode="fp32")
+    alt = architecture.logits(arch, seed, seqs, rows, mode="fp8") \
         if control else None
     gap = err = scale = 0.0
     tokens = 0
@@ -334,8 +336,8 @@ def run(root: Path, name: str, seed: int, seconds: int, traced: bool,
     from repro.kernels import softmax_state
     from repro.launch import serve
     clog = CompileLog()
-    arch = config_mod.arch(c.config)
-    cfg = config_mod.model_config(c.config, c.config_name)
+    arch = c.architecture.arch(c.config)
+    cfg = c.architecture.model_config(c.config, c.config_name)
     n = max(1, round(float(c.mix["requests_per_second"]) * seconds))
     prog_seed = traffic.program_seed(c.mix["flags"], n, seed, arch["vocab"])
     flags = dict(c.mix["flags"], **{"--seed": prog_seed, "--requests": n})
@@ -440,7 +442,8 @@ def run(root: Path, name: str, seed: int, seconds: int, traced: bool,
     if traced:
         reduced = reduce_trace(tracer)
         ctx = SimpleNamespace(
-            result=res, arch=arch, flags=flags, requests=reqs, ticks=ticks,
+            result=res, arch=arch, architecture=c.architecture,
+            flags=flags, requests=reqs, ticks=ticks,
             trace=reduced, trace_ticks=tracer.ticks, peaks=pk,
             pool_bytes=POOL_BYTES[flags.get("--kv-dtype", "fp")],
             compile_events=inside, loop=loop, ttft_ms=ttft)
@@ -457,8 +460,8 @@ def run(root: Path, name: str, seed: int, seconds: int, traced: bool,
     chk = c.cell["check"]
     sample = sample_requests(reqs, outputs, int(chk["requests"]), prog_seed)
     t_ref = time.perf_counter()
-    got = compare(arch, prog_seed, sample, outputs, seed_logits,
-                  first_logits, control) if sample else {
+    got = compare(c.architecture, arch, prog_seed, sample, outputs,
+                  seed_logits, first_logits, control) if sample else {
         "served_gap": None, "logit_err": None, "tokens": 0}
     _say(f"{'control' if control else 'reference'}: {len(sample)} "
          f"requests, {got['tokens']} served tokens, "

@@ -107,6 +107,40 @@ def _half_left_out(monkeypatch):
                         lambda args, vocab: make(args, vocab)[::2])
 
 
+TINY_ARCH = """\"\"\"A test architecture: DeepSeek-V3's, with a reference that
+leaves a mark beside this file for each call.\"\"\"
+from pathlib import Path
+
+from arch import deepseek_v3
+
+arch, model_config = deepseek_v3.arch, deepseek_v3.model_config
+step_flops = deepseek_v3.step_flops
+
+
+def logits(arch, seed, seqs, rows, *, mode):
+    with (Path(__file__).parent / "tiny_v3.calls").open("a") as f:
+        f.write(mode + "\\n")
+    return deepseek_v3.logits(arch, seed, seqs, rows, mode=mode)
+"""
+
+
+def test_new_architecture_is_new_files_only(checkout):
+    """An architecture added as bench/arch/<model_type>.py, with a
+    configuration of that model_type, runs the cell to correct, and the
+    check goes through the new module's reference."""
+    b = checkout / "bench"
+    before = {p: p.read_bytes() for p in b.rglob("*") if p.is_file()}
+    (b / "arch" / "tiny_v3.py").write_text(TINY_ARCH)
+    (b / "configs" / "tiny.json").write_text(json.dumps(
+        dict(tiny(3, "bfloat16"), model_type="tiny_v3")))
+    before.pop(b / "configs" / "tiny.json")      # the test's own file
+    out = run_tiny(checkout, traced=True)
+    assert out["correct"] is True and out["failed"] == 0
+    assert out["metrics"]["batch_occupancy"]["value"] > 0
+    assert (b / "arch" / "tiny_v3.calls").read_text().split() == ["fp32"]
+    assert all(p.read_bytes() == v for p, v in before.items())
+
+
 @pytest.mark.parametrize("fault", [_altered_token, _half_left_out])
 def test_broken_timed_path_is_not_correct(checkout, monkeypatch, fault):
     fault(monkeypatch)

@@ -67,8 +67,8 @@ def test_served_path_agrees_with_reference(first_dense):
     faults, failed = cell.counter_faults(reqs, res, {})
     assert not faults and failed == 0
     assert res["prefill_tokens_saved"] > 0          # prefix hits served
-    got = cell.compare(a, 5, reqs, res["outputs"], res["seed_logits"],
-                       res["first_decode_logits"])
+    got = cell.compare(config.architecture(c), a, 5, reqs, res["outputs"],
+                       res["seed_logits"], res["first_decode_logits"])
     assert got["tokens"] == sum(r.gen for r in reqs)
     # float32 on both sides: rounding of a different summation order only
     assert got["served_gap"] == 0.0
