@@ -15,13 +15,26 @@ import jax.numpy as jnp
 
 
 @dataclass(frozen=True)
+class IndexerConfig:
+    """DeepSeek Sparse Attention's lightning indexer (DeepSeek-V3.2): each
+    query scores every causal row with ``n_heads`` ReLU'd dot products of
+    width ``head_dim`` against one shared index key per row, and attends
+    only its ``topk`` best rows (DESIGN.md §17)."""
+    n_heads: int = 64
+    head_dim: int = 128
+    topk: int = 2048
+
+
+@dataclass(frozen=True)
 class MLAConfig:
-    """DeepSeek-style Multi-head Latent Attention."""
+    """DeepSeek-style Multi-head Latent Attention; ``indexer`` set makes it
+    DeepSeek Sparse Attention."""
     q_lora_rank: int = 1536
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+    indexer: IndexerConfig | None = None
 
     @property
     def qk_head_dim(self) -> int:

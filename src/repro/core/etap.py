@@ -22,6 +22,8 @@ returns O: [BG, H, Dv]
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -398,7 +400,7 @@ def decode_attention_paged(q, k_pool, v_pool, table, lengths, *,
 
 
 def etap_prefill_xla(q, k, v, start, *, scale: float, block: int = 512,
-                     rescale: str | None = None):
+                     rescale: str | None = None, sel=None):
     """Chunked ETAP prefill, online softmax over KV blocks (the XLA twin of
     the paged Pallas prefill kernel — DESIGN.md §9).
 
@@ -409,15 +411,17 @@ def etap_prefill_xla(q, k, v, start, *, scale: float, block: int = 512,
     key position p is live for chunk row c iff p <= start + c.
     Implemented as the linear-chain special case of :func:`etap_verify_xla`
     (qpos = start + row index) — the two are bitwise identical there.
+    sel: [B, Cq, S] bool, the rows each query row may attend besides the
+    causal mask (DeepSeek Sparse Attention's top-k, DESIGN.md §17).
     Returns [B, Cq, H, Dv]."""
     Cq = q.shape[1]
     qpos = start[:, None] + jnp.arange(Cq, dtype=jnp.int32)[None, :]
     return etap_verify_xla(q, k, v, qpos, scale=scale, block=block,
-                           rescale=rescale)
+                           rescale=rescale, sel=sel)
 
 
 def etap_verify_xla(q, k, v, qpos, *, scale: float, block: int = 512,
-                    rescale: str | None = None):
+                    rescale: str | None = None, sel=None):
     """Draft-verify ETAP attention: the chunked-prefill loop with an
     EXPLICIT per-query-row causal horizon (DESIGN.md §14).
 
@@ -440,6 +444,8 @@ def etap_verify_xla(q, k, v, qpos, *, scale: float, block: int = 512,
     qT = jnp.swapaxes(q.reshape(B, CH, Dk), 1, 2).astype(jnp.float32)
     # column c*H + h of the transposed score tile is query row c
     qpos = jnp.repeat(qpos.astype(jnp.int32), H, axis=1)       # [B, CH]
+    if sel is not None:
+        sel = jnp.repeat(sel, H, axis=1)                       # [B, CH, S]
 
     def step(j, carry):
         kj = jax.lax.dynamic_slice_in_dim(k, j * block, block, axis=1)
@@ -448,6 +454,9 @@ def etap_verify_xla(q, k, v, qpos, *, scale: float, block: int = 512,
                         preferred_element_type=jnp.float32) * scale
         kpos = j * block + jnp.arange(block, dtype=jnp.int32)  # [block]
         valid = kpos[None, :, None] <= qpos[:, None, :]        # [B,block,CH]
+        if sel is not None:
+            valid = valid & jnp.swapaxes(jax.lax.dynamic_slice_in_dim(
+                sel, j * block, block, axis=2), 1, 2)
         sT = jnp.where(valid, sT, NEG_INF)
         return softmax_state.update(
             carry, sT,
@@ -462,7 +471,8 @@ def etap_verify_xla(q, k, v, qpos, *, scale: float, block: int = 512,
 
 
 def prefill_attention_paged(q, k_pool, v_pool, table, start, *, spec=None,
-                            dv: int = 0, k_sz=None, v_sz=None, **legacy):
+                            dv: int = 0, k_sz=None, v_sz=None, sel=None,
+                            **legacy):
     """Chunked paged prefill attention entry point (the prefill analogue of
     :func:`decode_attention_paged`).
 
@@ -477,13 +487,16 @@ def prefill_attention_paged(q, k_pool, v_pool, table, start, *, spec=None,
     resumes over its own, which is why prefix skipping needs no kernel
     changes.  v_pool None → MLA-fused (V = first `dv` pool columns).
     `spec.mode` is accepted for parity with decode but ignored; both modes
-    share the transposed loop here — prefill tiles are never thin on M."""
+    share the transposed loop here — prefill tiles are never thin on M.
+    sel: [B,Cq,max_blocks*page] bool top-k mask of DeepSeek Sparse
+    Attention (MLA-fused bf16 pools only), applied on top of the causal
+    mask: the work stays dense, the softmax sees the selected rows only."""
     spec = attn_spec.coerce(spec, legacy, where="prefill_attention_paged")
     if spec.use_kernels:
         from repro.kernels.etap import ops as etap_ops
         if v_pool is None:
             return etap_ops.etap_prefill_mla_paged(
-                q, k_pool, dv, table, start, spec=spec, kv_sz=k_sz)
+                q, k_pool, dv, table, start, spec=spec, kv_sz=k_sz, sel=sel)
         return etap_ops.etap_prefill_paged(
             q, k_pool, v_pool, table, start, spec=spec,
             k_sz=k_sz, v_sz=v_sz)
@@ -492,7 +505,8 @@ def prefill_attention_paged(q, k_pool, v_pool, table, start, *, spec=None,
         q = q.astype(jnp.float32)          # match the dequantized fp32 rows
     return etap_prefill_xla(q, k, v, start, scale=spec.scale,
                             block=k_pool.shape[1],
-                            rescale=softmax_state.resolve(spec.rescale))
+                            rescale=softmax_state.resolve(spec.rescale),
+                            sel=sel)
 
 
 def verify_attention_paged(q, k_pool, v_pool, table, start, qpos, *,
@@ -527,6 +541,135 @@ def verify_attention_paged(q, k_pool, v_pool, table, start, qpos, *,
     return etap_verify_xla(q, k, v, qpos, scale=spec.scale,
                            block=k_pool.shape[1],
                            rescale=softmax_state.resolve(spec.rescale))
+
+
+# ----------------------------------------- DeepSeek Sparse Attention
+def index_scores_xla(q, w, k_pool, table, limit):
+    """XLA twin of the index-score kernel (kernels/etap/dsa.py), also its
+    oracle.  q: [B,R,heads,hd]; w: [B,R,heads] fp32; k_pool: [N,page,hd];
+    table: [B,max_blocks]; limit: [B] rows scored.  Returns
+    I = sum_h w_h · ReLU(q_h · k) as [B,R,max_blocks*page] fp32, -inf at
+    rows >= limit."""
+    from repro.runtime.paged_cache import gather_blocks
+    k = gather_blocks(k_pool, table)                           # [B,L,hd]
+    s = jnp.einsum("brhd,bld->brhl", q.astype(k.dtype), k,
+                   preferred_element_type=jnp.float32)
+    s = jnp.einsum("brhl,brh->brl", jnp.maximum(s, 0.0),
+                   w.astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST)
+    pos = jnp.arange(s.shape[-1], dtype=jnp.int32)
+    return jnp.where(pos[None, None, :] < limit[:, None, None], s, -jnp.inf)
+
+
+def index_scores_paged(q, w, k_pool, table, limit, *, spec, decode: bool):
+    """Index scores through the kernel (named by ``decode``: the decode
+    step's or a prefill chunk's) or its XLA twin.  A row all of whose
+    ReLUs are zero scores zero: -0.0 is made +0.0, so that such ties
+    order by position alone."""
+    if spec.use_kernels:
+        from repro.kernels.etap import ops as etap_ops
+        fn = (etap_ops.dsa_index_decode_paged if decode
+              else etap_ops.dsa_index_prefill_paged)
+        s = fn(q, w, k_pool, table, limit, spec=spec)
+    else:
+        s = index_scores_xla(q, w, k_pool, table, limit)
+    return jnp.where(s == 0, 0.0, s)
+
+
+def _ordered_bits(x):
+    """fp32 -> uint32 whose unsigned order is the floats' order."""
+    b = jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.uint32)
+    return jnp.where(b >> 31 == 1, ~b, b | jnp.uint32(1 << 31))
+
+
+def _largest_below(pred, bits: int, shape):
+    """The largest unsigned t < 2**bits with ``pred(t)`` true, for a
+    predicate that holds from 0 up to some t and not above: one bit at a
+    time from the top."""
+    def step(i, t):
+        cand = t | jnp.left_shift(jnp.uint32(1),
+                                  jnp.uint32(bits - 1) - i.astype(jnp.uint32))
+        return jnp.where(pred(cand), cand, t)
+    return jax.lax.fori_loop(0, bits, step, jnp.zeros(shape, jnp.uint32))
+
+
+def top_k_mask(scores, k: int):
+    """Mask of exactly the k entries along the last axis that
+    ``jax.lax.top_k(scores, k)`` picks: the largest, ties to the lower
+    index.  No sort: the k-th largest value is found bit by bit (32
+    counting passes over the row), then, among the entries equal to it,
+    the last position to keep (a pass per bit of the row length)."""
+    u = _ordered_bits(scores)
+    rows = scores.shape[:-1]
+    kth = _largest_below(
+        lambda t: jnp.sum(u >= t[..., None], axis=-1) >= k, 32, rows)
+    above = u > kth[..., None]
+    tie = u == kth[..., None]
+    need = k - jnp.sum(above, axis=-1)                    # >= 1 tied entries
+    pos = jnp.arange(scores.shape[-1], dtype=jnp.uint32)
+    last = _largest_below(
+        lambda p: jnp.sum(tie & (pos < p[..., None]), axis=-1) < need,
+        max(1, int(scores.shape[-1]).bit_length()), rows)
+    return above | (tie & (pos <= last[..., None]))
+
+
+@functools.partial(jax.jit, static_argnames=("topk",))
+def dsa_select(scores, pool, table, lengths, *, topk: int):
+    """The selection of a sparse decode step: each sequence's top-k rows by
+    index score (exact, ``jax.lax.top_k``), gathered through the block
+    table.  scores: [B,L] (-inf past each length); pool: [N,page,latent];
+    lengths: [B] rows per sequence.  Returns (rows [B,k,latent], valid
+    [B], idx [B,k]) with k = min(topk, L): the first min(lengths, k) rows
+    are the selected ones, best first, and idx their positions."""
+    k = min(topk, scores.shape[-1])
+    _, idx = jax.lax.top_k(scores, k)                          # [B,k]
+    page = pool.shape[1]
+    blk = jnp.take_along_axis(table, idx // page, axis=1)
+    return (pool[blk, idx % page],
+            jnp.minimum(lengths, k).astype(jnp.int32), idx)
+
+
+def dsa_decode_paged(q, pool, k_pool, q_idx, w, table, lengths, *, spec,
+                     dv: int, topk: int):
+    """DeepSeek Sparse Attention decode (DESIGN.md §17): the lightning
+    indexer scores every row of each sequence against its paged index
+    keys, the top-k rows are gathered, and ETAP decode runs over them
+    alone — no pass over the whole latent pool.
+
+    q: [B,H,latent] absorbed queries; pool: [N,page,latent]; k_pool:
+    [N,page,hd] index keys; q_idx: [B,heads,hd]; w: [B,heads]; lengths:
+    [B] rows per sequence (the new token's included).  Returns [B,H,dv]."""
+    spec = attn_spec.coerce(spec, {}, where="dsa_decode_paged")
+    scores = index_scores_paged(q_idx[:, None], w[:, None], k_pool, table,
+                                lengths, spec=spec, decode=True)[:, 0]
+    rows, valid, idx = dsa_select(scores, pool, table, lengths, topk=topk)
+    if spec.use_kernels:
+        from repro.kernels.etap import ops as etap_ops
+        return etap_ops.dsa_sparse_decode(q, rows, dv, valid, spec=spec), idx
+    block = min(spec.block, rows.shape[1])
+    pad = (-rows.shape[1]) % block
+    if pad:
+        rows = jnp.pad(rows, ((0, 0), (0, pad), (0, 0)))
+    return etap_decode_xla(q, rows, rows[..., :dv], valid, scale=spec.scale,
+                           block=block,
+                           rescale=softmax_state.resolve(spec.rescale)), idx
+
+
+def dsa_prefill_select(q_idx, w, k_pool, table, start, *, spec, topk: int):
+    """Per-row top-k masks of a prefill chunk: q_idx [B,C,heads,hd], w
+    [B,C,heads] at positions start + c.  Row c keeps the min(topk, causal
+    rows) causal rows with the largest index scores, ties to the earlier
+    row, as the decode step's top-k picks them.
+    Returns [B,C,max_blocks*page] bool."""
+    spec = attn_spec.coerce(spec, {}, where="dsa_prefill_select")
+    C = q_idx.shape[1]
+    scores = index_scores_paged(q_idx, w, k_pool, table, start + C,
+                                spec=spec, decode=False)       # [B,C,L]
+    qpos = start[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]
+    causal = (jnp.arange(scores.shape[-1], dtype=jnp.int32)[None, None, :]
+              <= qpos[:, :, None])
+    scores = jnp.where(causal, scores, -jnp.inf)
+    return causal & top_k_mask(scores, min(topk, scores.shape[-1]))
 
 
 def gqa_partial_xla(q, k, v, length, *, scale: float, block: int = 512,

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from repro.core import attn_spec
 from repro.core.etap import (decode_attention, decode_attention_paged,
+                             dsa_decode_paged, dsa_prefill_select,
                              prefill_attention_paged, seq_sharded_decode,
                              verify_attention_paged)
 from repro.models import layers
@@ -25,7 +26,7 @@ def init_mla(key, cfg, dtype):
     m = cfg.mla
     D, H = cfg.d_model, cfg.num_heads
     ks = jax.random.split(key, 7)
-    return {
+    params = {
         "w_dq": layers.init_dense(ks[0], D, m.q_lora_rank, dtype),
         "q_norm": jnp.zeros((m.q_lora_rank,), dtype),
         "w_uq": layers.init_dense(ks[1], m.q_lora_rank, H * m.qk_head_dim, dtype),
@@ -36,16 +37,82 @@ def init_mla(key, cfg, dtype):
         "w_uv": layers.init_dense(ks[4], m.kv_lora_rank, H * m.v_head_dim, dtype),
         "w_o": layers.init_dense(ks[5], H * m.v_head_dim, D, dtype),
     }
+    if m.indexer is not None:
+        params["idx"] = init_indexer(jax.random.fold_in(key, 7), cfg, dtype)
+    return params
 
 
-def _queries(params, cfg, x, positions):
+def init_indexer(key, cfg, dtype):
+    """The lightning indexer's weights (DeepSeek Sparse Attention), drawn
+    from a key folded off the block's MLA key so that every other weight
+    draws as it does without an indexer."""
+    m, ix, D = cfg.mla, cfg.mla.indexer, cfg.d_model
+    ks = jax.random.split(key, 3)
+    return {
+        "w_q": layers.init_dense(ks[0], m.q_lora_rank,
+                                 ix.n_heads * ix.head_dim, dtype),
+        "w_k": layers.init_dense(ks[1], D, ix.head_dim, dtype),
+        "k_scale": jnp.ones((ix.head_dim,), dtype),
+        "k_bias": jnp.zeros((ix.head_dim,), dtype),
+        "w_w": layers.init_dense(ks[2], D, ix.n_heads, dtype),
+    }
+
+
+def _q_latent(params, cfg, x):
+    """x: [..., D] -> the normed q latent cq [..., q_lora_rank]."""
+    return layers.rms_norm(layers.dense(x, params["w_dq"]), params["q_norm"],
+                           cfg.norm_eps)
+
+
+def _queries(params, cfg, x, positions, cq=None):
     """x: [..., D] -> (q_nope [..., H, nope], q_rope [..., H, rope])."""
     m, H = cfg.mla, cfg.num_heads
-    cq = layers.rms_norm(layers.dense(x, params["w_dq"]), params["q_norm"], cfg.norm_eps)
+    if cq is None:
+        cq = _q_latent(params, cfg, x)
     q = layers.dense(cq, params["w_uq"]).reshape(*x.shape[:-1], H, m.qk_head_dim)
     q_nope, q_rope = q[..., : m.qk_nope_head_dim], q[..., m.qk_nope_head_dim:]
     q_rope = layers.apply_rope(q_rope, positions, cfg.rope_theta)
     return q_nope, q_rope
+
+
+def _rope_head(x, positions, cfg):
+    """RoPE on the first qk_rope_head_dim columns of x [..., S, (heads,) d]."""
+    r = cfg.mla.qk_rope_head_dim
+    return jnp.concatenate(
+        [layers.apply_rope(x[..., :r], positions, cfg.rope_theta), x[..., r:]],
+        axis=-1)
+
+
+def _dense_f32(x, w):
+    return jnp.einsum("...d,df->...f", x, w,
+                      preferred_element_type=jnp.float32)
+
+
+# The indexer's projections, LayerNorm and RoPE stay in fp32 and q and k
+# are rounded to the pool's dtype once, at the end: the scores decide a
+# top-k, and every extra rounding moves rows across its threshold.
+def index_keys(params, cfg, x, positions):
+    """The indexer's key rows: x [..., S, D] -> [..., S, head_dim] in x's
+    dtype, one key shared by every index head: LayerNorm(x W_k), the
+    first rope columns rotated."""
+    p = params["idx"]
+    k = layers.layer_norm(_dense_f32(x, p["w_k"]), p["k_scale"],
+                          p["k_bias"], cfg.norm_eps)
+    return _rope_head(k, positions, cfg).astype(x.dtype)
+
+
+def index_queries(params, cfg, x, cq, positions):
+    """The indexer's queries and head weights for rows x [..., S, D] with
+    q latent cq [..., S, q_lora]: q [..., S, n_heads, head_dim] in cq's
+    dtype (the first rope columns rotated) and fp32 weights
+    w [..., S, n_heads], scaled by n_heads^-1/2 · head_dim^-1/2.  A past
+    row s scores sum_h w[h] · ReLU(q[h] · k_s)."""
+    ix = cfg.mla.indexer
+    p = params["idx"]
+    q = _dense_f32(cq, p["w_q"]).reshape(*cq.shape[:-1], ix.n_heads,
+                                         ix.head_dim)
+    w = _dense_f32(x, p["w_w"]) * (ix.n_heads ** -0.5 * ix.head_dim ** -0.5)
+    return _rope_head(q, positions, cfg).astype(cq.dtype), w
 
 
 def _latent(params, cfg, x, positions):
@@ -77,12 +144,13 @@ def mla_train(params, cfg, x, positions, *, return_cache: bool = False):
     return out
 
 
-def _absorbed_query(params, cfg, x, positions):
+def _absorbed_query(params, cfg, x, positions, cq=None):
     """Absorbed-form decode query:
     q_c[b,h] = q_nope[b,h] · W_uk[:,h]  (512-d), q = [q_c ; q_rope] (576-d).
-    x: [B,D]; positions: [B,1]. Returns q: [B,H,latent]."""
+    x: [B,D]; positions: [B,1]; cq: the q latent [B,1,q_lora] where the
+    caller has it. Returns q: [B,H,latent]."""
     m, H = cfg.mla, cfg.num_heads
-    q_nope, q_rope = _queries(params, cfg, x[:, None, :], positions)
+    q_nope, q_rope = _queries(params, cfg, x[:, None, :], positions, cq)
     q_nope, q_rope = q_nope[:, 0], q_rope[:, 0]               # [B,H,*]
     # absorb W_uk into the query: [B,H,nope] x [kv,H,nope] -> [B,H,kv]
     w_uk = params["w_uk"].reshape(m.kv_lora_rank, H, m.qk_nope_head_dim)
@@ -146,11 +214,16 @@ def mla_decode_paged(params, cfg, x, cache, table, lengths, *,
     lengths, so there is no shared scalar `pos`).  The single 576-wide
     latent pool is streamed once through the block table; V is its first
     kv_lora_rank columns (same one-stream argument as the dense MLA path).
-    Returns (out [B,D], {"c": updated pool})."""
+    Returns (out [B,D], {"c": updated pool}, sel): sel the positions
+    DeepSeek Sparse Attention selected, [B,k] (the first min(lengths + 1,
+    k) of each row valid), None without an indexer."""
     spec = attn_spec.coerce(spec, legacy, where="mla_decode_paged")
     m = cfg.mla
     B, D = x.shape
     positions = lengths[:, None].astype(jnp.int32)            # [B,1]
+    if m.indexer is not None:
+        return _mla_decode_dsa(params, cfg, x, cache, table, lengths,
+                               positions, spec)
     q = _absorbed_query(params, cfg, x, positions)
     c_t = _latent(params, cfg, x[:, None, :], positions)[:, 0]  # [B,latent]
     inner = spec.replace(scale=m.qk_head_dim ** -0.5,
@@ -162,12 +235,34 @@ def mla_decode_paged(params, cfg, x, cache, table, lengths, *,
             q, pool, None, table, lengths + 1, spec=inner,
             dv=m.kv_lora_rank, k_sz=sz)
         return (_absorbed_out(params, cfg, o_lat, x.dtype),
-                {"c": pool, "c_sz": sz})
+                {"c": pool, "c_sz": sz}, None)
     pool = paged_cache.append_rows(cache["c"], table, lengths, c_t)
     o_lat = decode_attention_paged(
         q, pool, None, table, lengths + 1, spec=inner,
         dv=m.kv_lora_rank)                                    # [B,H,512]
-    return _absorbed_out(params, cfg, o_lat, x.dtype), {"c": pool}
+    return _absorbed_out(params, cfg, o_lat, x.dtype), {"c": pool}, None
+
+
+def _mla_decode_dsa(params, cfg, x, cache, table, lengths, positions, spec):
+    """DeepSeek Sparse Attention decode (DESIGN.md §17): append the new
+    latent row and index key, score every row of each sequence with the
+    lightning indexer, and run absorbed MLA over the top-k rows only."""
+    m = cfg.mla
+    cq = _q_latent(params, cfg, x[:, None, :])                # [B,1,q_lora]
+    q = _absorbed_query(params, cfg, x, positions, cq)
+    xs = x[:, None, :]
+    c_t = _latent(params, cfg, xs, positions)[:, 0]           # [B,latent]
+    k_t = index_keys(params, cfg, xs, positions)[:, 0]        # [B,idx_dim]
+    q_idx, w = index_queries(params, cfg, xs, cq, positions)
+    pool = paged_cache.append_rows(cache["c"], table, lengths, c_t)
+    kpool = paged_cache.append_rows(cache["k_idx"], table, lengths, k_t)
+    o_lat, sel = dsa_decode_paged(
+        q, pool, kpool, q_idx[:, 0], w[:, 0], table, lengths + 1,
+        spec=spec.replace(scale=m.qk_head_dim ** -0.5,
+                          use_kernels=cfg.use_kernels),
+        dv=m.kv_lora_rank, topk=m.indexer.topk)               # [B,H,512]
+    return (_absorbed_out(params, cfg, o_lat, x.dtype),
+            {"c": pool, "k_idx": kpool}, sel)
 
 
 def _mla_chunk(params, cfg, x, cache, table, lengths, positions, *, spec,
@@ -177,10 +272,16 @@ def _mla_chunk(params, cfg, x, cache, table, lengths, positions, *, spec,
     over pool positions <= each query row's own horizon.  ``positions``
     [B,C] drives rope AND (via qpos) the causal mask; qpos None → the
     prefill entry (horizon = start + row index, implied by the kernel),
-    else the explicit per-row horizon of the verify entry."""
+    else the explicit per-row horizon of the verify entry.  Under DeepSeek
+    Sparse Attention (DESIGN.md §17) the chunk's index keys are appended
+    too, and each row attends only its top-k causal rows: a mask on the
+    paged prefill kernel, exact and dense in FLOPs.  Returns (out, new
+    cache, sel): sel the chunk's last row's mask [B, max_blocks*page]
+    under DeepSeek Sparse Attention, else None."""
     m, H = cfg.mla, cfg.num_heads
     B, C, D = x.shape
-    q_nope, q_rope = _queries(params, cfg, x, positions)      # [B,C,H,*]
+    cq = _q_latent(params, cfg, x)                            # [B,C,q_lora]
+    q_nope, q_rope = _queries(params, cfg, x, positions, cq)  # [B,C,H,*]
     # absorb W_uk into the chunk queries: [B,C,H,nope] x [kv,H,nope]
     w_uk = params["w_uk"].reshape(m.kv_lora_rank, H, m.qk_nope_head_dim)
     q_c = jnp.einsum("bchd,khd->bchk", q_nope.astype(jnp.float32),
@@ -198,6 +299,19 @@ def _mla_chunk(params, cfg, x, cache, table, lengths, positions, *, spec,
         pool = paged_cache.append_chunk(cache["c"], table, lengths, c_rows)
         kw = dict(spec=inner, dv=m.kv_lora_rank)
         new_cache = {"c": pool}
+    sel = None
+    if m.indexer is not None:
+        if qpos is not None:
+            raise ValueError("DeepSeek Sparse Attention has no draft "
+                             "verification")
+        kpool = paged_cache.append_chunk(
+            cache["k_idx"], table, lengths,
+            index_keys(params, cfg, x, positions))
+        q_idx, w = index_queries(params, cfg, x, cq, positions)
+        kw["sel"] = sel = dsa_prefill_select(
+            q_idx, w, kpool, table, lengths, spec=inner,
+            topk=m.indexer.topk)
+        new_cache["k_idx"] = kpool
     if qpos is None:
         o_lat = prefill_attention_paged(q, pool, None, table, lengths, **kw)
     else:
@@ -207,7 +321,7 @@ def _mla_chunk(params, cfg, x, cache, table, lengths, positions, *, spec,
     o = jnp.einsum("bchk,khd->bchd", o_lat.astype(jnp.float32),
                    w_uv.astype(jnp.float32)).astype(x.dtype)
     out = layers.dense(o.reshape(B, C, H * m.v_head_dim), params["w_o"])
-    return out, new_cache
+    return out, new_cache, (None if sel is None else sel[:, -1])
 
 
 def mla_prefill_chunk(params, cfg, x, cache, table, lengths, *,
@@ -224,7 +338,8 @@ def mla_prefill_chunk(params, cfg, x, cache, table, lengths, *,
     naive prefill: q·k = [q_nope·W_uk ; q_rope]·[c_kv ; k_rope] and
     o = P·(W_uv c_kv) = (P·c_kv)·W_uv, so scores and outputs agree with
     mla_train to float noise while streaming the 576-wide latent once.
-    Returns (out [B,C,D], {"c": updated pool})."""
+    Returns (out [B,C,D], {"c": updated pool}, sel): sel the last chunk
+    row's DeepSeek Sparse Attention mask, None without an indexer."""
     spec = attn_spec.coerce(spec, legacy, where="mla_prefill_chunk")
     C = x.shape[1]
     positions = lengths[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]
@@ -246,8 +361,9 @@ def mla_verify_chunk(params, cfg, x, cache, table, lengths, qpos, *,
     never a pool rewrite.  Returns (out [B,k,D], updated cache)."""
     spec = attn_spec.coerce(spec, legacy, where="mla_verify_chunk")
     qpos = qpos.astype(jnp.int32)
-    return _mla_chunk(params, cfg, x, cache, table, lengths, qpos,
-                      spec=spec, qpos=qpos)
+    out, cache, _ = _mla_chunk(params, cfg, x, cache, table, lengths, qpos,
+                               spec=spec, qpos=qpos)
+    return out, cache
 
 
 def init_mla_cache(cfg, batch: int, max_len: int, dtype):
@@ -262,6 +378,15 @@ def init_mla_cache_paged(cfg, layout, dtype, kv_dtype: str = "fp"):
     exactly."""
     shape = (layout.num_blocks, layout.block_size, cfg.mla.latent_dim)
     qdt = paged_cache.quant_dtype(kv_dtype)
+    ix = cfg.mla.indexer
+    if ix is not None:
+        if qdt is not None:
+            raise ValueError("DeepSeek Sparse Attention keeps its latent and "
+                             f"index pools in {jnp.dtype(dtype).name}; "
+                             f"kv_dtype {kv_dtype!r} is not supported")
+        # the indexer's keys: a second paged leaf under the same table
+        return {"c": jnp.zeros(shape, dtype),
+                "k_idx": jnp.zeros(shape[:2] + (ix.head_dim,), dtype)}
     if qdt is None:
         return {"c": jnp.zeros(shape, dtype)}
     sz0 = jnp.concatenate(

@@ -271,7 +271,7 @@ def _etap_prefill_body(start_ref, table_ref, q_ref, k_ref, v_ref, o_ref,
                        acc_ref, m_ref, l_ref, *, scale: float, page: int,
                        nb: int, heads: int, cols: int, fused_dv: int,
                        rescale: str, k_sz_ref=None, v_sz_ref=None,
-                       qpos_ref=None):
+                       qpos_ref=None, sel_ref=None):
     """Chunked paged ETAP prefill (DESIGN.md §9): the decode body with the
     single query row widened to a [Cq, H] tile, flattened to CH = Cq*H
     online-softmax columns.  The KV walk streams the sequence's pool blocks
@@ -291,7 +291,12 @@ def _etap_prefill_body(start_ref, table_ref, q_ref, k_ref, v_ref, o_ref,
     per-column absolute query position [1, cols] replaces the derived
     ``start + c // H`` — the draft-verification mask where each scored
     chunk row attends to exactly the pool rows at or before its own
-    position, independent of how the chunk maps onto the pool tail."""
+    position, independent of how the chunk maps onto the pool tail.
+
+    ``sel_ref`` (DeepSeek Sparse Attention, DESIGN.md §17): the tile's
+    query rows' top-k masks over this KV block, [cols // heads, page]; a
+    0/1 matmul against the row-of-column indicator spreads each row's mask
+    over its ``heads`` columns."""
     j = pl.program_id(2)
 
     @pl.when(j == 0)
@@ -315,7 +320,17 @@ def _etap_prefill_body(start_ref, table_ref, q_ref, k_ref, v_ref, o_ref,
         qpos = start + col // heads
     else:
         qpos = qpos_ref[0]                             # [1, cols] per-column
-    sT = jnp.where(kpos <= qpos, sT, NEG_INF)          # causal chunk-vs-pool
+    live = kpos <= qpos                                # causal chunk-vs-pool
+    if sel_ref is not None:
+        shape = (cols // heads, cols)
+        spread = (jax.lax.broadcasted_iota(jnp.int32, shape, 1) // heads
+                  == jax.lax.broadcasted_iota(jnp.int32, shape, 0))
+        picked = jax.lax.dot_general(
+            sel_ref[0, 0], spread.astype(jnp.float32),
+            (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)        # [page, cols]
+        live = live & (picked > 0.5)
+    sT = jnp.where(live, sT, NEG_INF)
 
     v_blk = k_blk[:, :fused_dv] if fused_dv else _dequant(v_ref[0], v_sz_ref)
     m_ref[...], l_ref[...], acc_ref[...] = softmax_state.update(
@@ -335,6 +350,12 @@ def _prefill_body_fused(start_ref, table_ref, q_ref, k_ref, o_ref,
                         acc, m, l, **kw):
     _etap_prefill_body(start_ref, table_ref, q_ref, k_ref, None, o_ref,
                        acc, m, l, **kw)
+
+
+def _prefill_body_sel(start_ref, table_ref, q_ref, sel_ref, k_ref, o_ref,
+                      acc, m, l, **kw):
+    _etap_prefill_body(start_ref, table_ref, q_ref, k_ref, None, o_ref,
+                       acc, m, l, sel_ref=sel_ref, **kw)
 
 
 def _prefill_body_quant(start_ref, table_ref, q_ref, k_ref, k_sz_ref,
@@ -391,8 +412,20 @@ def _col_tiling(CH: int):
     return PREFILL_COL_TILE, -(-CH // PREFILL_COL_TILE) * PREFILL_COL_TILE
 
 
+def _sel_operand(sel, rows: int, page: int):
+    """[B, Cq, nb*page] bool top-k mask -> the kernel's [B, nb, rows, page]
+    fp32 planes (rows >= Cq, padded with live rows): one (rows-of-the-tile,
+    page) block per grid step."""
+    B, Cq, S = sel.shape
+    m = sel.astype(jnp.float32)
+    if rows != Cq:
+        m = jnp.pad(m, ((0, 0), (0, rows - Cq), (0, 0)), constant_values=1.0)
+    return m.reshape(B, rows, S // page, page).transpose(0, 2, 1, 3)
+
+
 def _prefill_call(q, pool, v_pool, table, start, *, heads, scale, interpret,
-                  fused_dv, rescale, k_sz=None, v_sz=None, qpos=None):
+                  fused_dv, rescale, k_sz=None, v_sz=None, qpos=None,
+                  sel=None):
     B, CH, Dk = q.shape
     page = pool.shape[1]
     nb = table.shape[1]
@@ -422,6 +455,12 @@ def _prefill_call(q, pool, v_pool, table, start, *, heads, scale, interpret,
         in_specs.append(pl.BlockSpec((1, 1, cols),
                                      lambda b, t, j, *_: (b, 0, t)))
         operands.append(qpos.astype(jnp.int32)[:, None, :])
+    if sel is not None:
+        # DeepSeek Sparse Attention: fused bf16 pools, no explicit qpos
+        assert fused_dv and not quant and qpos is None and cols % heads == 0
+        in_specs.append(pl.BlockSpec((1, 1, cols // heads, page),
+                                     lambda b, t, j, *_: (b, j, t, 0)))
+        operands.append(_sel_operand(sel, CHp // heads, page))
     in_specs.append(pool_spec(Dk))                                       # pool
     operands.append(pool)
     if quant:
@@ -436,7 +475,9 @@ def _prefill_call(q, pool, v_pool, table, start, *, heads, scale, interpret,
 
     kw = dict(scale=scale, page=page, nb=nb, heads=heads, cols=cols,
               fused_dv=fused_dv, rescale=softmax_state.resolve(rescale))
-    if qpos is not None:
+    if sel is not None:
+        body = functools.partial(_prefill_body_sel, **kw)
+    elif qpos is not None:
         body = functools.partial(
             (_verify_body_quant_fused if fused_dv else _verify_body_quant)
             if quant else
@@ -491,12 +532,15 @@ def etap_prefill_paged_pallas(q, k_pool, v_pool, table, start, *,
 
 def etap_prefill_mla_paged_pallas(q, kv_pool, dv: int, table, start, *,
                                   scale: float, interpret: bool = True,
-                                  kv_sz=None, rescale: str | None = None):
-    """Paged MLA-fused chunked prefill: single latent pool, V = pool[..., :dv]."""
+                                  kv_sz=None, rescale: str | None = None,
+                                  sel=None):
+    """Paged MLA-fused chunked prefill: single latent pool, V = pool[..., :dv].
+    sel: [B,Cq,max_blocks*page] bool top-k mask (DeepSeek Sparse Attention)
+    applied on top of the causal one."""
     B, Cq, H, Dk = q.shape
     o = _prefill_call(q.reshape(B, Cq * H, Dk), kv_pool, None, table, start,
                       heads=H, scale=scale, interpret=interpret, fused_dv=dv,
-                      rescale=rescale, k_sz=kv_sz)
+                      rescale=rescale, k_sz=kv_sz, sel=sel)
     return o.reshape(B, Cq, H, dv)
 
 

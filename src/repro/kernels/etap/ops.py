@@ -16,6 +16,7 @@ import jax.numpy as jnp
 
 from repro.core import attn_spec
 from repro.kernels.etap.combine import combine_splits
+from repro.kernels.etap.dsa import dsa_index_pallas
 from repro.kernels.etap.etap import (etap_decode_mla_paged_pallas,
                                      etap_decode_mla_pallas,
                                      etap_decode_paged_pallas,
@@ -190,12 +191,46 @@ def etap_prefill_paged(q, k_pool, v_pool, table, start, *, spec,
 
 @attn_spec.attn_entry(uses=("interpret", "rescale"), static_argnames=("dv",))
 def etap_prefill_mla_paged(q, kv_pool, dv: int, table, start, *, spec,
-                           kv_sz=None):
-    """Chunked paged MLA-fused ETAP prefill: one latent pool, V = pool[..., :dv]."""
+                           kv_sz=None, sel=None):
+    """Chunked paged MLA-fused ETAP prefill: one latent pool, V = pool[..., :dv].
+    sel: [B,Cq,max_blocks*page] bool, the rows each query row may attend
+    besides the causal mask (DeepSeek Sparse Attention's top-k)."""
     return etap_prefill_mla_paged_pallas(q, kv_pool, dv, table, start,
                                          scale=spec.scale,
                                          interpret=spec.interpret,
-                                         kv_sz=kv_sz, rescale=spec.rescale)
+                                         kv_sz=kv_sz, rescale=spec.rescale,
+                                         sel=sel)
+
+
+# ------------------------------------------- DeepSeek Sparse Attention
+# Named entries, so the device trace tells the decode step's index kernel
+# from the chunk's and the sparse decode from the dense one.
+@attn_spec.attn_entry(uses=("interpret",))
+def dsa_index_decode_paged(q, w, pool, table, limit, *, spec):
+    """Index scores of one query row per sequence: q [B,1,heads,hd],
+    w [B,1,heads] -> [B,1,max_blocks*page] fp32 (kernels/etap/dsa.py)."""
+    return dsa_index_pallas(q, w, pool, table, limit,
+                            interpret=spec.interpret)
+
+
+@attn_spec.attn_entry(uses=("interpret",))
+def dsa_index_prefill_paged(q, w, pool, table, limit, *, spec):
+    """Index scores of a prefill chunk's rows: q [B,C,heads,hd],
+    w [B,C,heads] -> [B,C,max_blocks*page] fp32."""
+    return dsa_index_pallas(q, w, pool, table, limit,
+                            interpret=spec.interpret)
+
+
+@attn_spec.attn_entry(uses=("block", "interpret", "rescale"),
+                      static_argnames=("dv",))
+def dsa_sparse_decode(q, rows, dv: int, length, *, spec):
+    """ETAP decode over each sequence's selected latent rows [B,k,latent]
+    (the first ``length`` of them valid); V = rows[..., :dv]."""
+    block = min(spec.block, rows.shape[1])
+    rows = _pad_seq(rows, block)
+    return etap_decode_mla_pallas(q, rows, dv, length, scale=spec.scale,
+                                  block=block, interpret=spec.interpret,
+                                  rescale=spec.rescale)
 
 
 @attn_spec.attn_entry(uses=("interpret", "rescale"))

@@ -184,6 +184,19 @@ def _make_requests(args, vocab: int):
     return reqs
 
 
+def _dsa_rows(start: int, count: int, topk: int) -> tuple:
+    """(rows scored, rows selected) of ``count`` query rows at positions
+    start .. start + count - 1 under DeepSeek Sparse Attention: row i
+    scores its start + i + 1 causal rows and keeps min(topk, that)."""
+    scored = count * start + count * (count + 1) // 2
+    lo, hi = start + 1, start + count            # causal rows of the rows
+    full = max(0, hi - max(lo, topk + 1) + 1)    # rows that keep topk
+    part = min(hi, topk) - lo + 1 if lo <= topk else 0
+    selected = full * topk + (part * (lo + min(hi, topk)) // 2
+                              if part > 0 else 0)
+    return scored, selected
+
+
 def paged_programs(cfg, layout, spec):
     """Every jitted program that takes or returns the paged pool.
 
@@ -193,17 +206,22 @@ def paged_programs(cfg, layout, spec):
     came in, so it is updated in place from one program to the next.  A
     new program that rebinds the pool must do the same (DESIGN.md §8).
     Named functions, so the compiled modules and the device trace say
-    which program ran."""
+    which program ran.  Under DeepSeek Sparse Attention ``step`` and
+    ``prefill`` also return the rows each layer selected (the decode
+    step's positions, the chunk's last row's mask)."""
+    dsa = cfg.mla is not None and cfg.mla.indexer is not None
+
     def serve_init_pool():
         return model.init_paged_cache(cfg, layout, kv_dtype=spec.kv_dtype)
 
     def serve_decode_step(p, c, t, table, lengths):
         return model.decode_step(p, cfg, c, t, None, spec=spec,
                                  cache_layout="paged", block_table=table,
-                                 lengths=lengths)
+                                 lengths=lengths, with_selection=dsa)
 
     def serve_prefill_chunk(p, c, t, table, lengths):
-        return model.prefill_chunk(p, cfg, c, t, table, lengths, spec=spec)
+        return model.prefill_chunk(p, cfg, c, t, table, lengths, spec=spec,
+                                   with_selection=dsa)
 
     def serve_verify_step(p, c, t, table, lengths):
         return model.verify_step(p, cfg, c, t, table, lengths, spec=spec)
@@ -266,8 +284,20 @@ def run_paged(args, cfg, *, record_logits: bool = False) -> dict:
     the quantized prompt buckets of :func:`_make_requests`.
 
     ``record_logits=True`` fills the returned ``seed_logits`` and
-    ``first_decode_logits`` ({request id: fp32 logits row}); otherwise
-    both stay empty and no logits leave the device."""
+    ``first_decode_logits`` ({request id: fp32 logits row}) and, under
+    DeepSeek Sparse Attention, ``dsa_selected``: each request's prompt and
+    the positions every layer selected for its last prefill row and each
+    decode row; otherwise all stay empty and nothing more leaves the
+    device."""
+    indexer = cfg.mla.indexer if cfg.mla is not None else None
+    if indexer is not None and (args.kv_dtype != "fp"
+                                or args.spec_tokens > 0):
+        # the quantized pools and the verify pass carry no index keys
+        raise ValueError(
+            "DeepSeek Sparse Attention (an indexer config) serves bf16 pools "
+            "without speculation: --kv-dtype must be fp and --spec-tokens 0 "
+            f"(got --kv-dtype {args.kv_dtype}, --spec-tokens "
+            f"{args.spec_tokens})")
     # one fresh registry per run — back-to-back runs in one process
     # (tests, benchmarks) must never mix counters.  Every subsystem below
     # (pool, scheduler, heartbeats, injector, drafter) writes into it.
@@ -358,7 +388,7 @@ def run_paged(args, cfg, *, record_logits: bool = False) -> dict:
     table0, lengths0 = bp.device_views()
     logits0, holder["cache"] = step_fn(params, holder["cache"],
                                        jnp.zeros((B,), jnp.int32),
-                                       table0, lengths0)
+                                       table0, lengths0)[:2]
     jax.block_until_ready(logits0)
 
     # speculative decode (DESIGN.md §14): a host-side drafter proposes
@@ -392,6 +422,12 @@ def run_paged(args, cfg, *, record_logits: bool = False) -> dict:
     c_replay = reg.counter("serve/replayed_tokens")
     c_restarts = reg.counter("serve/worker_restarts")
     c_ticks = reg.counter("serve/ticks")
+    # DeepSeek Sparse Attention: rows the indexer scored and rows attention
+    # kept, per query row summed over the run (each layer does the same)
+    c_scored = c_selected = None
+    if indexer is not None:
+        c_scored = reg.counter("dsa/rows_scored")
+        c_selected = reg.counter("dsa/rows_selected")
     g_queued = reg.gauge("sched/queued")
     g_running = reg.gauge("sched/running")
     t_prefill = 0.0
@@ -399,6 +435,17 @@ def run_paged(args, cfg, *, record_logits: bool = False) -> dict:
     # step's logits, both computed from inputs two attention paths share —
     # what a comparison of the paths holds to its bound (chip_smoke.py)
     seed_logits, first_logits = {}, {}
+    # record_logits under DeepSeek Sparse Attention: the positions every
+    # layer selected for each request's last prefill row and every decode
+    # row ({request id: {"prompt", "rows": {position: [n_layers, n]}}}),
+    # what a reference needs to tell a selection fault from a near-tie
+    dsa_selected = {}
+    sel_dtype = (np.uint16 if bp.table.shape[1] * layout.block_size <= 2 ** 16
+                 else np.int32)
+
+    def keep_selection(r, pos, rows):
+        dsa_selected.setdefault(r.id, {"rows": {}})["rows"][pos] = \
+            rows.astype(sel_dtype)
 
     # spans (DESIGN.md §15): one serve.tick per iteration; inside it each
     # device launch is a *.launch span (host prep + dispatch) and a *.wait
@@ -472,8 +519,9 @@ def run_paged(args, cfg, *, record_logits: bool = False) -> dict:
                         trow = jnp.array(bp.table[b:b + 1])
                         lrow = jnp.array(bp.lengths[b:b + 1])
                         sched.start_prefill(r, time.perf_counter())
-                        logits, holder["cache"] = prefill_fn(
-                            params, holder["cache"], toks_c, trow, lrow)
+                        out = prefill_fn(params, holder["cache"], toks_c,
+                                         trow, lrow)
+                        logits, holder["cache"] = out[:2]
                     with tracer.span("serve.prefill.wait", req=r.id):
                         jax.block_until_ready(logits)
                         t_prefill += time.perf_counter() - tp
@@ -483,6 +531,15 @@ def run_paged(args, cfg, *, record_logits: bool = False) -> dict:
                                 seed_logits.setdefault(
                                     r.id,
                                     np.asarray(logits[0, -1], np.float32))
+                            if record_logits and indexer is not None:
+                                mask = np.asarray(out[2])[:, 0]
+                                keep_selection(r, plen - 1, np.stack(
+                                    [np.flatnonzero(m) for m in mask]))
+                    if c_scored is not None:
+                        sc, se = _dsa_rows(int(bp.lengths[b]), c,
+                                           indexer.topk)
+                        c_scored.inc(sc)
+                        c_selected.inc(se)
                     bp.extend(b, c)
                     r.pf_pos += c
                     spent += c
@@ -541,10 +598,11 @@ def run_paged(args, cfg, *, record_logits: bool = False) -> dict:
                             for r in plain:
                                 cur_arr[r.slot] = (r.replay[0] if r.replay
                                                    else r.cur)
-                            logits, holder["cache"] = step_fn(
+                            out = step_fn(
                                 params, holder["cache"],
                                 jnp.array(cur_arr, jnp.int32),
                                 jnp.array(table_m), jnp.array(lens_m))
+                            logits, holder["cache"] = out[:2]
                         with tracer.span("serve.decode.wait"):
                             nxt = np.asarray(jnp.argmax(logits, axis=-1))
                             new = ([r for r in plain
@@ -554,9 +612,21 @@ def run_paged(args, cfg, *, record_logits: bool = False) -> dict:
                                 rows = np.asarray(logits, np.float32)
                                 first_logits.update({r.id: rows[r.slot]
                                                      for r in new})
+                            if record_logits and indexer is not None:
+                                idx = np.asarray(out[2])     # [L, B, k]
+                                for r in plain:
+                                    pos = int(lens_m[r.slot])
+                                    n = min(indexer.topk, pos + 1)
+                                    keep_selection(r, pos, idx[:, r.slot, :n])
                         c_steps.inc()
                         if pf_tokens:
                             c_inter.inc()
+                        if c_scored is not None:
+                            for r in plain:
+                                sc, se = _dsa_rows(int(bp.lengths[r.slot]), 1,
+                                                   indexer.topk)
+                                c_scored.inc(sc)
+                                c_selected.inc(se)
 
                         # ---- retire / bookkeep (host side)
                         with tracer.span("serve.decode.retire"):
@@ -668,6 +738,8 @@ def run_paged(args, cfg, *, record_logits: bool = False) -> dict:
     t_decode = t_total - t_prefill
 
     outputs = {rid: r.out for rid, r in sorted(sched.done.items())}
+    for rid, rec in dsa_selected.items():
+        rec["prompt"] = np.asarray(sched.done[rid].prompt, np.int32)
     refused_ids = sched.refused_ids
     prefill_tokens_saved = sched.prefill_tokens_saved
     sstats = sched.stats()
@@ -721,6 +793,7 @@ def run_paged(args, cfg, *, record_logits: bool = False) -> dict:
             "metrics": snap,
             "seed_logits": seed_logits,
             "first_decode_logits": first_logits,
+            "dsa_selected": dsa_selected,
             "t_prefill": t_prefill, "t_decode": t_decode}
 
 

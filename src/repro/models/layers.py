@@ -13,6 +13,16 @@ def rms_norm(x, scale, eps: float = 1e-6):
     return ((x * jax.lax.rsqrt(var + eps)) * (1.0 + scale.astype(jnp.float32))).astype(dt)
 
 
+def layer_norm(x, scale, bias, eps: float = 1e-6):
+    """LayerNorm with a scale and a bias, computed in fp32."""
+    dt = x.dtype
+    x = x.astype(jnp.float32)
+    mu = jnp.mean(x, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(x - mu), axis=-1, keepdims=True)
+    y = (x - mu) * jax.lax.rsqrt(var + eps)
+    return (y * scale.astype(jnp.float32) + bias.astype(jnp.float32)).astype(dt)
+
+
 def init_dense(key, d_in: int, d_out: int, dtype) -> jax.Array:
     std = d_in ** -0.5
     return (jax.random.normal(key, (d_in, d_out), jnp.float32) * std).astype(dtype)

@@ -119,17 +119,23 @@ def _block_seq(params, cfg, sig, x, positions, collect_cache: bool):
 
 def _block_decode(params, cfg, sig, x, cache, pos, spec,
                   cache_layout="dense", block_table=None, lengths=None):
-    """One block, one token. x: [B,D]. Returns (x, new_cache).
+    """One block, one token. x: [B,D]. Returns (x, new_cache, sel).
     cache_layout "paged": the attention cache is a block pool; `pos` is
-    replaced by per-sequence `lengths` + the shared `block_table`."""
+    replaced by per-sequence `lengths` + the shared `block_table`.  sel:
+    the rows DeepSeek Sparse Attention selected (mla_decode_paged), else
+    None."""
     kind, is_moe = sig
     h = layers.rms_norm(x, params["norm1"], cfg.norm_eps)
+    sel = None
     if kind == "attn":
-        if cache_layout == "paged":
-            fn = (mla_mod.mla_decode_paged if cfg.attention_kind == "mla"
-                  else attention.attention_decode_paged)
-            mixed, cache = fn(params["mix"], cfg, h, cache, block_table,
-                              lengths, spec=spec)
+        if cache_layout == "paged" and cfg.attention_kind == "mla":
+            mixed, cache, sel = mla_mod.mla_decode_paged(
+                params["mix"], cfg, h, cache, block_table, lengths,
+                spec=spec)
+        elif cache_layout == "paged":
+            mixed, cache = attention.attention_decode_paged(
+                params["mix"], cfg, h, cache, block_table, lengths,
+                spec=spec)
         elif cfg.attention_kind == "mla":
             mixed, cache = mla_mod.mla_decode(params["mix"], cfg, h, cache,
                                               pos, spec=spec)
@@ -142,7 +148,7 @@ def _block_decode(params, cfg, sig, x, cache, pos, spec,
         mixed, cache = mamba.mamba_decode(params["mix"], cfg, h, cache)
     x = x + mixed
     if kind == "ssm":
-        return x, cache
+        return x, cache, sel
     h2 = layers.rms_norm(x, params["norm2"], cfg.norm_eps)
     if is_moe:
         # serving: one group of B tokens, dropless routing
@@ -150,7 +156,7 @@ def _block_decode(params, cfg, sig, x, cache, pos, spec,
         f = f[0]
     else:
         f = layers.mlp(params["ffn"], h2)
-    return x + f, cache
+    return x + f, cache, sel
 
 
 def _init_block_cache(cfg, sig, batch: int, max_len: int, dtype):
@@ -176,14 +182,19 @@ def init(rng, cfg):
     fe = frontend.init_frontend(keys[1], cfg, dtype)
     if fe is not None:
         params["frontend"] = fe
+    # each layer is drawn on its own and only then stacked, so no stacked
+    # float32 draw of a whole group is ever held: the transient float32 is
+    # one layer's largest matrix.  A layer's key is the one the vmapped
+    # draw used, so the weights are bitwise the same.
     gp = []
     for g, key in zip(groups, keys[2:], strict=True):
-        gkeys = jax.random.split(key, g["n"])
-        def one(k, g=g):
+        stack = []
+        for k in jax.random.split(key, g["n"]):
             ks = jax.random.split(k, len(g["sigs"]))
-            return {f"b{j}": _init_block(ks[j], cfg, s, dtype)
-                    for j, s in enumerate(g["sigs"])}
-        gp.append(jax.vmap(one)(gkeys))
+            stack.append({f"b{j}": _init_block(ks[j], cfg, s, dtype)
+                          for j, s in enumerate(g["sigs"])})
+        gp.append(jax.tree.map(lambda *a: jnp.stack(a), *stack))
+        del stack
     params["groups"] = gp
     return params
 
@@ -310,14 +321,18 @@ def paged_row_bytes(cfg, kv_dtype: str = "fp") -> int:
     """KV-cache bytes ONE token costs across the whole layer stack in a
     paged cache of the given layout — the quantity the serve loop's
     byte-budget capacity accounting divides by (DESIGN.md §11).  MLA: one
-    latent_dim row per layer; GQA: K heads × head_dim for K and V each
-    (each head is its own quantization granule, so each carries its own
-    (scale, zp) overhead)."""
+    latent_dim row per layer, plus the indexer's key row under DeepSeek
+    Sparse Attention; GQA: K heads × head_dim for K and V each (each head
+    is its own quantization granule, so each carries its own (scale, zp)
+    overhead)."""
     from repro.runtime.paged_cache import row_bytes
     n_layers = len(cfg.layer_kinds())
     if cfg.attention_kind == "mla":
-        return n_layers * row_bytes(cfg.mla.latent_dim, kv_dtype,
-                                    cfg.jax_dtype)
+        per_layer = row_bytes(cfg.mla.latent_dim, kv_dtype, cfg.jax_dtype)
+        if cfg.mla.indexer is not None:
+            per_layer += row_bytes(cfg.mla.indexer.head_dim, kv_dtype,
+                                   cfg.jax_dtype)
+        return n_layers * per_layer
     Kv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
     per_stream = row_bytes(Kv * hd, kv_dtype, cfg.jax_dtype, granules=Kv)
     return n_layers * 2 * per_stream                      # K and V pools
@@ -368,15 +383,19 @@ def _block_prefill_chunk(params, cfg, sig, x, cache, table, lengths, spec,
     x: [B,C,D].  Paged caches are attention-only (init_paged_cache), so
     the recurrent/SSM kinds never reach here.  qpos [B,C] switches the
     attention layer to its draft-verification twin (explicit per-row
-    causal horizon — DESIGN.md §14); everything else is identical."""
+    causal horizon — DESIGN.md §14); everything else is identical.
+    Returns (x, new_cache, sel): sel the last row's DeepSeek Sparse
+    Attention mask (mla_prefill_chunk), else None."""
     kind, is_moe = sig
     assert kind == "attn", kind
     h = layers.rms_norm(x, params["norm1"], cfg.norm_eps)
-    if qpos is None:
-        fn = (mla_mod.mla_prefill_chunk if cfg.attention_kind == "mla"
-              else attention.attention_prefill_chunk)
-        mixed, cache = fn(params["mix"], cfg, h, cache, table, lengths,
-                          spec=spec)
+    sel = None
+    if qpos is None and cfg.attention_kind == "mla":
+        mixed, cache, sel = mla_mod.mla_prefill_chunk(
+            params["mix"], cfg, h, cache, table, lengths, spec=spec)
+    elif qpos is None:
+        mixed, cache = attention.attention_prefill_chunk(
+            params["mix"], cfg, h, cache, table, lengths, spec=spec)
     else:
         fn = (mla_mod.mla_verify_chunk if cfg.attention_kind == "mla"
               else attention.attention_verify_chunk)
@@ -396,11 +415,11 @@ def _block_prefill_chunk(params, cfg, sig, x, cache, table, lengths, spec,
         f, _ = moe.moe_ffn(params["ffn"], cfg, h2, dropless=True)
     else:
         f = layers.mlp(params["ffn"], h2)
-    return x + f, cache
+    return x + f, cache, sel
 
 
 def prefill_chunk(params, cfg, cache, tokens, block_table, lengths, *,
-                  spec=None, **legacy):
+                  spec=None, with_selection: bool = False, **legacy):
     """CHUNKED paged prefill: run C prompt tokens per sequence directly
     against the block-pool serving cache (DESIGN.md §9).
 
@@ -418,10 +437,13 @@ def prefill_chunk(params, cfg, cache, tokens, block_table, lengths, *,
     (DESIGN.md §10) map the matched blocks into the table, set lengths to
     the match length, and only the unmatched prompt TAIL ever runs through
     here.  Returns (logits [B,C,V], new cache); the final chunk's
-    last-position logits seed the first decode token."""
+    last-position logits seed the first decode token.  ``with_selection``
+    (DeepSeek Sparse Attention) adds a third output: the chunk's last
+    row's top-k mask in every layer, [n_layers, B, max_blocks*page]."""
     spec = attn_spec.coerce(spec, legacy, where="prefill_chunk")
-    return _chunk_forward(params, cfg, cache, tokens, block_table, lengths,
-                          spec)
+    out = _chunk_forward(params, cfg, cache, tokens, block_table, lengths,
+                         spec)
+    return out if with_selection else out[:2]
 
 
 def _paged_layers(params, cfg, cache, x, block_table, block):
@@ -437,8 +459,10 @@ def _paged_layers(params, cfg, cache, x, block_table, block):
     layout of the pool is not row-major, XLA relays each group's pool out
     once on the way in and once on the way out (DESIGN.md §8).
     ``block(lp, sig, x, pool, table)`` runs one block and returns
-    ``(x, pool)``.  Returns (x, new cache)."""
-    new_caches = []
+    ``(x, pool, sel)``.  Returns (x, new cache, sels): sels the blocks'
+    ``sel`` outputs stacked in layer order, None where no block gives one
+    (DeepSeek Sparse Attention's selections, :func:`decode_step`)."""
+    new_caches, sels = [], []
     for g, gparams, gcache in zip(layer_groups(cfg), params["groups"], cache,
                                   strict=True):
         n = g["n"]
@@ -449,17 +473,23 @@ def _paged_layers(params, cfg, cache, x, block_table, block):
             x, pools = carry
             lp, i = xs
             table = block_table + i * N
-            new = {}
+            new, sel = {}, []
             for j, sig in enumerate(g["sigs"]):
-                x, new[f"b{j}"] = block(lp[f"b{j}"], sig, x, pools[f"b{j}"],
-                                        table)
-            return (x, new), None
+                x, new[f"b{j}"], s = block(lp[f"b{j}"], sig, x,
+                                           pools[f"b{j}"], table)
+                sel.append(s)
+            return (x, new), (jnp.stack(sel) if all(
+                s is not None for s in sel) else None)
 
-        (x, flat), _ = jax.lax.scan(body, (x, flat),
-                                    (gparams, jnp.arange(n, dtype=jnp.int32)))
+        (x, flat), sel = jax.lax.scan(
+            body, (x, flat), (gparams, jnp.arange(n, dtype=jnp.int32)))
         new_caches.append(jax.tree.map(lambda a, ref: a.reshape(ref.shape),
                                        flat, gcache))
-    return x, new_caches
+        sels.append(None if sel is None
+                    else sel.reshape((-1,) + sel.shape[2:]))
+    if any(s is None for s in sels):
+        return x, new_caches, None
+    return x, new_caches, jnp.concatenate(sels)
 
 
 def _chunk_forward(params, cfg, cache, tokens, block_table, lengths, spec,
@@ -471,10 +501,11 @@ def _chunk_forward(params, cfg, cache, tokens, block_table, lengths, spec,
         return _block_prefill_chunk(lp, cfg, sig, x, pool, table, lengths,
                                     spec, qpos)
 
-    x, new_caches = _paged_layers(params, cfg, cache, x, block_table, block)
+    x, new_caches, sel = _paged_layers(params, cfg, cache, x, block_table,
+                                       block)
     x = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = layers.unembed(params["embed"], x)
-    return logits, new_caches
+    return logits, new_caches, sel
 
 
 def verify_step(params, cfg, cache, tokens, block_table, lengths, *,
@@ -497,7 +528,7 @@ def verify_step(params, cfg, cache, tokens, block_table, lengths, *,
         k = tokens.shape[1]
         qpos = lengths[:, None] + jnp.arange(k, dtype=jnp.int32)[None, :]
     return _chunk_forward(params, cfg, cache, tokens, block_table, lengths,
-                          spec, qpos.astype(jnp.int32))
+                          spec, qpos.astype(jnp.int32))[:2]
 
 
 def _pad_cache_rows(cfg, sig, cache_rows, max_len, batch_s):
@@ -536,7 +567,8 @@ def prefill(params, cfg, batch, max_len: int):
 
 def decode_step(params, cfg, cache, tokens, pos, *, spec=None,
                 cache_layout: str = "dense",
-                block_table=None, lengths=None, **legacy):
+                block_table=None, lengths=None,
+                with_selection: bool = False, **legacy):
     """One serving step. tokens: [B] int32; pos: scalar index of the new token.
     Returns (logits [B,V], new_cache). spec: one AttnSpec carrying mode /
     kv_splits / rescale for every attention layer (legacy mode=/kv_splits=
@@ -549,7 +581,10 @@ def decode_step(params, cfg, cache, tokens, pos, *, spec=None,
     cache_layout "paged" (the serving default in launch/serve.py): `cache`
     is the pool pytree from :func:`init_paged_cache`, and `block_table`
     [B, max_blocks] + per-sequence `lengths` [B] replace the shared scalar
-    `pos` — ragged sequences decode in one batch (continuous batching)."""
+    `pos` — ragged sequences decode in one batch (continuous batching).
+    ``with_selection`` (paged, DeepSeek Sparse Attention) adds a third
+    output: the positions each layer selected, [n_layers, B, k], the
+    first min(lengths + 1, k) of each row valid."""
     spec = attn_spec.coerce(spec, legacy, where="decode_step")
     assert cache_layout in ("dense", "paged"), cache_layout
     if cache_layout == "paged":
@@ -561,8 +596,8 @@ def decode_step(params, cfg, cache, tokens, pos, *, spec=None,
                                  cache_layout="paged", block_table=table,
                                  lengths=lengths)
 
-        x, new_caches = _paged_layers(params, cfg, cache, x, block_table,
-                                      block)
+        x, new_caches, sel = _paged_layers(params, cfg, cache, x,
+                                           block_table, block)
     else:
         # a dense cache has no table to offset: it rides the scan as xs/ys
         new_caches = []
@@ -572,14 +607,16 @@ def decode_step(params, cfg, cache, tokens, pos, *, spec=None,
                 lp, lc = xs
                 ncs = {}
                 for j, sig in enumerate(g["sigs"]):
-                    x, ncs[f"b{j}"] = _block_decode(lp[f"b{j}"], cfg, sig, x,
-                                                    lc[f"b{j}"], pos, spec)
+                    x, ncs[f"b{j}"], _ = _block_decode(
+                        lp[f"b{j}"], cfg, sig, x, lc[f"b{j}"], pos, spec)
                 return x, ncs
             x, gc_new = jax.lax.scan(body, x, (gparams, gcache))
             new_caches.append(gc_new)
+        sel = None
     x = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = layers.unembed(params["embed"], x)
-    return logits, new_caches
+    return (logits, new_caches, sel) if with_selection else (logits,
+                                                             new_caches)
 
 
 def param_count(params) -> int:

@@ -270,3 +270,97 @@ def test_pool_format_agrees_across_every_writer(r1_programs, one_chip):
         c = fn.lower(cache, *args).compile()
         assert c.input_formats[0][0] == want
         assert c.output_formats == want
+
+
+# -------------------------------------------- DeepSeek Sparse Attention
+V32_SLOTS, V32_TOKENS = 8, 32_768 + 256          # v32-doc-decode-32k's pool
+
+
+@pytest.mark.parametrize("rows", [1, 512])
+def test_dsa_index_kernel(one_chip, rows):
+    """The index-score kernel, as the decode step (one row a sequence) and
+    a prefill chunk (512 rows) call it, over the v32 cell's key pool."""
+    nb = V32_TOKENS // PAGE
+    n_blocks = 1 + V32_SLOTS * nb
+    b = V32_SLOTS if rows == 1 else 1
+    fn = ops.dsa_index_decode_paged if rows == 1 else \
+        ops.dsa_index_prefill_paged
+    text = _compile(
+        lambda q, w, p, t, n: fn(q, w, p, t, n, spec=SPEC), one_chip,
+        ((b, rows, 64, 128), jnp.bfloat16), ((b, rows, 64), jnp.float32),
+        ((n_blocks, PAGE, 128), jnp.bfloat16), ((b, nb), jnp.int32),
+        ((b,), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+def test_dsa_sparse_decode_and_masked_prefill(one_chip):
+    text = _compile(
+        lambda q, r, n: ops.dsa_sparse_decode(q, r, DV, n, spec=SPEC),
+        one_chip, ((B, H, LAT), jnp.bfloat16), ((B, 2048, LAT), jnp.bfloat16),
+        LENS)
+    assert "tpu_custom_call" in text
+    text = _compile(
+        lambda q, p, t, s, sel: ops.etap_prefill_mla_paged(
+            q, p, DV, t, s, spec=SPEC, sel=sel),
+        one_chip, ((1, 512, H, LAT), jnp.bfloat16), _pool(), ((1, NB), jnp.int32),
+        ((1,), jnp.int32), ((1, 512, NB * PAGE), jnp.bool_))
+    assert "tpu_custom_call" in text
+
+
+@pytest.fixture(scope="module")
+def v32_programs(one_chip):
+    """The decode and prefill programs of the deepseek-v32-tp8 share (3
+    dense + 4 MoE layers, indexer whole) over the v32-doc-decode-32k pool
+    (8 slots of 33,024 tokens: 4,129 blocks)."""
+    import sys
+    from pathlib import Path
+    bench = Path(__file__).resolve().parents[1] / "bench"
+    sys.path.insert(0, str(bench))
+    from harness import config
+    c = config.load(bench / "configs" / "deepseek-v32-tp8.json")
+    cfg = dataclasses.replace(config.model_config(c, "v32"),
+                              use_kernels=True)
+    layout = layout_for(V32_SLOTS, V32_TOKENS, block_size=PAGE)
+    progs = serve.paged_programs(
+        cfg, layout, attn_spec.AttnSpec(mode="etap", interpret=False))
+
+    def place(s):
+        return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+
+    def arr(shape):
+        return place(jax.ShapeDtypeStruct(shape, jnp.int32))
+
+    params = jax.tree.map(place, jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), cfg)))
+    cache = jax.tree.map(place, jax.eval_shape(progs.init))
+    nb = layout.max_blocks
+    texts = {name: fn.lower(params, cache, arr(toks), arr(table),
+                            arr(table[:1])).compile().as_text()
+             for name, fn, toks, table in (
+                 ("decode", progs.step, (V32_SLOTS,), (V32_SLOTS, nb)),
+                 ("prefill", progs.prefill, (1, 512), (1, nb)))}
+    return cfg, layout, texts
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_dsa_programs_compile_with_r1s_boundary_copies(v32_programs,
+                                                       program):
+    """The DSA decode step holds the index kernel and the sparse decode and
+    no paged ETAP decode over the whole latent pool; the prefill program
+    holds its own index kernel and the masked prefill kernel.  As for R1
+    (above), the latent pools are relaid out only at the program's
+    boundary, once in and once out per layer pool (2 x 7); the index-key
+    pools, whose default layout is the row-major one, not at all."""
+    cfg, layout, texts = v32_programs
+    text = texts[program]
+    names = set(re.findall(r"%(dsa_\w+|etap_\w+)\.\d+ = ", text))
+    if program == "decode":
+        assert names == {"dsa_index_decode_paged", "dsa_sparse_decode"}
+    else:
+        assert names == {"dsa_index_prefill_paged", "etap_prefill_mla_paged"}
+    found = whole_pool_copies(text, layout.num_blocks, (PAGE, LAT))
+    inside = [f for f in found if f[3] or f[1] != "copy"]
+    assert not inside, f"whole-pool copies in the layer scan: {inside}"
+    pools = sum(math.prod(d[:-2]) // layout.num_blocks for _, _, d, _ in found)
+    assert pools == 2 * cfg.num_layers, found
+    assert whole_pool_copies(text, layout.num_blocks, (PAGE, 128)) == []
