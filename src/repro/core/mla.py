@@ -144,11 +144,13 @@ def mla_train(params, cfg, x, positions, *, return_cache: bool = False):
     return out
 
 
-def _absorbed_query(params, cfg, x, positions, cq=None):
+def _absorbed_query(params, cfg, x, positions, cq=None, width=None):
     """Absorbed-form decode query:
     q_c[b,h] = q_nope[b,h] · W_uk[:,h]  (512-d), q = [q_c ; q_rope] (576-d).
     x: [B,D]; positions: [B,1]; cq: the q latent [B,1,q_lora] where the
-    caller has it. Returns q: [B,H,latent]."""
+    caller has it; width: a paged pool's row width, to which q is
+    zero-extended (its zero lanes meet the pool's padded lanes, so the
+    scores are exact).  Returns q: [B,H,width or latent]."""
     m, H = cfg.mla, cfg.num_heads
     q_nope, q_rope = _queries(params, cfg, x[:, None, :], positions, cq)
     q_nope, q_rope = q_nope[:, 0], q_rope[:, 0]               # [B,H,*]
@@ -156,7 +158,8 @@ def _absorbed_query(params, cfg, x, positions, cq=None):
     w_uk = params["w_uk"].reshape(m.kv_lora_rank, H, m.qk_nope_head_dim)
     q_c = jnp.einsum("bhd,chd->bhc", q_nope.astype(jnp.float32),
                      w_uk.astype(jnp.float32)).astype(x.dtype)
-    return jnp.concatenate([q_c, q_rope], axis=-1)            # [B,H,latent]
+    q = jnp.concatenate([q_c, q_rope], axis=-1)               # [B,H,latent]
+    return q if width is None else paged_cache.pad_lanes(q, width)
 
 
 def _absorbed_out(params, cfg, o_lat, dtype):
@@ -208,12 +211,15 @@ def mla_decode_paged(params, cfg, x, cache, table, lengths, *,
                      spec=None, **legacy):
     """Absorbed-form decode against a PAGED latent cache.
 
-    x: [B,D]; cache: {"c": pool [num_blocks, page, latent]}; table:
+    x: [B,D]; cache: {"c": pool [num_blocks, page, W]}; table:
     [B,max_blocks]; lengths: [B] — each sequence's new token is written at
     its own position `lengths[b]` (continuous batching serves ragged
-    lengths, so there is no shared scalar `pos`).  The single 576-wide
-    latent pool is streamed once through the block table; V is its first
-    kv_lora_rank columns (same one-stream argument as the dense MLA path).
+    lengths, so there is no shared scalar `pos`).  The pool stores each
+    latent row lane-aligned, W = lane_width(latent) (640 for 576), its
+    padded lanes 0; the query is zero-extended to W, so K is the whole
+    stored row.  The pool is streamed once through the block table; V is
+    its first kv_lora_rank columns (same one-stream argument as the dense
+    MLA path).
     Returns (out [B,D], {"c": updated pool}, sel): sel the positions
     DeepSeek Sparse Attention selected, [B,k] (the first min(lengths + 1,
     k) of each row valid), None without an indexer."""
@@ -224,7 +230,8 @@ def mla_decode_paged(params, cfg, x, cache, table, lengths, *,
     if m.indexer is not None:
         return _mla_decode_dsa(params, cfg, x, cache, table, lengths,
                                positions, spec)
-    q = _absorbed_query(params, cfg, x, positions)
+    q = _absorbed_query(params, cfg, x, positions,
+                        width=cache["c"].shape[-1])
     c_t = _latent(params, cfg, x[:, None, :], positions)[:, 0]  # [B,latent]
     inner = spec.replace(scale=m.qk_head_dim ** -0.5,
                          use_kernels=cfg.use_kernels)
@@ -249,7 +256,8 @@ def _mla_decode_dsa(params, cfg, x, cache, table, lengths, positions, spec):
     lightning indexer, and run absorbed MLA over the top-k rows only."""
     m = cfg.mla
     cq = _q_latent(params, cfg, x[:, None, :])                # [B,1,q_lora]
-    q = _absorbed_query(params, cfg, x, positions, cq)
+    q = _absorbed_query(params, cfg, x, positions, cq,
+                        width=cache["c"].shape[-1])
     xs = x[:, None, :]
     c_t = _latent(params, cfg, xs, positions)[:, 0]           # [B,latent]
     k_t = index_keys(params, cfg, xs, positions)[:, 0]        # [B,idx_dim]
@@ -286,7 +294,8 @@ def _mla_chunk(params, cfg, x, cache, table, lengths, positions, *, spec,
     w_uk = params["w_uk"].reshape(m.kv_lora_rank, H, m.qk_nope_head_dim)
     q_c = jnp.einsum("bchd,khd->bchk", q_nope.astype(jnp.float32),
                      w_uk.astype(jnp.float32)).astype(x.dtype)
-    q = jnp.concatenate([q_c, q_rope], axis=-1)               # [B,C,H,latent]
+    q = paged_cache.pad_lanes(jnp.concatenate([q_c, q_rope], axis=-1),
+                              cache["c"].shape[-1])           # [B,C,H,W]
     c_rows = _latent(params, cfg, x, positions)               # [B,C,latent]
     inner = spec.replace(scale=m.qk_head_dim ** -0.5,
                          use_kernels=cfg.use_kernels)
@@ -372,11 +381,14 @@ def init_mla_cache(cfg, batch: int, max_len: int, dtype):
 
 def init_mla_cache_paged(cfg, layout, dtype, kv_dtype: str = "fp"):
     """Paged latent pool (block 0 = reserved null block, see
-    runtime/paged_cache.py).  kv_dtype "int8"/"fp8": the pool stores codes
-    and a parallel per-row (scale, zp) pool rides under "c_sz"
-    (DESIGN.md §11); scale 1 / zp 0 makes the all-zero init round-trip
-    exactly."""
-    shape = (layout.num_blocks, layout.block_size, cfg.mla.latent_dim)
+    runtime/paged_cache.py), its rows lane_width(latent) wide: the TPU's
+    default layout of such a pool is row-major, the layout the kernels
+    read, so no program relays it (DESIGN.md §8).  kv_dtype "int8"/"fp8":
+    the pool stores codes and a parallel per-row (scale, zp) pool rides
+    under "c_sz" (DESIGN.md §11); scale 1 / zp 0 makes the all-zero init
+    round-trip exactly."""
+    shape = (layout.num_blocks, layout.block_size,
+             paged_cache.lane_width(cfg.mla.latent_dim))
     qdt = paged_cache.quant_dtype(kv_dtype)
     ix = cfg.mla.indexer
     if ix is not None:

@@ -93,7 +93,9 @@ def summarize_paged(snap: dict, ctx: dict) -> list:
          f"budget={ctx['budget']} kv_dtype={ctx['kv_dtype']} "
          f"rescale={ctx['rescale']} prefix_cache="
          f"{'on' if ctx['prefix'] is not None else 'off'} "
-         f"preemption={ctx['preemption']} spec_tokens={ctx['spec_tokens']}"),
+         f"preemption={ctx['preemption']} spec_tokens={ctx['spec_tokens']} "
+         f"pool_row_major="
+         f"{int(snap['gauges'].get('paged/pool_row_major', 0))}"),
         (f"[serve] {tokens_served} tokens in {steps} decode steps "
          f"({tokens_served / max(steps, 1):.2f} tokens/step occupancy); "
          f"{prefill_chunks} prefill chunks, {interleaved} steps "

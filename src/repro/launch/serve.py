@@ -336,6 +336,11 @@ def run_paged(args, cfg, *, record_logits: bool = False) -> dict:
                               spec_draft=args.spec_draft)
     progs = paged_programs(cfg, layout, spec)
     cache = progs.init()
+    # whether the device keeps every pool leaf row-major, the layout the
+    # kernels read: 0 means each program relays the pool (DESIGN.md §8)
+    reg.gauge("paged/pool_row_major").set(int(all(
+        a.format.layout.major_to_minor == tuple(range(a.ndim))
+        for a in jax.tree.leaves(cache))))
     pending = deque(sorted(_make_requests(args, cfg.vocab_size),
                            key=lambda r: (r["arrival"], r["id"])))
     n_requests = len(pending)

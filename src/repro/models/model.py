@@ -321,14 +321,15 @@ def paged_row_bytes(cfg, kv_dtype: str = "fp") -> int:
     """KV-cache bytes ONE token costs across the whole layer stack in a
     paged cache of the given layout — the quantity the serve loop's
     byte-budget capacity accounting divides by (DESIGN.md §11).  MLA: one
-    latent_dim row per layer, plus the indexer's key row under DeepSeek
-    Sparse Attention; GQA: K heads × head_dim for K and V each (each head
-    is its own quantization granule, so each carries its own (scale, zp)
-    overhead)."""
-    from repro.runtime.paged_cache import row_bytes
+    latent row per layer at its stored, lane-aligned width, plus the
+    indexer's key row under DeepSeek Sparse Attention; GQA: K heads ×
+    head_dim for K and V each (each head is its own quantization granule,
+    so each carries its own (scale, zp) overhead)."""
+    from repro.runtime.paged_cache import lane_width, row_bytes
     n_layers = len(cfg.layer_kinds())
     if cfg.attention_kind == "mla":
-        per_layer = row_bytes(cfg.mla.latent_dim, kv_dtype, cfg.jax_dtype)
+        per_layer = row_bytes(lane_width(cfg.mla.latent_dim), kv_dtype,
+                              cfg.jax_dtype)
         if cfg.mla.indexer is not None:
             per_layer += row_bytes(cfg.mla.indexer.head_dim, kv_dtype,
                                    cfg.jax_dtype)
@@ -455,9 +456,9 @@ def _paged_layers(params, cfg, cache, x, block_table, block):
     ``block_table + i*N``, so its null block is ``i*N`` and inactive slots
     stay masked as before.  The scatter writes the carry in place and the
     kernels read it through the table: no layer's pool is sliced out,
-    relaid out or stacked back inside the scan.  Where the device's default
-    layout of the pool is not row-major, XLA relays each group's pool out
-    once on the way in and once on the way out (DESIGN.md §8).
+    relaid out or stacked back inside the scan.  The pool's rows are
+    lane-aligned, so its default layout is the row-major one the kernels
+    read and XLA relays it at no program boundary either (DESIGN.md §8).
     ``block(lp, sig, x, pool, table)`` runs one block and returns
     ``(x, pool, sel)``.  Returns (x, new cache, sels): sels the blocks'
     ``sel`` outputs stacked in layer order, None where no block gives one

@@ -90,6 +90,24 @@ KV_LAYOUTS = ("fp", "int8", "fp8")
 HAS_FP8 = hasattr(jnp, "float8_e4m3fn")
 F8_MAX = 448.0                    # e4m3fn finite max (no inf encoding)
 INT8_MAX = 127.0
+LANES = 128                       # a TPU vreg's lane count (DESIGN.md §8)
+
+
+def lane_width(d: int) -> int:
+    """Stored width of a d-wide paged latent row: d rounded up to whole
+    128-lane tiles (576 -> 640).  A pool whose rows fill their lane tiles
+    has a row-major default layout on the TPU at any block count, so no
+    program relays it (DESIGN.md §8); a width already a multiple of 128
+    is stored as it is."""
+    return -(-int(d) // LANES) * LANES
+
+
+def pad_lanes(x, width: int):
+    """Zero-extend x's last axis to ``width`` (x as it is at that width)."""
+    pad = width - x.shape[-1]
+    if not pad:
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
 
 
 @dataclasses.dataclass(frozen=True)
@@ -558,13 +576,15 @@ def append_rows(pool, table, lengths, rows):
     """Write one new token row per sequence at its current length.
 
     pool: [N, bs, *F]; table: [B, max_blocks] int32; lengths: [B] int32
-    (write position = lengths[b]); rows: [B, *F].  Inactive slots (all-null
-    table, length 0) land in the null block — harmless, masked on read."""
+    (write position = lengths[b]); rows: [B, *F], the last axis at most
+    the pool's and zero-extended to it (a lane-aligned latent pool keeps
+    its padded lanes exactly 0).  Inactive slots (all-null table, length
+    0) land in the null block — harmless, masked on read."""
     bs = pool.shape[1]
     blk = lengths // bs
     slot = lengths % bs
     pid = jnp.take_along_axis(table, blk[:, None], axis=1)[:, 0]   # [B]
-    return pool.at[pid, slot].set(rows)
+    return pool.at[pid, slot].set(pad_lanes(rows, pool.shape[-1]))
 
 
 def append_chunk(pool, table, lengths, rows):
@@ -572,17 +592,19 @@ def append_chunk(pool, table, lengths, rows):
 
     pool: [N, bs, *F]; table: [B, max_blocks] int32; lengths: [B] int32
     (chunk token c of sequence b lands at logical position lengths[b] + c);
-    rows: [B, C, *F].  The chunked-prefill analogue of :func:`append_rows`:
-    one scatter covers the whole chunk even when it straddles block
-    boundaries.  Rows of a sequence whose table is all-null (inactive slot)
-    land in the null block — harmless, masked on read."""
+    rows: [B, C, *F], zero-extended to the pool's last axis as in
+    :func:`append_rows`.  The chunked-prefill analogue of
+    :func:`append_rows`: one scatter covers the whole chunk even when it
+    straddles block boundaries.  Rows of a sequence whose table is all-null
+    (inactive slot) land in the null block — harmless, masked on read."""
     bs = pool.shape[1]
     C = rows.shape[1]
     pos = lengths[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]  # [B,C]
     blk = pos // bs
     slot = pos % bs
     pid = jnp.take_along_axis(table, blk, axis=1)                     # [B,C]
-    return pool.at[pid, slot].set(rows.astype(pool.dtype))
+    return pool.at[pid, slot].set(
+        pad_lanes(rows.astype(pool.dtype), pool.shape[-1]))
 
 
 def copy_block(pool, src: int, dst: int):
@@ -710,7 +732,9 @@ def layout_for_bytes(budget_bytes: int, bytes_per_row: int, max_len: int,
 def append_rows_quant(pool, sz_pool, table, lengths, rows):
     """Quantized :func:`append_rows`: quantize the new rows in the pool's
     layout and scatter codes + (scale, zp) through the same table/length
-    coordinates.  rows arrive in fp; returns (pool, sz_pool)."""
+    coordinates.  rows arrive in fp; returns (pool, sz_pool).  Each
+    row's (scale, zp) is taken over its own lanes only; a lane-aligned
+    pool's padded lanes get code 0, finite once dequantized."""
     codes, sz = quantize_rows(rows, kv_dtype_of(pool))
     return (append_rows(pool, table, lengths, codes),
             append_rows(sz_pool, table, lengths, sz))

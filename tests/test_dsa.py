@@ -404,8 +404,9 @@ def test_r1_shape_builds_no_indexer_state():
                                           np.asarray(flat[path]))
     layout = pc.layout_for(2, 32, block_size=8)
     assert set(model.init_paged_cache(cfg, layout)[0]["b0"]) == {"c"}
-    assert model.paged_row_bytes(cfg) == 3 * 40 * 4
-    assert model.paged_row_bytes(dsa_cfg()) == 3 * (40 + 16) * 4
+    # the latent row is stored lane-aligned (40 -> 128), the index key as is
+    assert model.paged_row_bytes(cfg) == 3 * 128 * 4
+    assert model.paged_row_bytes(dsa_cfg()) == 3 * (128 + 16) * 4
 
 
 @pytest.mark.parametrize("extra", [["--kv-dtype", "int8"],

@@ -512,3 +512,179 @@ def test_pool_format_survives_every_writer():
         np.testing.assert_array_equal(np.asarray(leaf[:, 4:6]), row)
     _, c4 = progs.step(params, c3, tok, table, lengths)
     check(c3, c4)
+
+
+# ------------------------------------------- the lane-aligned latent pool
+LAT, DV, WIDE = 576, 512, pc.lane_width(576)     # R1's latent, V, 640
+
+
+def test_lane_width_rounds_up_to_whole_lane_tiles():
+    assert [pc.lane_width(d) for d in (40, 128, 576, 640, 641)] == \
+        [128, 128, 640, 640, 768]
+    x = jnp.ones((2, 3, 40), jnp.bfloat16)
+    y = pc.pad_lanes(x, 128)
+    assert y.shape == (2, 3, 128) and y.dtype == x.dtype
+    assert not np.asarray(y[..., 40:], np.float32).any()
+    assert pc.pad_lanes(x, 40) is x
+
+
+def _mla_stack(kv_dtype="fp", **over):
+    import dataclasses
+
+    from repro.configs import get_config, reduced
+    from repro.models import model
+    cfg = dataclasses.replace(reduced(get_config("deepseek_r1_671b")),
+                              num_layers=2, moe=None, **over)
+    layout = pc.layout_for(2, 32, block_size=8, spare_blocks=3)
+    return cfg, layout, model.init_paged_cache(cfg, layout,
+                                               kv_dtype=kv_dtype)
+
+
+def _padding(cache, latent):
+    """Every value in the padded lanes of every latent code pool."""
+    return np.concatenate([np.asarray(g[k]["c"][..., latent:], np.float32)
+                           .ravel() for g in cache for k in g])
+
+
+@pytest.mark.parametrize("kv_dtype", ["fp", "int8"])
+def test_padded_lanes_stay_zero_through_every_writer(kv_dtype):
+    """The latent pool is stored lane_width(latent) wide; the decode row,
+    the prefill chunk and the verify rows, the COW block copy and a swap
+    out and back all leave its padded lanes exactly 0 (code 0 in a
+    quantized pool)."""
+    from repro.core import attn_spec
+    from repro.models import model
+
+    cfg, layout, cache = _mla_stack(kv_dtype)
+    latent = cfg.mla.latent_dim
+    for g in cache:
+        for blk in g.values():
+            assert blk["c"].shape[-1] == pc.lane_width(latent) > latent
+    params = model.init(jax.random.PRNGKey(0), cfg)
+    spec = attn_spec.AttnSpec(kv_dtype=kv_dtype)
+    bp = pc.BlockPool(layout, 2)
+    for b in range(2):
+        assert bp.admit(0, 32) == b
+    toks = jax.random.randint(jax.random.PRNGKey(1), (2, 11), 0,
+                              cfg.vocab_size)
+    table, lengths = bp.device_views()
+    _, cache = model.prefill_chunk(params, cfg, cache, toks[:, :9], table,
+                                   lengths, spec=spec)   # crosses a page
+    for b in range(2):
+        bp.extend(b, 9)
+    table, lengths = bp.device_views()
+    _, cache = model.decode_step(params, cfg, cache, toks[:, 9], None,
+                                 spec=spec, cache_layout="paged",
+                                 block_table=table, lengths=lengths)
+    _, cache = model.verify_step(params, cfg, cache, toks[:, 9:], table,
+                                 lengths, spec=spec)
+    first, second = (int(i) for i in bp.block_ids(0)[:2])
+    cache = model.copy_paged_block(cache, first, layout.num_blocks - 1)
+    rows = model.read_paged_blocks(cache, [first, second])   # swap out
+    cache = model.write_paged_blocks(cache, np.asarray([5, 6], np.int32),
+                                     rows)                   # and back
+    written = np.asarray(cache[0]["b0"]["c"][0, first], np.float32)
+    assert written[:, :latent].any()
+    assert not _padding(cache, latent).any()
+
+
+def _lane_case(path, use_kernels):
+    """One attention call on a 576-wide pool and the same call on the
+    640-wide pool that holds the same rows, with q zero-extended: the
+    paged decode, the chunked prefill, the verify and the DSA sparse
+    decode (selection included), on the Pallas kernel in interpret mode or
+    the XLA twin."""
+    from repro.core import attn_spec
+    from repro.core import etap as core
+
+    rng = np.random.default_rng(5)
+    spec = attn_spec.AttnSpec(scale=192 ** -0.5, use_kernels=use_kernels)
+    page, lens = 64, [200, 131]
+    dense = jnp.asarray(rng.normal(size=(2, 256, LAT)), jnp.bfloat16)
+    pool, bp = _paged(dense, lens, page, spare=0)
+    table, lengths = bp.device_views()
+    wide = pc.pad_lanes(pool, WIDE)
+
+    def q_of(shape):
+        q = jnp.asarray(rng.normal(size=shape + (LAT,)), jnp.bfloat16)
+        return q, pc.pad_lanes(q, WIDE)
+
+    if path == "decode":
+        q, qw = q_of((2, 16))
+        return [core.decode_attention_paged(qq, p, None, table, lengths,
+                                            spec=spec, dv=DV)
+                for qq, p in ((q, pool), (qw, wide))]
+    if path in ("prefill", "verify"):
+        q, qw = q_of((2, 8, 4))
+        start = lengths - 8
+        qpos = start[:, None] + jnp.asarray([0, 1, 2, 2, 4, 5, 5, 7])
+        if path == "prefill":
+            return [core.prefill_attention_paged(qq, p, None, table, start,
+                                                 spec=spec, dv=DV)
+                    for qq, p in ((q, pool), (qw, wide))]
+        return [core.verify_attention_paged(qq, p, None, table, start, qpos,
+                                            spec=spec, dv=DV)
+                for qq, p in ((q, pool), (qw, wide))]
+    q, qw = q_of((2, 16))
+    kdense = jnp.asarray(rng.normal(size=(2, 256, 128)), jnp.bfloat16)
+    kpool, _ = _paged(kdense, lens, page, spare=0)
+    q_idx = jnp.asarray(rng.normal(size=(2, 8, 128)), jnp.bfloat16)
+    w = jnp.asarray(rng.uniform(size=(2, 8)), jnp.float32)
+    return [core.dsa_decode_paged(qq, p, kpool, q_idx, w, table, lengths,
+                                  spec=spec, dv=DV, topk=128)
+            for qq, p in ((q, pool), (qw, wide))]
+
+
+@pytest.mark.parametrize("use_kernels", [True, False],
+                         ids=["pallas", "xla"])
+@pytest.mark.parametrize("path", ["decode", "prefill", "verify", "dsa"])
+def test_wide_pool_is_bitwise_the_narrow_one(path, use_kernels):
+    narrow, wide = _lane_case(path, use_kernels)
+    for a, b in zip(jax.tree.leaves(narrow), jax.tree.leaves(wide),
+                    strict=True):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a).view(np.uint8),
+                                      np.asarray(b).view(np.uint8))
+
+
+@pytest.mark.parametrize("kv_dtype", ["int8", "fp8"])
+def test_quantized_rows_in_a_wide_pool(kv_dtype):
+    """A quantized row's (scale, zp) comes from its 576 real lanes alone,
+    its codes there are those a 576-wide pool holds, and the padded codes
+    are 0: finite once dequantized, and met only by zero query lanes."""
+    if kv_dtype == "fp8" and not pc.HAS_FP8:
+        pytest.skip("no float8_e4m3fn in this jax build")
+    rng = np.random.default_rng(9)
+    layout = pc.layout_for(2, 64, block_size=16)
+    bp = pc.BlockPool(layout, 2)
+    for b in range(2):
+        assert bp.admit(0, 64) == b
+        bp.extend(b, 13 + 20 * b)
+    table, lengths = bp.device_views()
+    dt = pc.quant_dtype(kv_dtype)
+    sz0 = jnp.zeros((layout.num_blocks, 16, 2), jnp.float32)
+    rows = jnp.asarray(rng.normal(size=(2, 5, LAT)) * 3, jnp.bfloat16)
+    out = {}
+    for width in (LAT, WIDE):
+        pool = jnp.zeros((layout.num_blocks, 16, width), dt)
+        pool, sz = pc.append_chunk_quant(pool, sz0, table, lengths, rows)
+        out[width] = pc.append_rows_quant(pool, sz, table, lengths + 5,
+                                          rows[:, 0])
+    (narrow, sz_n), (wide, sz_w) = out[LAT], out[WIDE]
+    view = np.uint8
+    np.testing.assert_array_equal(np.asarray(wide[..., :LAT]).view(view),
+                                  np.asarray(narrow).view(view))
+    assert not np.asarray(wide[..., LAT:]).view(view).any()
+    np.testing.assert_array_equal(np.asarray(sz_w), np.asarray(sz_n))
+    assert np.isfinite(np.asarray(pc.dequantize_rows(wide, sz_w))).all()
+
+
+def test_run_paged_reports_a_row_major_pool():
+    """run_paged reads the pool's device format once, at creation, into
+    the paged/pool_row_major gauge (1 on the CPU, whose default layout is
+    row-major at every width)."""
+    from repro.launch import serve
+    args = serve.parse_args(["--reduced", "--batch", "1", "--prompt", "8",
+                             "--gen", "2", "--requests", "1"])
+    res = serve.run(args)
+    assert res["metrics"]["gauges"]["paged/pool_row_major"] == 1

@@ -1,13 +1,14 @@
 """Compile the serve path's Pallas kernels for a described TPU v5e.
 
 Nothing runs: each test lowers a kernel entry (or the whole jitted paged
-decode step) at DeepSeek-R1 chip-share widths — B 16, H 16, latent 576,
-Dv 512, page 64, bf16 — for one chip of a ``v5e:2x2`` topology, and
-asserts the compiled program holds a Mosaic kernel (``tpu_custom_call``).
-Mosaic's block-tiling rule and its VMEM budget are enforced here, where
-interpret mode enforces neither.  The serve programs of
-``launch/serve.paged_programs`` are compiled as the serve loop jits them:
-their compiled text must hold no whole-pool copy inside the layer scan,
+decode step) at DeepSeek-R1 chip-share widths — B 16, H 16, latent 576
+stored 640 lanes wide, Dv 512, page 64, bf16 — for one chip of a
+``v5e:2x2`` topology, and asserts the compiled program holds a Mosaic
+kernel (``tpu_custom_call``).  Mosaic's block-tiling rule and its VMEM
+budget are enforced here, where interpret mode enforces neither.  The
+serve programs of ``launch/serve.paged_programs`` are compiled as the
+serve loop jits them: their compiled text must hold no whole-pool copy,
+the chip's default layout of every pool they carry must be row-major,
 and their pool formats must agree from one program to the next.
 
 The topology is described inside a module fixture (never at import):
@@ -31,9 +32,10 @@ from repro.core import attn_spec
 from repro.kernels.etap import ops
 from repro.launch import serve
 from repro.models import model
-from repro.runtime.paged_cache import layout_for
+from repro.runtime.paged_cache import lane_width, layout_for
 
 B, H, LAT, DV, PAGE, NB = 16, 16, 576, 512, 64, 64
+W = lane_width(LAT)                     # the paged pool's stored row width
 N_BLOCKS = 1 + B * NB
 SPEC = attn_spec.AttnSpec(scale=192 ** -0.5, interpret=False)
 
@@ -75,7 +77,7 @@ def _compile(fn, one_chip, *shapes):
 
 
 def _pool(dtype=jnp.bfloat16):
-    return (N_BLOCKS, PAGE, LAT), dtype
+    return (N_BLOCKS, PAGE, W), dtype
 
 
 TABLE = ((B, NB), jnp.int32)
@@ -85,7 +87,7 @@ SZ = ((N_BLOCKS, PAGE, 2), jnp.float32)
 
 @pytest.mark.parametrize("kv", ["bf16", "int8"])
 def test_paged_mla_decode(one_chip, kv):
-    q = ((B, H, LAT), jnp.bfloat16)
+    q = ((B, H, W), jnp.bfloat16)
     if kv == "int8":
         text = _compile(
             lambda q, p, sz, t, n: ops.etap_decode_mla_paged(
@@ -104,7 +106,7 @@ def test_paged_splitkv_partial_and_combine(one_chip):
     text = _compile(
         lambda q, p, t, n: ops.etap_decode_mla_paged_splitkv(
             q, p, DV, t, n, spec=spec),
-        one_chip, ((B, H, LAT), jnp.bfloat16), _pool(), TABLE, LENS)
+        one_chip, ((B, H, W), jnp.bfloat16), _pool(), TABLE, LENS)
     assert text.count("tpu_custom_call") >= 2     # partial + combine
 
 
@@ -113,7 +115,7 @@ def test_paged_prefill(one_chip, chunk):
     text = _compile(
         lambda q, p, t, s: ops.etap_prefill_mla_paged(
             q, p, DV, t, s, spec=SPEC),
-        one_chip, ((B, chunk, H, LAT), jnp.bfloat16), _pool(), TABLE, LENS)
+        one_chip, ((B, chunk, H, W), jnp.bfloat16), _pool(), TABLE, LENS)
     assert "tpu_custom_call" in text
 
 
@@ -122,7 +124,7 @@ def test_paged_verify(one_chip):
     text = _compile(
         lambda q, p, t, s, qp: ops.etap_verify_mla_paged(
             q, p, DV, t, s, qp, spec=SPEC),
-        one_chip, ((B, k, H, LAT), jnp.bfloat16), _pool(), TABLE, LENS,
+        one_chip, ((B, k, H, W), jnp.bfloat16), _pool(), TABLE, LENS,
         ((B, k), jnp.int32))
     assert "tpu_custom_call" in text
 
@@ -199,8 +201,8 @@ def r1_programs(one_chip, chip_smoke):
     """The serve programs of a 4-layer R1 chip share (3 scanned dense
     layers + 1 lone MoE layer) for the described chip, over the
     r1-doc-decode cell's pool (16 slots of 16,512 tokens: 4,129 blocks,
-    where the chip's default pool layout puts the block axis minor),
-    with shape arguments placed on it."""
+    where a 576-wide row would put the block axis minor in the chip's
+    default layout), with shape arguments placed on it."""
     cfg = dataclasses.replace(chip_smoke.chip_share_config(),
                               use_kernels=True)
     layout = layout_for(B, CELL_TOKENS, block_size=PAGE)
@@ -234,19 +236,18 @@ def r1_programs(one_chip, chip_smoke):
 @pytest.mark.parametrize("program", ["decode", "prefill", "verify"])
 def test_serve_program_copies_the_pool_only_at_its_boundary(r1_programs,
                                                           program):
-    """No serve program slices a layer's pool out of the stack, relays it
-    or writes it back inside the layer scan: the pool is carried whole.
-    What is left is one relayout of each group's pool on the way in and
-    one on the way out (the chip's default layout of a 4,129-block pool
-    is not the row-major one the kernels read): 2 x 4 layer pools."""
-    cfg, _, layout, _, compile_ = r1_programs
+    """No serve program copies a whole layer pool, in the layer scan or
+    at its boundary: the pool is carried whole through the scan, and its
+    lane-aligned rows make the chip's default layout the row-major one
+    the kernels read, so nothing relays it in or out.  Checked at the
+    stored width and at the latent's own, so that a pool that went back
+    to 576-wide rows cannot pass."""
+    _, _, layout, _, compile_ = r1_programs
     text = compile_(program).as_text()
     assert "tpu_custom_call" in text
-    found = whole_pool_copies(text, layout.num_blocks, (PAGE, LAT))
-    inside = [f for f in found if f[3] or f[1] != "copy"]
-    assert not inside, f"whole-pool copies in the layer scan: {inside}"
-    pools = sum(math.prod(d[:-2]) // layout.num_blocks for _, _, d, _ in found)
-    assert pools == 2 * cfg.num_layers, found
+    for row in ((PAGE, W), (PAGE, LAT)):
+        found = whole_pool_copies(text, layout.num_blocks, row)
+        assert not found, f"whole-pool copies at row {row}: {found}"
 
 
 def test_pool_format_agrees_across_every_writer(r1_programs, one_chip):
@@ -296,30 +297,36 @@ def test_dsa_index_kernel(one_chip, rows):
 def test_dsa_sparse_decode_and_masked_prefill(one_chip):
     text = _compile(
         lambda q, r, n: ops.dsa_sparse_decode(q, r, DV, n, spec=SPEC),
-        one_chip, ((B, H, LAT), jnp.bfloat16), ((B, 2048, LAT), jnp.bfloat16),
+        one_chip, ((B, H, W), jnp.bfloat16), ((B, 2048, W), jnp.bfloat16),
         LENS)
     assert "tpu_custom_call" in text
     text = _compile(
         lambda q, p, t, s, sel: ops.etap_prefill_mla_paged(
             q, p, DV, t, s, spec=SPEC, sel=sel),
-        one_chip, ((1, 512, H, LAT), jnp.bfloat16), _pool(), ((1, NB), jnp.int32),
+        one_chip, ((1, 512, H, W), jnp.bfloat16), _pool(), ((1, NB), jnp.int32),
         ((1,), jnp.int32), ((1, 512, NB * PAGE), jnp.bool_))
     assert "tpu_custom_call" in text
 
 
-@pytest.fixture(scope="module")
-def v32_programs(one_chip):
-    """The decode and prefill programs of the deepseek-v32-tp8 share (3
-    dense + 4 MoE layers, indexer whole) over the v32-doc-decode-32k pool
-    (8 slots of 33,024 tokens: 4,129 blocks)."""
+def _v32_config():
+    """The deepseek-v32-tp8 share (3 dense + 4 MoE layers, indexer whole)
+    as the benchmark builds it, on the kernels."""
     import sys
     from pathlib import Path
     bench = Path(__file__).resolve().parents[1] / "bench"
     sys.path.insert(0, str(bench))
     from harness import config
     c = config.load(bench / "configs" / "deepseek-v32-tp8.json")
-    cfg = dataclasses.replace(config.model_config(c, "v32"),
-                              use_kernels=True)
+    return dataclasses.replace(config.model_config(c, "v32"),
+                               use_kernels=True)
+
+
+@pytest.fixture(scope="module")
+def v32_programs(one_chip):
+    """The decode and prefill programs of the deepseek-v32-tp8 share over
+    the v32-doc-decode-32k pool (8 slots of 33,024 tokens: 4,129
+    blocks)."""
+    cfg = _v32_config()
     layout = layout_for(V32_SLOTS, V32_TOKENS, block_size=PAGE)
     progs = serve.paged_programs(
         cfg, layout, attn_spec.AttnSpec(mode="etap", interpret=False))
@@ -348,19 +355,44 @@ def test_dsa_programs_compile_with_r1s_boundary_copies(v32_programs,
     """The DSA decode step holds the index kernel and the sparse decode and
     no paged ETAP decode over the whole latent pool; the prefill program
     holds its own index kernel and the masked prefill kernel.  As for R1
-    (above), the latent pools are relaid out only at the program's
-    boundary, once in and once out per layer pool (2 x 7); the index-key
-    pools, whose default layout is the row-major one, not at all."""
-    cfg, layout, texts = v32_programs
+    (above), no latent pool is copied whole, at either width, and no
+    index-key pool either: all of them are row-major by default."""
+    _, layout, texts = v32_programs
     text = texts[program]
     names = set(re.findall(r"%(dsa_\w+|etap_\w+)\.\d+ = ", text))
     if program == "decode":
         assert names == {"dsa_index_decode_paged", "dsa_sparse_decode"}
     else:
         assert names == {"dsa_index_prefill_paged", "etap_prefill_mla_paged"}
-    found = whole_pool_copies(text, layout.num_blocks, (PAGE, LAT))
-    inside = [f for f in found if f[3] or f[1] != "copy"]
-    assert not inside, f"whole-pool copies in the layer scan: {inside}"
-    pools = sum(math.prod(d[:-2]) // layout.num_blocks for _, _, d, _ in found)
-    assert pools == 2 * cfg.num_layers, found
-    assert whole_pool_copies(text, layout.num_blocks, (PAGE, 128)) == []
+    for row in ((PAGE, W), (PAGE, LAT), (PAGE, 128)):
+        found = whole_pool_copies(text, layout.num_blocks, row)
+        assert not found, f"whole-pool copies at row {row}: {found}"
+
+
+@pytest.mark.parametrize("arch,slots,tokens,blocks", [
+    ("r1", B, CELL_TOKENS, 4_129),               # r1-doc-decode
+    ("r1", B, 16_384 + 32, 4_113),               # r1-longprompt-prefill
+    ("v32", V32_SLOTS, V32_TOKENS, 4_129),       # a 4-layer group, k_idx
+    ("r1", B, 32_768 + 512, 8_321),              # the 32K cells
+    ("r1", B, 65_536, 16_385),                   # the 64K cell
+])
+def test_pool_default_layout_is_row_major(one_chip, chip_smoke, arch, slots,
+                                          tokens, blocks):
+    """The chip's default format of every pool leaf ``progs.init`` returns
+    is row-major at the cells' block counts, so the pool a cache-loaded
+    executable hands back (in the default format) is the one the kernels
+    read, and no program relays it."""
+    cfg = _v32_config() if arch == "v32" else dataclasses.replace(
+        chip_smoke.chip_share_config(), use_kernels=True)
+    layout = layout_for(slots, tokens, block_size=PAGE)
+    assert layout.num_blocks == blocks
+    progs = serve.paged_programs(
+        cfg, layout, attn_spec.AttnSpec(mode="etap", interpret=False))
+    out = jax.jit(progs.init, out_shardings=one_chip).lower().compile() \
+        .output_formats
+    leaves = jax.tree.leaves(out)
+    shapes = [a.shape for a in jax.tree.leaves(jax.eval_shape(progs.init))]
+    assert len(leaves) == len(shapes)
+    assert (4 if arch == "v32" else 3, blocks, PAGE, W) in shapes
+    for f, shape in zip(leaves, shapes, strict=True):
+        assert f.layout.major_to_minor == tuple(range(len(shape))), shape
